@@ -70,6 +70,7 @@ def cmd_dump_config(args):
 
 
 def cmd_train(args):
+    from paddle_tpu.core import compile_cache
     from paddle_tpu.launch import distributed_init_from_env
     from paddle_tpu.obs import flight_recorder as _flight
     from paddle_tpu.trainer import SGD
@@ -79,6 +80,7 @@ def cmd_train(args):
     # PADDLE_FLIGHT_DIR=<dir> arms the anomaly flight recorder
     # (watchdog rungs dump span/timeline/event bundles there)
     _flight.enable_from_env()
+    compile_cache.enable()
 
     # under `paddle launch` every worker carries the rendezvous env —
     # join it before any device use (cluster_train trainer_id wiring)
@@ -394,12 +396,14 @@ def cmd_serve(args):
     import signal
     import time as _time
 
+    from paddle_tpu.core import compile_cache
     from paddle_tpu.obs import flight_recorder as _flight
     from paddle_tpu.serving.tcp import ServingTCPServer
 
     # PADDLE_FLIGHT_DIR=<dir> arms the anomaly flight recorder
     # (breaker opens / shed spikes / SLO breaches dump bundles there)
     _flight.enable_from_env()
+    compile_cache.enable()
 
     spec = importlib.util.spec_from_file_location("_serve_config",
                                                   args.config)

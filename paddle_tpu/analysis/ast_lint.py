@@ -29,7 +29,7 @@ tree. Each pass encodes a rule the repo learned the hard way:
   pass tracks only locally-bound jit objects.
 - **raw_collective_outside_shard_map** — `lax.psum` / `ppermute` /
   `all_to_all` / `all_gather` are only meaningful over a named mesh
-  axis, i.e. inside a function that flows into `core.mesh.shard_map`.
+  axis, i.e. inside a function that flows into `jax.shard_map`.
   A raw collective in ordinary jit code either crashes on an unbound
   axis name or — under an enclosing pmap/shard_map it was never
   written for — silently reduces over the WRONG axis. The pass roots
@@ -563,7 +563,7 @@ def check_raw_collective_outside_shard_map(repo_dir: str) -> list:
                 f"which never flows into shard_map — the axis name "
                 f"is unbound (or bound to the WRONG mesh axis under "
                 f"someone else's pmap); wrap the caller in "
-                f"core.mesh.shard_map or justify with "
+                f"jax.shard_map or justify with "
                 f"`# {_COLLECTIVE_PRAGMA}`"
             )
     return violations

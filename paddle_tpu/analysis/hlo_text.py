@@ -107,10 +107,12 @@ _SHAPE_RE = re.compile(
 )
 _INSTR_RE = re.compile(
     r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*"      # instruction name
-    r"((?:\([^()]*\))|\S+)\s+"                   # output shape (or tuple;
-    # tuple shapes nest no parens but DO carry /*index=N*/ comments
-    # from 6 elements up — a [^=] shape matcher loses every big-carry
-    # while loop and tuple-form all-to-all)
+    r"((?:\((?:[^()]|\([^()]*\))*\))|\S+)\s+"   # output shape (or tuple;
+    # tuple shapes carry /*index=N*/ comments from 6 elements up — a
+    # [^=] shape matcher loses every big-carry while loop and
+    # tuple-form all-to-all — and, compiled for a TPU, one level of
+    # parens from the tiled layouts, `{3,2,1,0:T(2,128)(2,1)S(1)}`:
+    # without it every async -start collective of a TPU module is lost)
     r"([\w\-]+)\("                               # opcode
 )
 # instructions that move no HBM bytes of their own: reads are charged

@@ -235,8 +235,8 @@ class BeamSearchDecoder:
         t1 = time.perf_counter()
         # the chain depth is MEASURED: `chunks` is a counter carried
         # through the while-loop state, incremented once per executed
-        # iteration (= one sequential dispatch-chain link on a tunneled
-        # runtime), fetched after the run — never derived from config.
+        # iteration (= one link of the sequential dependency chain),
+        # fetched after the run — never derived from config.
         # The int() fetches BLOCK on the whole jitted while-loop, so
         # they are the device-time window; only the submit window
         # before them is host dispatch work (`last_timeline` is what

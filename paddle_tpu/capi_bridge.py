@@ -15,18 +15,9 @@ with capi/matrix.h-style dense row-major float buffers.
 from __future__ import annotations
 
 import ctypes
-import os
+import itertools
 
 import numpy as np
-
-if os.environ.get("PADDLE_TPU_FORCE_CPU"):
-    # serving hosts without an accelerator (and the CI that exercises the
-    # C ABI) force the CPU backend before jax initializes
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-
-import itertools
 
 _HANDLES: dict = {}
 _NEXT = itertools.count(1)  # atomic under the GIL

@@ -25,22 +25,6 @@ DATA_AXIS = "data"
 MODEL_AXIS = "model"
 SEQ_AXIS = "seq"
 
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """`jax.shard_map` across jax versions: new runtimes expose it at
-    the top level with `check_vma`; this container's 0.4.37 only has
-    `jax.experimental.shard_map` with the older `check_rep` spelling.
-    One shim so every SPMD entry point (ring/ulysses attention,
-    sharded embedding, pipeline stages) runs on both."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs,
-                  out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as sm_exp
-
-    return sm_exp(f, mesh=mesh, in_specs=in_specs,
-                  out_specs=out_specs, check_rep=check_vma)
-
 _current_mesh: Optional[Mesh] = None
 
 
@@ -89,29 +73,14 @@ def get_mesh() -> Mesh:
     return _current_mesh
 
 
-def _enable_cpu_collectives() -> None:
-    """jax>=0.4.30 CPU backends refuse cross-process computations
-    ("Multiprocess computations aren't implemented on the CPU
-    backend") unless a collectives implementation is configured BEFORE
-    the backend is created. When this jaxlib ships the gloo TCP
-    collectives, turn them on so the multi-process CPU smoke path
-    (launch/test_distributed_multiprocess) runs like it did on older
-    runtimes. No-op on TPU/GPU platforms and on jaxlibs without gloo."""
-    try:
-        from jax._src.lib import xla_client as _xc
-
-        if not hasattr(_xc._xla, "make_gloo_tcp_collectives"):
-            return
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # pragma: no cover - best-effort compat shim
-        pass
-
-
 def distributed_init(coordinator_address=None, num_processes=None, process_id=None):
     """Multi-host control-plane bootstrap (replaces etcd registration of
     go/pserver/etcd_client.go and the sockets of pserver/LightNetwork.h)."""
     if coordinator_address is not None:
-        _enable_cpu_collectives()
+        # the CPU backend runs cross-process programs only over gloo,
+        # and the option is read when the backend is created; it has
+        # no effect on a TPU
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
         jax.distributed.initialize(
             coordinator_address=coordinator_address,
             num_processes=num_processes,

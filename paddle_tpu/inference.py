@@ -12,11 +12,11 @@ TPU-native deployment artifacts:
   binary to the pure-C runtime — and `load_compiled` runs it without
   the model-building code present.
 - `store_verified` / `load_verified`: the **verified AOT program
-  cache** (ISSUE 16). The stock persistent XLA compilation cache was
-  observed deserializing *corrupt* executables on this runtime
-  (tests/conftest.py documents the heap corruption), so the only
-  trustworthy fast-boot path is one we verify ourselves: every cache
-  entry carries sha256 digests over all of its files, the compiled
+  cache** (ISSUE 16). The stock persistent XLA compilation cache
+  checks nothing it reloads (an earlier runtime was seen handing back
+  corrupt executables), so the only trustworthy fast-boot path is one
+  we verify ourselves: every cache entry carries sha256 digests over
+  all of its files, the compiled
   program's HLO text, and a policy audited by `analysis/hlo_audit` —
   a replica may only boot from an entry whose digests match AND whose
   HLO passes the audit gate. Entries are published atomically (write
@@ -26,10 +26,13 @@ TPU-native deployment artifacts:
 
 from __future__ import annotations
 
+import logging
+
 from paddle_tpu.core.arg import Arg
 from paddle_tpu.trainer.trainer import Inferencer
 
 Inference = Inferencer  # v2 name
+logger = logging.getLogger(__name__)
 
 __all__ = ["Inference", "Inferencer", "infer", "export_compiled",
            "load_compiled", "CompiledArtifactError", "VerifiedCacheError",
@@ -251,11 +254,13 @@ def store_verified(cache_dir: str, key: str, fn, example_args: tuple,
     from jax.experimental.serialize_executable import serialize
 
     from paddle_tpu.analysis import hlo_audit as _audit
+    from paddle_tpu.core import compile_cache as _compile_cache
 
     policy = dict(policy or {})
     _register_arg_serialization()
     jitted = jax.jit(fn)
-    compiled = jitted.lower(*example_args).compile()
+    with _compile_cache.bypassed():  # serialized below: compile fresh
+        compiled = jitted.lower(*example_args).compile()
     hlo_text = compiled.as_text()
     exec_payload = pickle.dumps(serialize(compiled))
     shlo_payload = jexport.export(jitted)(*example_args).serialize()
@@ -284,6 +289,8 @@ def store_verified(cache_dir: str, key: str, fn, example_args: tuple,
             "key": key,
             "created_unix": time.time(),
             "jax_version": jax.__version__,
+            "num_devices": len(
+                compiled.runtime_executable().local_devices()),
             "policy": policy,
             "files": {
                 name: _sha256_file(os.path.join(tmp, name))
@@ -388,30 +395,44 @@ def load_verified(cache_dir: str, key: str,
         raise VerifiedCacheError(
             "audit", f"entry {key!r} fails the boot policy gate: {bad}")
     # digests + audit passed: the bytes may now reach XLA. Fast path =
-    # the serialized executable; version skew falls back to the
-    # portable StableHLO export (which recompiles on first call).
+    # the serialized executable, loaded onto as many local devices as
+    # it was compiled for (left to itself the loader spans EVERY local
+    # device and the first call fails on its shard count). An
+    # executable this runtime cannot load (another jaxlib, another
+    # chip) falls back to the portable StableHLO export, which
+    # recompiles on first call — logged and counted, never silent.
+    import jax
+    from jax.experimental.serialize_executable import (
+        deserialize_and_load,
+    )
+
+    from paddle_tpu import obs as _obs
+
     exec_path = os.path.join(entry, "program.exec")
     with open(exec_path, "rb") as f:
         exec_blob = f.read()
+    payload = _unwrap_envelope(exec_blob, exec_path,
+                               require_envelope=True)
+    devices = jax.local_devices()[:int(meta.get("num_devices", 1))]
+    loads = _obs.get_registry().counter("inference.verified_loads")
     try:
-        from jax.experimental.serialize_executable import (
-            deserialize_and_load,
-        )
-
-        payload = _unwrap_envelope(exec_blob, exec_path,
-                                   require_envelope=True)
         exe, in_tree, out_tree = pickle.loads(payload)
-        compiled = deserialize_and_load(exe, in_tree, out_tree)
-        return VerifiedProgram(compiled, "exec", meta, audit)
-    except CompiledArtifactError:
-        raise  # digest said clean but the envelope didn't: refuse
-    except Exception:
-        with open(os.path.join(entry, "program.shlo"), "rb") as f:
+        compiled = deserialize_and_load(
+            exe, in_tree, out_tree, execution_devices=devices)
+    except Exception as e:  # noqa: BLE001 - any loader refusal
+        logger.warning(
+            "verified cache entry %r: executable did not load on %s "
+            "(%s: %s); recompiling from the StableHLO export",
+            key, devices, type(e).__name__, e)
+        shlo_path = os.path.join(entry, "program.shlo")
+        with open(shlo_path, "rb") as f:
             shlo_blob = f.read()
-        call = load_compiled(shlo_blob,
-                             source=os.path.join(entry, "program.shlo"),
+        call = load_compiled(shlo_blob, source=shlo_path,
                              require_envelope=True)
+        loads.inc(via="shlo")
         return VerifiedProgram(call, "shlo", meta, audit)
+    loads.inc(via="exec")
+    return VerifiedProgram(compiled, "exec", meta, audit)
 
 
 def infer(output=None, parameters=None, input=None, network=None,

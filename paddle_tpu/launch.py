@@ -98,6 +98,8 @@ def launch(
     coord_host = hosts[0] if not _is_local(hosts[0]) else "127.0.0.1"
     coord = f"{coord_host}:{coordinator_port}"
 
+    # This launcher never imports JAX: a parent that had touched it
+    # would hold the host's chips, and the workers need them.
     def _spawn(host, rank):
         env_kv = {
             "PADDLE_COORDINATOR": coord,

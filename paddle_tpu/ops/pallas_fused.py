@@ -34,9 +34,7 @@ import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 
-
-def _interpret() -> bool:
-    return jax.devices()[0].platform != "tpu"
+from paddle_tpu import ops as _ops
 
 
 def _block_rows(n: int, cin: int, cout: int, itemsize: int = 2) -> int:
@@ -146,7 +144,7 @@ def _fused_fwd_impl(u, scale, shift, w, res, relu):
         args.append(_pad_rows(res, bn)[0])
     y, s1, s2 = _fwd_call(
         n, n_pad, bn, cin, cout, u.dtype, relu, res is not None,
-        _interpret(),
+        _ops.pallas_interpret(),
     )(*args)
     return y[:n], s1[0], s2[0]
 
@@ -248,7 +246,7 @@ def _bwd_impl(relu, has_res, residuals, cotangents):
     y_p, _ = _pad_rows(y, bn)
     dy_p, _ = _pad_rows(dy, bn)
     grid = (n_pad // bn,)
-    interpret = _interpret()
+    interpret = _ops.pallas_interpret()
     s2d = scale.reshape(1, cin).astype(jnp.float32)
     t2d = shift.reshape(1, cin).astype(jnp.float32)
     d1_2d = d1.reshape(1, cout).astype(jnp.float32)

@@ -38,12 +38,17 @@ LSTM [i, f, g, o] with peepholes (wci, wcf, wco); GRU [u, r | c].
 from __future__ import annotations
 
 import functools
+import logging
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from paddle_tpu import ops as _ops
+
+logger = logging.getLogger(__name__)
 
 _VMEM_BUDGET = 10 * 1024 * 1024  # soft planning budget (VMEM is ~16MB)
 # the backward keeps BOTH w and the resident dW accumulator in VMEM
@@ -54,6 +59,39 @@ _VMEM_BUDGET_BWD = 13 * 1024 * 1024
 
 def _round8(n: int) -> int:
     return -(-n // 8) * 8
+
+
+_FALLBACKS_SAID: set = set()
+
+
+def _fell_back(kernel: str, why: str, x) -> None:
+    """A caller asked for `kernel` and gets the lax.scan reference.
+    Counted every time in obs (`pallas_rnn.fallbacks`, label
+    `kernel`) and logged once per (kernel, shape). It runs while the
+    program is traced, so the count is of traced programs, not of
+    steps — a run whose counter moved did not run that kernel."""
+    from paddle_tpu import obs
+
+    obs.get_registry().counter("pallas_rnn.fallbacks").inc(kernel=kernel)
+    key = (kernel, tuple(x.shape), str(x.dtype))
+    if key not in _FALLBACKS_SAID:
+        _FALLBACKS_SAID.add(key)
+        logger.warning(
+            "pallas_rnn: %s was asked for at x=%s %s and is NOT used "
+            "(%s); the lax.scan reference runs instead",
+            kernel, tuple(x.shape), x.dtype, why,
+        )
+
+
+def _ref_like_kernel(ref, x, *rest):
+    """The scan reference under the kernels' dtype rule: compute in
+    float32, return x's dtype. The bare references need one dtype
+    throughout (bf16 activations against f32 weights break the scan
+    carry), which the kernels never did."""
+    *ws, lens = rest
+    f32 = jnp.float32
+    y = ref(x.astype(f32), *(w.astype(f32) for w in ws), lens)
+    return y.astype(x.dtype)
 
 
 def _plan(b: int, t: int, h: int, tok_bytes: int, fixed_bytes: int,
@@ -317,6 +355,7 @@ def _lstm_fwd_pallas(x, w, b7, lens, *, interpret, want_c):
     h = h4 // 4
     plan = _lstm_plan(bsz, t_max, h)
     if plan is None:
+        _fell_back("lstm_fwd", "no block plan fits VMEM", x)
         return None
     bb, tb, bp, tp = plan
     if orig == jnp.bfloat16:
@@ -347,7 +386,7 @@ def _lstm_fwd_pallas(x, w, b7, lens, *, interpret, want_c):
             pltpu.VMEM((bb, h), jnp.float32),
             pltpu.VMEM((bb, h), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=_ops.pallas_interpret(interpret),
     )(xp, w, b7, lensp)
     if want_c:
         y, c = out
@@ -362,6 +401,7 @@ def _lstm_bwd_pallas(x, w, b7, lens, y, c_seq, dy, *, interpret):
     h = h4 // 4
     plan = _lstm_bwd_plan(bsz, t_max, h)
     if plan is None:
+        _fell_back("lstm_bwd", "no block plan fits VMEM", x)
         return None
     bb, tb, bp, tp = plan
     # measured on v5e: with bb < 32 the per-step [bb,h]@[h,4h] matmul
@@ -370,6 +410,7 @@ def _lstm_bwd_pallas(x, w, b7, lens, y, c_seq, dy, *, interpret):
     # kernel wins 1.56x) — fall back unless the batch block is wide.
     # interpret mode (CPU tests) keeps the kernel path regardless.
     if bb < 32 and not interpret:
+        _fell_back("lstm_bwd", f"batch block {bb} < 32", x)
         return None
     f32 = jnp.float32
     # everything in f32 inside the kernel — including w/b7, matching
@@ -418,7 +459,7 @@ def _lstm_bwd_pallas(x, w, b7, lens, y, c_seq, dy, *, interpret):
             pltpu.VMEM((bb, tb, h), f32),
             pltpu.VMEM((1, 7 * h), f32),
         ],
-        interpret=interpret,
+        interpret=_ops.pallas_interpret(interpret),
     )(xp, w, b7, lensp, yp_, yp_, cp_, cp_, dyp)
     return dx[:bsz, :t_max].astype(orig), dw, db7
 
@@ -431,7 +472,7 @@ def lstm_fused(x, w, gb, wci, wcf, wco, lens, interpret=False):
         want_c=False,
     )
     if out is None:  # weights too large for VMEM: scan is MXU-bound
-        return lstm_ref(x, w, gb, wci, wcf, wco, lens)
+        return _ref_like_kernel(lstm_ref, x, w, gb, wci, wcf, wco, lens)
     return out[0]
 
 
@@ -442,7 +483,7 @@ def _lstm_fused_fwd(x, w, gb, wci, wcf, wco, lens, interpret):
         want_c=True,
     )
     if out is None:
-        y = lstm_ref(x, w, gb, wci, wcf, wco, lens)
+        y = _ref_like_kernel(lstm_ref, x, w, gb, wci, wcf, wco, lens)
         return y, (x, w, gb, wci, wcf, wco, lens, None, None)
     y, c_seq = out
     return y, (x, w, gb, wci, wcf, wco, lens, y, c_seq)
@@ -464,7 +505,10 @@ def _lstm_fused_bwd(interpret, res, dy):
             dwcf = db7[0, 5 * h : 6 * h].astype(wcf.dtype)
             dwco = db7[0, 6 * h : 7 * h].astype(wco.dtype)
             return (dx, dw.astype(w.dtype), dgb, dwci, dwcf, dwco, None)
-    _, vjp = jax.vjp(lambda *a: lstm_ref(*a, lens), x, w, gb, wci, wcf, wco)
+    _, vjp = jax.vjp(
+        lambda *a: _ref_like_kernel(lstm_ref, *a, lens),
+        x, w, gb, wci, wcf, wco,
+    )
     return (*vjp(dy), None)
 
 
@@ -660,10 +704,12 @@ def _gru_bwd_pallas(x, w_g, w_c, b, lens, y, dy, *, interpret):
     h = h3 // 3
     plan = _gru_bwd_plan(bsz, t_max, h)
     if plan is None:
+        _fell_back("gru_bwd", "no block plan fits VMEM", x)
         return None
     bb, tb, bp, tp = plan
     # same MXU-fill gate as the LSTM backward (measured on v5e)
     if bb < 32 and not interpret:
+        _fell_back("gru_bwd", f"batch block {bb} < 32", x)
         return None
     f32 = jnp.float32
     wg_dt, wc_dt = w_g.dtype, w_c.dtype  # cotangents match the primals
@@ -711,7 +757,7 @@ def _gru_bwd_pallas(x, w_g, w_c, b, lens, y, dy, *, interpret):
             pltpu.VMEM((bb, tb, h), f32),
             pltpu.VMEM((1, 3 * h), f32),
         ],
-        interpret=interpret,
+        interpret=_ops.pallas_interpret(interpret),
     )(xp, w_g, w_c, b2, lensp, yp_, yp_, dyp)
     return (
         dx[:bsz, :t_max].astype(orig),
@@ -727,6 +773,7 @@ def _gru_fwd_kernel(x, w_g, w_c, b, lens, *, interpret):
     h = h3 // 3
     plan = _gru_plan(bsz, t_max, h)
     if plan is None:
+        _fell_back("gru_fwd", "no block plan fits VMEM", x)
         return None
     bb, tb, bp, tp = plan
     if orig == jnp.bfloat16:
@@ -749,7 +796,7 @@ def _gru_fwd_kernel(x, w_g, w_c, b, lens, *, interpret):
         out_specs=pl.BlockSpec((bb, tb, h), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((bp, tp, h), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bb, h), jnp.float32)],
-        interpret=interpret,
+        interpret=_ops.pallas_interpret(interpret),
     )(xp, w_g, w_c, b[None, :], lensp)
     return y[:bsz, :t_max].astype(orig)
 
@@ -760,7 +807,7 @@ def gru_fused(x, w_g, w_c, b, lens, interpret=False):
         x, w_g, w_c, b, lens[:, None].astype(jnp.int32), interpret=interpret
     )
     if y is None:  # weights too large for VMEM
-        return gru_ref(x, w_g, w_c, b, lens)
+        return _ref_like_kernel(gru_ref, x, w_g, w_c, b, lens)
     return y
 
 
@@ -781,17 +828,10 @@ def _gru_fused_bwd(interpret, res, dy):
         if out is not None:
             dx, dwg, dwc, db3 = out
             return (dx, dwg, dwc, db3.astype(b.dtype), None)
-    _, vjp = jax.vjp(lambda *a: gru_ref(*a, lens), x, w_g, w_c, b)
+    _, vjp = jax.vjp(
+        lambda *a: _ref_like_kernel(gru_ref, *a, lens), x, w_g, w_c, b
+    )
     return (*vjp(dy), None)
 
 
 gru_fused.defvjp(_gru_fused_fwd, _gru_fused_bwd)
-
-
-def use_fused_default() -> bool:
-    """Auto policy: fused kernels on real TPU, scan elsewhere."""
-    try:
-        plat = jax.devices()[0].platform
-    except Exception:
-        return False
-    return plat not in ("cpu", "gpu")

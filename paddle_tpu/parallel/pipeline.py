@@ -26,18 +26,15 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from paddle_tpu.core.mesh import shard_map as _shard_map
 
 
-def _pipeline_local(stage_fn, axis_name, params, xs, n_stages):
+def _pipeline_local(stage_fn, axis_name, params, xs):
     """Runs under shard_map: `params` is THIS device's stage slice (no
     stage axis), `xs` [M, ...] the full microbatch stream (replicated).
     Returns [M, ...] outputs, valid on the LAST stage (zeros elsewhere,
-    all-gathered by the caller). `n_stages` is the static axis size
-    (lax.axis_size is missing on this runtime's jax 0.4.37, and the
-    tick count must be static anyway)."""
+    all-gathered by the caller)."""
     idx = lax.axis_index(axis_name)
-    S = n_stages
+    S = lax.axis_size(axis_name)  # static: sets the tick count
     M = xs.shape[0]
     T = M + S - 1  # total ticks to drain the pipe
 
@@ -101,10 +98,9 @@ def pipeline_apply(
     def local(params, xs):
         # shard_map hands us the [1, ...]-sliced stage params
         params = jax.tree_util.tree_map(lambda a: a[0], params)
-        return _pipeline_local(stage_fn, axis_name, params, xs,
-                               mesh.shape[axis_name])
+        return _pipeline_local(stage_fn, axis_name, params, xs)
 
-    return _shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=in_specs,

@@ -41,7 +41,6 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from paddle_tpu.core.mesh import SEQ_AXIS
-from paddle_tpu.core.mesh import shard_map as _shard_map
 
 NEG_INF = -1e30
 
@@ -469,14 +468,14 @@ def ring_attention(
         )
 
     if kv_lens is None:
-        return _shard_map(
+        return jax.shard_map(
             lambda a, c, d: local(a, c, d, None),
             mesh=mesh,
             in_specs=(spec, spec, spec),
             out_specs=spec,
             check_vma=False,
         )(q, k, v)
-    return _shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(spec, spec, spec, P(b)),
@@ -521,14 +520,14 @@ def ulysses_attention(
     b = _batch_axis(mesh)
     spec = P(b, axis, None, None)
     if kv_lens is None:
-        return _shard_map(
+        return jax.shard_map(
             lambda x, y, z: local(x, y, z, None),
             mesh=mesh,
             in_specs=(spec, spec, spec),
             out_specs=spec,
             check_vma=False,
         )(q, k, v)
-    return _shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(spec, spec, spec, P(b)),

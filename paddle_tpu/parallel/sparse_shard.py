@@ -61,7 +61,6 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from paddle_tpu.core.mesh import MODEL_AXIS, get_mesh
-from paddle_tpu.core.mesh import shard_map as _shard_map
 from paddle_tpu.parallel.sparse import embedding_lookup
 
 _MASK64 = np.uint64(0xFFFFFFFFFFFFFFFF)
@@ -216,11 +215,12 @@ def _push_program(mesh, axis, S, D, M, n_state, dtype):
             return new_cache, new_state
 
         sharded = P(axis, None)
-        fn = _shard_map(
+        fn = jax.shard_map(
             local, mesh=mesh,
             in_specs=(sharded, (sharded,) * n_state, P(), P(),
                       (P(),) * n_state),
             out_specs=(sharded, (sharded,) * n_state),
+            check_vma=False,
         )
         _PROGRAMS[key] = jax.jit(fn, donate_argnums=(0, 1))
     return _PROGRAMS[key]
@@ -260,10 +260,11 @@ def _update_program(mesh, axis, S, D, N, k, n_state, dtype,
             return new_cache, new_state
 
         sharded = P(axis, None)
-        fn = _shard_map(
+        fn = jax.shard_map(
             local, mesh=mesh,
             in_specs=(sharded, (sharded,) * n_state, P(), P(), P()),
             out_specs=(sharded, (sharded,) * n_state),
+            check_vma=False,
         )
         _PROGRAMS[key] = jax.jit(fn, donate_argnums=(0, 1))
     return _PROGRAMS[key]
@@ -306,10 +307,11 @@ def step_program(mesh, axis, S, D, N, k, n_state, dtype, update_fn):
         return out, new_cache, new_state
 
     sharded = P(axis, None)
-    fn = _shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(sharded, (sharded,) * n_state, P(), P(), P(), P()),
         out_specs=(P(), sharded, (sharded,) * n_state),
+        check_vma=False,
     )
     return jax.jit(fn, donate_argnums=(0, 1))
 

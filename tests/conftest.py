@@ -14,35 +14,14 @@ if "xla_force_host_platform_device_count" not in _flags:
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
-# XLA compilation cache, scoped to THIS pytest session: subprocesses
-# spawned by tests (bench smokes, distributed workers, the elastic
-# trainer workers) share it through the exported env var, so the
-# expensive programs compile once per run.
-#
-# The dir is deliberately FRESH per session, not persistent:
-# deserializing cache entries from a previous session corrupts the
-# heap on this runtime ("corrupted double-linked list" / segfault
-# mid-dispatch, reproducibly killing the suite from test_v2_api
-# onward — the seed's 323-dots-then-abort). A cold run costs ~no extra
-# wall clock (the suite is dominated by unique in-process compiles),
-# and concurrent sessions (run_suite.sh shards) can no longer tear
-# each other's shared entries — the likely original poisoner.
-# PADDLE_TPU_TEST_CACHE overrides explicitly (at your own risk).
-XLA_CACHE_DIR = os.environ.get("PADDLE_TPU_TEST_CACHE")
-if not XLA_CACHE_DIR:
-    import atexit
-    import shutil
-    import tempfile
+# One persistent XLA cache for the session, at the fixed place every
+# entry point uses (core/compile_cache.py). It is exported so that the
+# subprocesses tests spawn (bench smokes, distributed workers, the
+# inline replica/trainer sources of testing_faults, which never call
+# the helper) read and write the same directory.
+from paddle_tpu.core import compile_cache  # noqa: E402
 
-    XLA_CACHE_DIR = tempfile.mkdtemp(prefix="paddle_tpu_jax_cache_")
-    # this (main) pytest process outlives every test subprocess that
-    # shares the dir, so cleaning at exit leaks nothing into /tmp
-    atexit.register(shutil.rmtree, XLA_CACHE_DIR, ignore_errors=True)
-jax.config.update("jax_compilation_cache_dir", XLA_CACHE_DIR)
-# subprocess-spawning tests inherit the same cache through the
-# environment — plain assignment so it really is one source of truth
-# even when the outer environment already set a different cache dir
-os.environ["JAX_COMPILATION_CACHE_DIR"] = XLA_CACHE_DIR
+os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", compile_cache.enable())
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.3)
 
 

@@ -40,11 +40,17 @@ def _rand_table(seed, scale=3.0, vocab=V):
 
 
 def _peaked_table(vocab=V):
-    """Sharply peaked chain 0->2->3->eos: every beam finishes at t=3."""
-    t = np.full((vocab, vocab), -5.0, np.float32)
+    """Chain 0->2->3->eos with every tie broken by the table itself:
+    peaks of different height, eos the runner-up of every row, and
+    the losers a whole unit apart by column. The best beam finishes
+    at t=3 and the last one ([0,2,3,eos]) at t=4, each top-4 cut by a
+    margin of ~1.0 — so the step count is a property of the table,
+    not of how a runtime's top_k orders equal candidates."""
+    t = np.tile(-5.0 - np.arange(vocab, dtype=np.float32), (vocab, 1))
+    t[:, EOS] = 0.0
     t[0, 2] = 5.0
-    t[2, 3] = 5.0
-    t[3, EOS] = 5.0
+    t[2, 3] = 6.0
+    t[3, EOS] = 7.0
     return t
 
 
@@ -104,9 +110,9 @@ class TestMultiTokenDispatch:
         assert np.array_equal(s, ref[0])
         assert np.array_equal(l, ref[1])
         assert np.array_equal(sc, ref[2])
-        assert dec.last_steps == ref_dec.last_steps == 5
+        assert dec.last_steps == ref_dec.last_steps == 4
         assert dec.last_chain_depth == 1
-        assert ref_dec.last_chain_depth == 5
+        assert ref_dec.last_chain_depth == 4
 
     def test_seq2seq_attention_bit_identical(self):
         """The real conditioned decoder (statics + boot memory +

@@ -75,6 +75,8 @@ print(f"TOTAL {float(total):.8f}", flush=True)
 
 
 def _run_phase(phase, port, ckpt_dir):
+    # the two workers are CPU processes by design (JAX_PLATFORMS below):
+    # the pytest parent has touched JAX and would hold a chip
     procs = []
     for pid in range(2):
         env = dict(

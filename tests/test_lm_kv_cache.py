@@ -51,11 +51,24 @@ def _prompts(b=3, t0=11, seed=0, spec=SPEC):
 
 
 def _plm(params, spec=SPEC, num_pages=64, page_size=4,
-         max_pages_per_seq=16):
+         max_pages_per_seq=16, eos_id=EOS):
     cache = PagedKVCache(spec, num_pages=num_pages,
                          page_size=page_size,
                          max_pages_per_seq=max_pages_per_seq)
-    return PagedLM(spec, params, cache, eos_id=EOS)
+    return PagedLM(spec, params, cache, eos_id=eos_id)
+
+
+def _unemitted_eos(params, ids, lens, max_new):
+    """An eos id the greedy walk over these prompts never emits, so
+    that every request runs its whole `max_new` whatever the
+    initialiser drew (the scheduling tests below need requests that
+    stay alive; a seed-0 weight that happens to emit EOS at t=1 under
+    one PRNG and not another must not decide them). The probe decodes
+    with an out-of-vocabulary eos, which can never match."""
+    free, _ = lmm.greedy_decode_recompute(
+        SPEC, params, ids, lens, max_new, SPEC.vocab
+    )
+    return int(np.setdiff1d(np.arange(2, SPEC.vocab), free)[0])
 
 
 class TestFunctionalForward:
@@ -208,10 +221,12 @@ class TestEngine:
         output."""
         ids, lens = _prompts()
         max_new = 8
+        eos = _unemitted_eos(params, ids, lens, max_new)
         ref_t, _ = lmm.greedy_decode_recompute(
-            SPEC, params, ids, lens, max_new, EOS
+            SPEC, params, ids, lens, max_new, eos
         )
-        eng = LMEngine(_plm(params), slots=2, max_new=max_new)
+        eng = LMEngine(_plm(params, eos_id=eos), slots=2,
+                       max_new=max_new)
         rids = [eng.submit(ids[i, :lens[i]]) for i in range(3)]
         eng.run()
         for i, rid in enumerate(rids):
@@ -227,11 +242,12 @@ class TestEngine:
         one re-enters later, byte-identical."""
         ids, lens = _prompts()
         max_new = 8
+        eos = _unemitted_eos(params, ids, lens, max_new)
         ref_t, _ = lmm.greedy_decode_recompute(
-            SPEC, params, ids, lens, max_new, EOS
+            SPEC, params, ids, lens, max_new, eos
         )
         # 12 pages: not enough for all three fully-grown + scratch
-        plm = _plm(params, num_pages=12)
+        plm = _plm(params, num_pages=12, eos_id=eos)
         eng = LMEngine(plm, slots=3, max_new=max_new)
         rids = [eng.submit(ids[i, :lens[i]]) for i in range(3)]
         eng.run()

@@ -439,6 +439,68 @@ class TestSparseUpdaterKernel:
             np.testing.assert_allclose(out[i], -np.ones(D), atol=1e-6)
 
 
+def test_sparse_updater_other_dtype_compiles_anew():
+    """The compiled steps are kept per argument shape AND dtype: the
+    same shapes with ids of another integer width get a program of
+    their own, as a retracing jit would give, not a type error."""
+    from paddle_tpu.parallel.sparse import SparseUpdater
+
+    def upd(p, g):
+        return p - g
+
+    V, D, N = 32, 4, 8
+    u = SparseUpdater(upd)
+    param = u.place(np.zeros((V, D), np.float32))
+    grads = jnp.ones((N, D), jnp.float32)
+    ids = np.arange(N)
+    param, _ = u(param, jnp.asarray(ids, jnp.int32), grads)
+    param, _ = u(param, jnp.asarray(ids, jnp.int16), grads)
+    assert len(u._steps) == 2
+    out = u.unplace(param)
+    np.testing.assert_allclose(out[:N], -2 * np.ones((N, D)), atol=1e-6)
+    assert not out[N:].any()
+
+
+def test_compile_cache_bypass_overlapping_threads():
+    """`bypassed()` flips a process-global option. Two blocks that
+    overlap on two threads, leaving in the order they entered, must
+    keep the cache off until the last one leaves and then put back what
+    was there before the first."""
+    import threading
+
+    import jax
+
+    from paddle_tpu.core import compile_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    a_in, b_in, a_out = (threading.Event() for _ in range(3))
+    seen = {}
+
+    def first():
+        with compile_cache.bypassed():
+            a_in.set()
+            b_in.wait(10)
+        seen["after_first_left"] = jax.config.jax_enable_compilation_cache
+        a_out.set()
+
+    def second():
+        a_in.wait(10)
+        with compile_cache.bypassed():
+            b_in.set()
+            a_out.wait(10)
+            with compile_cache.bypassed():  # nested on one thread
+                pass
+            seen["inside_second"] = jax.config.jax_enable_compilation_cache
+
+    threads = [threading.Thread(target=f) for f in (first, second)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(30)
+    assert seen == {"after_first_left": False, "inside_second": False}
+    assert jax.config.jax_enable_compilation_cache == before
+
+
 def test_sparse_updater_run_steps_matches_sequential():
     """run_steps (n updates fused into one dispatch — the amortized
     bench/catchUpWith path) must equal n sequential __call__ steps."""

@@ -236,8 +236,7 @@ class TestCAPI:
         assert r.returncode == 0, r.stderr.decode()
 
         env = dict(os.environ)
-        env["PADDLE_TPU_FORCE_CPU"] = "1"
-        env.pop("JAX_PLATFORMS", None)
+        env["JAX_PLATFORMS"] = "cpu"  # the embedding program decides
         r = subprocess.run(
             [exe, lib, REPO, merged],
             capture_output=True,
@@ -284,8 +283,7 @@ def _build_example(name, tmp_path):
 
 def _run_example(exe, *args, timeout=300):
     env = dict(os.environ)
-    env["PADDLE_TPU_FORCE_CPU"] = "1"
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"  # the embedding program decides
     return subprocess.run(
         [exe, *args], capture_output=True, env=env, timeout=timeout
     )

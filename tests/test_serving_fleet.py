@@ -646,14 +646,19 @@ class TestFleetFaults:
             "jax.config.update('jax_platforms', 'cpu')\n"
             "from paddle_tpu import inference, testing_faults\n"
             "print('GO', flush=True)\n"
-            "fn = testing_faults.replica_program_fn(64, 256)\n"
+            # ~8 s of compile on this box: 0.8 s in is mid-compile
+            # with room (64 layers took ~1.2 s and raced the kill)
+            "fn = testing_faults.replica_program_fn(1000, 256)\n"
             "inference.store_verified(\n"
             f"    {cache!r}, 'k', fn,\n"
             "    (np.zeros((1, 8), np.float32),))\n"
         )
         proc = subprocess.Popen(
             [sys.executable, "-c", src], cwd=REPO,
-            env=dict(os.environ, JAX_PLATFORMS="cpu"),
+            # the kill has to land mid-compile: the persistent cache
+            # would hand the program over in milliseconds
+            env=dict(os.environ, JAX_PLATFORMS="cpu",
+                     JAX_ENABLE_COMPILATION_CACHE="false"),
             stdout=subprocess.PIPE, text=True)
         assert proc.stdout.readline().startswith("GO")
         time.sleep(0.8)  # mid-compile / mid-write

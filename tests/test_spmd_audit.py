@@ -407,6 +407,29 @@ ENTRY %main (p: (s32[], f32[64])) -> (s32[], f32[64]) {
         assert len(colls) == 1
         assert colls[0]["kind"] == "all-reduce"
 
+    def test_tpu_tiled_layouts_in_a_tuple_shape(self):
+        """Compiled for a TPU, layouts carry tilings with parens of
+        their own — `T(2,128)(2,1)S(1)` — inside the tuple shape of
+        every async -start op. Lines as the v5e compiler printed them
+        for ring attention (PR 21): the parser used to see none."""
+        tiled = "bf16[2,2,2,8]{3,2,1,0:T(2,128)(2,1)S(1)}"
+        lines = [
+            "ENTRY %main (p0: bf16[2,2,2,8]) -> bf16[2,2,2,8] {",
+            f"  %p0 = {tiled} parameter(0)",
+            f"  %collective-permute-start = ({tiled}, {tiled}, "
+            "u32[]{:S(2)}, u32[]{:S(2)}) collective-permute-start("
+            "%p0), channel_id=1, "
+            "source_target_pairs={{0,1},{1,2},{2,3},{3,0}}",
+            f"  ROOT %collective-permute-done = {tiled} "
+            "collective-permute-done(%collective-permute-start)",
+            "}",
+        ]
+        colls = hlo_text.parse_collectives(lines)
+        assert [c["kind"] for c in colls] == ["collective-permute"]
+        assert colls[0]["source_target_pairs"] == [
+            (0, 1), (1, 2), (2, 3), (3, 0)
+        ]
+
     def test_nested_tuple_alias_map(self):
         """input_output_alias with nested tuple indices on both
         sides."""

@@ -1,0 +1,212 @@
+"""The Pallas kernels of the main path, compiled by the TPU's own
+compiler for a described (not attached) v5e chip, at the widths the
+chip smoke and the benchmark rows use.
+
+Interpret mode — what every other kernel test here runs — cannot see
+what Mosaic refuses: a block that breaks the (8, 128) tiling rule, a
+kernel over the scoped-VMEM limit, a program that does not fit HBM.
+These compiles can, at about two seconds each and no chip time. Nothing
+runs: they say nothing about results or speed, and a pass here is not a
+chip run (`chip_smoke.py` is).
+
+Rules this file keeps (on-chip-measurement guide, section 2): the
+topology is described inside a fixture, never while a module is
+imported (only one process may hold the TPU library; the suite runs
+under several workers); all such tests live in this one file; no child
+processes; the persistent compile cache is off around them (an
+executable compiled for a described chip cannot be read back without
+one)."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 - any refusal means "skip"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *shapes):
+    """TPU-compiled HLO text of fn at the given (sharded) shapes."""
+    return jax.jit(fn).lower(*shapes).compile().as_text()
+
+
+def _has_kernel(text: str) -> bool:
+    return "tpu_custom_call" in text
+
+
+# (B, T, H, D, dtype): the LM prefill bucket chip_smoke serves, then
+# the long-context, NMT-probe and wide-head shapes of the bench rows
+FLASH_SHAPES = [
+    (2, 1024, 4, 64, jnp.float32),
+    (4, 4096, 8, 64, jnp.bfloat16),
+    (256, 32, 8, 64, jnp.bfloat16),
+    (8, 1024, 16, 128, jnp.bfloat16),
+]
+
+
+@pytest.mark.parametrize("b,t,h,d,dtype", FLASH_SHAPES)
+def test_flash_attention_forward_and_gradient(one_chip, b, t, h, d, dtype):
+    from paddle_tpu.parallel import ring
+
+    qkv = jax.ShapeDtypeStruct((b, t, h, d), dtype, sharding=one_chip)
+    lens = jax.ShapeDtypeStruct((b,), jnp.int32, sharding=one_chip)
+
+    def fwd(q, k, v, n):
+        return ring.flash_dense_attention(q, k, v, causal=True, kv_len=n,
+                                          impl="pallas")
+
+    def loss(q, k, v, n):
+        return jnp.sum(fwd(q, k, v, n).astype(jnp.float32))
+
+    assert _has_kernel(_compile(fwd, qkv, qkv, qkv, lens))
+    assert _has_kernel(_compile(jax.grad(loss, argnums=(0, 1, 2)),
+                                qkv, qkv, qkv, lens))
+
+
+# the three 1x1 sites of ResNet-50 at bs=256: rows = B*H*W
+RESNET_1X1 = [(802816, 64, 256), (50176, 256, 1024), (12544, 512, 2048)]
+
+
+@pytest.mark.parametrize("n,cin,cout", RESNET_1X1)
+def test_bn_act_conv1x1_forward_and_gradient(one_chip, monkeypatch,
+                                             n, cin, cout):
+    from paddle_tpu import ops
+    from paddle_tpu.ops.pallas_fused import bn_act_conv1x1
+
+    # the kernel asks the default backend, which is the CPU here; the
+    # compile is for the chip, where interpret mode does not exist
+    monkeypatch.setattr(ops, "pallas_interpret",
+                        lambda requested=None: False)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    u = sds((n, cin), jnp.bfloat16)
+    vec = sds((cin,), jnp.float32)
+    w = sds((cin, cout), jnp.float32)
+
+    def fwd(u, scale, shift, w, res):
+        return bn_act_conv1x1(u, scale, shift, w, residual=res)
+
+    def loss(u, scale, shift, w, res):
+        y, s1, s2 = fwd(u, scale, shift, w, res)
+        return (jnp.sum(y.astype(jnp.float32)) + jnp.sum(s1)
+                + jnp.sum(s2))
+
+    assert _has_kernel(_compile(fwd, u, vec, vec, w, u))
+    assert _has_kernel(_compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
+                                u, vec, vec, w, u))
+
+
+def test_sparse_updater_row_kernel(one_chip):
+    """The in-place row update at chip_smoke's CTR step: 2**20 rows of
+    64, 16384 touched slots, one optimizer slot."""
+    from paddle_tpu.parallel.sparse import SparseUpdater
+
+    v, d, k = 1 << 20, 64, 256 * 64
+
+    def momentum(p, g, m):
+        m2 = 0.9 * m + g
+        return p - 0.01 * m2, m2
+
+    upd = SparseUpdater(momentum, interpret=False)
+    step = upd._one_step(upd._make_call(v, d, k, 1, jnp.float32), v, k)
+    table = jax.ShapeDtypeStruct((v + 1, 1, d), jnp.float32,
+                                 sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((256, 64), jnp.int32, sharding=one_chip)
+    grads = jax.ShapeDtypeStruct((k, d), jnp.float32, sharding=one_chip)
+    text = _compile(lambda p, m, i, g: step(p, (m,), i, g),
+                    table, table, ids, grads)
+    assert _has_kernel(text)
+
+
+def _fallbacks() -> float:
+    from paddle_tpu import obs
+    from paddle_tpu.obs.aggregate import family_total
+
+    return family_total(obs.get_registry().snapshot()["counters"],
+                        "pallas_rnn.fallbacks")
+
+
+def _rnn_shapes(one_chip, b, t, h, mult, dtype=jnp.float32):
+    def sds(shape, dt=dtype):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    return sds, sds((b, t, mult * h)), sds((b,), jnp.int32)
+
+
+def test_fused_lstm_and_gru_hold_their_kernels(one_chip):
+    """b=64, t=100, h=256 (the reference's LSTM benchmark width): both
+    fused cells, forward and gradient, compile WITH their kernels and
+    report no fallback."""
+    from paddle_tpu.ops import pallas_rnn as pr
+
+    b, t, h = 64, 100, 256
+    before = _fallbacks()
+    sds, x4, lens = _rnn_shapes(one_chip, b, t, h, 4)
+    lstm_args = (x4, sds((h, 4 * h)), sds((4 * h,)), sds((h,)),
+                 sds((h,)), sds((h,)), lens)
+
+    def lstm(*a):
+        return pr.lstm_fused(*a, False)
+
+    assert _has_kernel(_compile(lstm, *lstm_args))
+    assert _has_kernel(_compile(
+        jax.grad(lambda *a: jnp.sum(lstm(*a)), argnums=(0, 1)),
+        *lstm_args))
+
+    _, x3, _ = _rnn_shapes(one_chip, b, t, h, 3)
+    gru_args = (x3, sds((h, 2 * h)), sds((h, h)), sds((3 * h,)), lens)
+
+    def gru(*a):
+        return pr.gru_fused(*a, False)
+
+    assert _has_kernel(_compile(gru, *gru_args))
+    assert _has_kernel(_compile(
+        jax.grad(lambda *a: jnp.sum(gru(*a)), argnums=(0, 1)),
+        *gru_args))
+    assert _fallbacks() == before
+
+
+def test_fused_lstm_that_falls_back_says_so(one_chip):
+    """b=128, t=32, h=512: the gradient kernel's batch block comes out
+    under 32 rows and the scan reference runs instead. That has to be
+    visible — the counter moves — and, fed bf16 activations against
+    f32 weights, the reference has to compile at all (its scan carry
+    used to break)."""
+    from paddle_tpu.ops import pallas_rnn as pr
+
+    b, t, h = 128, 32, 512
+    sds, x4, lens = _rnn_shapes(one_chip, b, t, h, 4, jnp.bfloat16)
+    f32 = jnp.float32
+    args = (x4, sds((h, 4 * h), f32), sds((4 * h,), f32), sds((h,), f32),
+            sds((h,), f32), sds((h,), f32), lens)
+    before = _fallbacks()
+    _compile(
+        jax.grad(lambda *a: jnp.sum(pr.lstm_fused(*a, False)
+                                    .astype(jnp.float32)),
+                 argnums=(0, 1)),
+        *args)
+    assert _fallbacks() > before
