@@ -55,14 +55,15 @@ _DEFAULTS: dict[str, Any] = {
     # RecompileError from inside the retrace — the bench/CI mode.
     "recompile_guard": "off",
     # per-step timeline attribution (obs/timeline.py): fence the
-    # device with block_until_ready every N steps so device_step is
-    # measured end-to-end while steady-state dispatch stays async.
-    # 0 = never fence (fetches the loop makes anyway still count).
+    # device with block_until_ready every N steps (span `train.fence`)
+    # so device_step is measured end-to-end while steady-state
+    # dispatch stays async. 0 = never fence (fetches the loop makes
+    # anyway still count). It samples nothing else: the trainer spans
+    # every step.
     "timeline_sample_period": 16,
-    # distributed tracing (obs/tracing.py): trainer step spans ride
-    # the timeline_sample_period fences; serving traces every request
-    # that arrives WITH a carrier, plus every Nth anonymous request
-    # when trace_serve_period > 0 (0 = carrier-bearing only)
+    # distributed tracing (obs/tracing.py): serving traces every
+    # request that arrives WITH a carrier, plus every Nth anonymous
+    # request when trace_serve_period > 0 (0 = carrier-bearing only)
     "trace_serve_period": 0,
     # flight recorder (obs/flight_recorder.py): ring size, dump rate
     # limit, dump-dir bound, and the guarded jax-profiler capture hook
@@ -91,8 +92,6 @@ _DEFAULTS: dict[str, Any] = {
     "fleet_burn_min_decisions": 20,
     "fleet_incident_min_interval_s": 60.0,
     "fleet_incident_max_bundles": 8,
-    # data
-    "prefetch_depth": 2,
     # kernels: None = auto (fused Pallas cells on TPU, lax.scan elsewhere)
     "use_pallas_rnn": None,
     # precision policy: params in float32, matmuls in bfloat16 by default

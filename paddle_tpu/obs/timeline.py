@@ -1,36 +1,62 @@
 """Per-step wall-time attribution for training hot loops.
 
-Splits every trained batch's wall time into the four places it can
-go, so an input-pipeline stall is a tracked number like MFU instead
-of a vibe:
+Splits every trained batch's wall time into the places it can go, so
+an input-pipeline stall is a tracked number like MFU instead of a
+vibe. The trainer times each piece once, with `obs.tracing.span`, and
+hands the finished span to `add`: the timeline's totals are the sums
+of the very durations the spans carry. Which span feeds which part:
 
-- **data_wait**        — blocking in the reader/feeder before the
-                         step could even be dispatched
-- **host_dispatch**    — Python + runtime time to *submit* the jitted
-                         step (async dispatch: this returns before
+- **data_wait**        — `train.input_wait.reader`, `.feeder`: the
+                         training thread blocked obtaining the next
+                         fed batch
+- **host_dispatch**    — `train.dispatch`: Python + runtime time to
+                         *submit* the jitted step, argument transfer
+                         included (async dispatch: this returns before
                          the device finishes)
-- **device_step**      — time blocked waiting on device results (the
-                         loss fetch, plus a full `block_until_ready`
-                         fence every `sample_period` steps so the
+- **device_step**      — `train.fetch`, the host blocked on the loss,
+                         plus `train.fence`, a full `block_until_ready`
+                         every `sample_period` steps so the
                          parameter-update tail is measured too while
-                         steady-state dispatch stays async)
-- **checkpoint_stall** — training-thread stalls inside checkpoint
-                         saves / preemption flushes
+                         steady-state dispatch stays async
+- **handlers**         — `train.handlers`: evaluators, the watchdog's
+                         ladder, the EndIteration handler, logging
+- **checkpoint_stall** — `train.checkpoint`: training-thread stalls
+                         inside checkpoint saves
 
 The timeline is pure bookkeeping (no jax — the *trainer* owns the
-fencing; `fence_now()` only answers "is this a sampled step").
-Totals are mirrored into the process registry as counters under
-`<prefix>.`; `fractions()` yields the `data_wait_frac` /
-`host_overhead_frac` / `device_frac` fields the bench drivers attach
-to every permanent north-star row, and `emit_pass()` writes one
-structured `timeline` event per pass to the JSONL stream.
+fencing; `fence_now()` only answers "is this a fenced step"). Totals
+are mirrored into the process registry as counters under `<prefix>.`;
+`fractions()` yields the `data_wait_frac` / `host_overhead_frac` /
+`device_frac` fields the bench drivers attach to every permanent
+north-star row, and `emit_pass()` writes one structured `timeline`
+event per pass to the JSONL stream. `end_step` is the always-on
+reader of the spans: a step that took over `SLOW_FACTOR` times the
+median of the last `SLOW_WINDOW` is logged with its split, with or
+without a profiler or a sink.
 """
 
 from __future__ import annotations
 
+import collections
+import logging
+import statistics
+
 from paddle_tpu.obs import metrics as _metrics
 
-PARTS = ("data_wait", "host_dispatch", "device_step", "checkpoint_stall")
+PARTS = ("data_wait", "host_dispatch", "device_step", "handlers",
+         "checkpoint_stall")
+SPAN_PART = {
+    "train.input_wait.reader": "data_wait",
+    "train.input_wait.feeder": "data_wait",
+    "train.dispatch": "host_dispatch",
+    "train.fetch": "device_step",
+    "train.fence": "device_step",
+    "train.handlers": "handlers",
+    "train.checkpoint": "checkpoint_stall",
+}
+SLOW_FACTOR = 3.0
+SLOW_WINDOW = 32
+SLOW_MIN_HISTORY = 4     # steps before the median means anything
 
 
 class StepTimeline:
@@ -42,37 +68,58 @@ class StepTimeline:
         self.sample_period = int(sample_period)
         self.prefix = prefix
         self._reg = registry or _metrics.get_registry()
-        self._totals = {p: 0.0 for p in PARTS}
-        # most recent per-part duration — the step-span emitter reads
-        # the split of THIS step after run_step measured it
-        self.last = {p: 0.0 for p in PARTS}
+        self._log = logging.getLogger(f"paddle_tpu.{prefix}")
+        self._totals_ns = {p: 0 for p in PARTS}
+        self._step_ns = {}       # span name -> ns, of the open step
+        self._walls_ns = collections.deque(maxlen=SLOW_WINDOW)
         self._steps = 0
         self._fenced = 0
 
     # ---- accumulation (trainer-side) ----
-    def _add(self, part: str, dt: float) -> None:
-        self._totals[part] += dt
-        self.last[part] = dt
-        self._reg.counter(f"{self.prefix}.{part}_s").inc(dt)
+    def add(self, span) -> None:
+        """A finished `tracing.Span` of one of SPAN_PART's names."""
+        dt = span.dur_ns
+        self._totals_ns[SPAN_PART[span.name]] += dt
+        self._step_ns[span.name] = self._step_ns.get(span.name, 0) + dt
+        self._reg.counter(
+            f"{self.prefix}.{SPAN_PART[span.name]}_s").inc(dt * 1e-9)
 
-    def add_data_wait(self, dt: float) -> None:
-        self._add("data_wait", dt)
+    def step_done(self, n: int = 1) -> None:
+        self._steps += n
+        self._reg.counter(f"{self.prefix}.steps").inc(n)
 
-    def add_dispatch(self, dt: float) -> None:
-        self._add("host_dispatch", dt)
-
-    def add_device(self, dt: float) -> None:
-        self._add("device_step", dt)
-
-    def add_checkpoint(self, dt: float) -> None:
-        self._add("checkpoint_stall", dt)
-
-    def step_done(self) -> None:
-        self._steps += 1
-        self._reg.counter(f"{self.prefix}.steps").inc()
+    def end_step(self, root) -> bool:
+        """The step's root span has closed: hold its wall against the
+        recent median; True where it was a slow step (one WARNING, one
+        `slow_step` event, `<prefix>.slow_steps` + 1)."""
+        wall, split = root.dur_ns, self._step_ns
+        self._step_ns = {}
+        median = (statistics.median(self._walls_ns)
+                  if len(self._walls_ns) >= SLOW_MIN_HISTORY else wall)
+        slow = wall > SLOW_FACTOR * median
+        if slow:
+            median_s = median * 1e-9
+            parts = {
+                "input_wait_s": split.get("train.input_wait.reader", 0)
+                + split.get("train.input_wait.feeder", 0),
+                "dispatch_s": split.get("train.dispatch", 0),
+                "fetch_s": split.get("train.fetch", 0),
+                "fence_s": split.get("train.fence", 0),
+                "handlers_s": split.get("train.handlers", 0),
+            }
+            parts = {k: round(v * 1e-9, 6) for k, v in parts.items()}
+            self._reg.counter(f"{self.prefix}.slow_steps").inc()
+            self._reg.event("slow_step", wall_s=round(wall * 1e-9, 6),
+                            median_s=round(median_s, 6),
+                            **root.labels, **parts)
+            self._log.warning(
+                "slow step %s: %.4f s against a median of %.4f s: %s",
+                root.labels, wall * 1e-9, median_s, parts)
+        self._walls_ns.append(wall)
+        return slow
 
     def fence_now(self, step_index: int) -> bool:
-        """True on sampled steps — the trainer then blocks until the
+        """True on fenced steps — the trainer then blocks until the
         whole step (parameter update included) has landed, so
         device_step covers the tail the loss fetch alone would miss."""
         if self.sample_period <= 0:
@@ -88,30 +135,21 @@ class StepTimeline:
         return self._steps
 
     def totals(self) -> dict:
-        return dict(self._totals)
+        """Seconds a part: the sum of its spans' nanoseconds."""
+        return {p: ns * 1e-9 for p, ns in self._totals_ns.items()}
 
     def fractions(self) -> dict:
-        """Shares of the MEASURED wall (the four parts' sum — loop
-        bookkeeping outside them is not attributed). All zero before
-        the first step."""
-        wall = sum(self._totals.values())
-        if wall <= 0.0:
-            return {
-                "data_wait_frac": 0.0,
-                "host_overhead_frac": 0.0,
-                "device_frac": 0.0,
-                "checkpoint_stall_frac": 0.0,
-            }
-        return {
-            "data_wait_frac": round(self._totals["data_wait"] / wall, 4),
-            "host_overhead_frac": round(
-                self._totals["host_dispatch"] / wall, 4
-            ),
-            "device_frac": round(self._totals["device_step"] / wall, 4),
-            "checkpoint_stall_frac": round(
-                self._totals["checkpoint_stall"] / wall, 4
-            ),
-        }
+        """Shares of the loop's wall: the parts cover a step from the
+        reader's call to the last handler's return, but for the
+        BeginIteration handler. All zero before the first step."""
+        wall = sum(self._totals_ns.values())
+        names = {"data_wait": "data_wait_frac",
+                 "host_dispatch": "host_overhead_frac",
+                 "device_step": "device_frac",
+                 "handlers": "handlers_frac",
+                 "checkpoint_stall": "checkpoint_stall_frac"}
+        return {names[p]: round(ns / wall, 4) if wall else 0.0
+                for p, ns in self._totals_ns.items()}
 
     def emit_pass(self, pass_id: int, global_step: int) -> None:
         """One `timeline` event on the JSONL stream per pass (no-op
@@ -124,6 +162,6 @@ class StepTimeline:
             steps=self._steps,
             fenced_steps=self._fenced,
             sample_period=self.sample_period,
-            **{f"{p}_s": round(self._totals[p], 6) for p in PARTS},
+            **{f"{p}_s": round(s, 6) for p, s in self.totals().items()},
             **self.fractions(),
         )

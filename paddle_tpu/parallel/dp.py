@@ -25,6 +25,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from paddle_tpu.analysis.recompile_guard import RecompileGuard
 from paddle_tpu.core.mesh import DATA_AXIS
+from paddle_tpu.obs import tracing as _tracing
 
 
 def batch_sharding(mesh: Mesh) -> NamedSharding:
@@ -47,14 +48,18 @@ def param_sharding(mesh: Mesh, pc=None) -> NamedSharding:
     return NamedSharding(mesh, auto_param_spec(pc, mesh))
 
 
-def shard_batch(feed: dict, mesh: Mesh) -> dict:
-    """Device-put a host feed with batch-dim sharding."""
-    sh = batch_sharding(mesh)
+def shard_batch(feed: dict, mesh: Mesh, sharding=None) -> dict:
+    """Device-put a host feed with batch-dim sharding (or `sharding`),
+    under the span `train.h2d`: on a mesh the transfer is a call of
+    its own, and the span says what it took."""
+    sh = sharding or batch_sharding(mesh)
 
     def put(x):
         return jax.device_put(x, sh) if x is not None else None
 
-    return jax.tree_util.tree_map(put, feed)
+    nbytes = sum(x.nbytes for x in jax.tree_util.tree_leaves(feed))
+    with _tracing.span("train.h2d", bytes=nbytes):
+        return jax.tree_util.tree_map(put, feed)
 
 
 class TrainStep:
@@ -108,36 +113,44 @@ class TrainStep:
         def step(params, opt_state, state, feed, step_i, rng,
                  lr_scale=None):
             guard.note(params, feed)
+            # every device operation of the step has a scope a trace
+            # can be read by: `<type>:<name>` from Network.forward
+            # (backward: the same under `transpose(jvp(...))`),
+            # `optimizer`, `watchdog`. Scopes are metadata: the
+            # compiled program and its cache key do not change.
             (loss, (outs, new_state)), grads = jax.value_and_grad(
                 net.loss_fn, has_aux=True
             )(params, feed, state=state, train=True, rng=rng)
-            new_params, new_opt_state = opt.update(
-                grads, params, opt_state, step_i, lr_scale=lr_scale
-            )
+            with jax.named_scope("optimizer"):
+                new_params, new_opt_state = opt.update(
+                    grads, params, opt_state, step_i, lr_scale=lr_scale
+                )
             outs = {k: v for k, v in outs.items() if k in keep}
             if not watchdog:
                 return new_params, new_opt_state, new_state, loss, outs
             # all-finite reduction, fused into the update program: a
             # handful of per-leaf reductions + ANDs, no extra pass over
             # activations and no host sync
-            finite = jnp.isfinite(loss)
-            for g in jax.tree_util.tree_leaves(grads):
-                finite = finite & jnp.all(jnp.isfinite(g))
+            with jax.named_scope("watchdog"):
+                finite = jnp.isfinite(loss)
+                for g in jax.tree_util.tree_leaves(grads):
+                    finite = finite & jnp.all(jnp.isfinite(g))
 
-            def _keep(new, old):
-                return jnp.where(finite, new, old)
+                def _keep(new, old):
+                    return jnp.where(finite, new, old)
 
-            new_params = jax.tree_util.tree_map(
-                _keep, new_params, params
-            )
-            new_opt_state = jax.tree_util.tree_map(
-                _keep, new_opt_state, opt_state
-            )
-            new_state = jax.tree_util.tree_map(_keep, new_state, state)
-            health = jnp.stack([
-                loss.astype(jnp.float32),
-                finite.astype(jnp.float32),
-            ])
+                new_params = jax.tree_util.tree_map(
+                    _keep, new_params, params
+                )
+                new_opt_state = jax.tree_util.tree_map(
+                    _keep, new_opt_state, opt_state
+                )
+                new_state = jax.tree_util.tree_map(
+                    _keep, new_state, state)
+                health = jnp.stack([
+                    loss.astype(jnp.float32),
+                    finite.astype(jnp.float32),
+                ])
             return new_params, new_opt_state, new_state, health, outs
 
         if mesh is not None:
@@ -228,11 +241,9 @@ class TrainStep:
         leaves stacked [n, ...]. jax.jit retraces per distinct n —
         use one or two stable chunk sizes."""
         if self.mesh is not None:
-            sh = NamedSharding(self.mesh, P(None, DATA_AXIS))
-            feeds = jax.tree_util.tree_map(
-                lambda x: jax.device_put(x, sh) if x is not None else None,
-                feeds,
-            )
+            feeds = shard_batch(
+                feeds, self.mesh,
+                NamedSharding(self.mesh, P(None, DATA_AXIS)))
         if self.watchdog:
             return self._multi(
                 params, opt_state, state, feeds, step_i, step_key,

@@ -10,7 +10,6 @@ forwardBackward + updater; the whole mesh runs it SPMD.
 from __future__ import annotations
 
 import logging
-import time
 from typing import Callable, Optional
 
 import jax
@@ -57,40 +56,15 @@ class _NullPreemptionGuard:
         return False
 
 
-def _emit_step_spans(trace_id, trace_parent, tl, pass_id, batch_id,
-                     global_step, t_data, t_rs):
-    """Span tree for one SAMPLED training step (ISSUE 11): a
-    `train.step` root over `train.data_wait` / `train.host_dispatch` /
-    `train.device_step` children, stamped from the exact perf_counter
-    boundaries the StepTimeline just accumulated. The loop uses
-    perf_counter; spans want wall starts — convert via the current
-    perf->wall offset (both clocks are process-local)."""
-    now_pc = time.perf_counter()
-    now_wall = time.time()
+_PASS_OVER = object()  # what next() gives when the reader has ended
 
-    def wall(t_pc):
-        return now_wall - (now_pc - t_pc)
 
-    root = _tracing.new_span_id()
-    _tracing.emit_span(
-        "train.step", trace_id, root, trace_parent,
-        dur_s=now_pc - t_data, ts=wall(t_data),
-        labels={"pass_id": pass_id, "batch_id": batch_id,
-                "global_step": global_step, "sampled": True},
-    )
-    _tracing.emit_span(
-        "train.data_wait", trace_id, _tracing.new_span_id(), root,
-        dur_s=tl.last["data_wait"], ts=wall(t_data),
-    )
-    _tracing.emit_span(
-        "train.host_dispatch", trace_id, _tracing.new_span_id(), root,
-        dur_s=tl.last["host_dispatch"], ts=wall(t_rs),
-    )
-    _tracing.emit_span(
-        "train.device_step", trace_id, _tracing.new_span_id(), root,
-        dur_s=tl.last["device_step"],
-        ts=wall(t_rs + tl.last["host_dispatch"]),
-    )
+def _feed_size(feed) -> tuple:
+    """(rows, bytes) of a fed batch: the leading dimension of its
+    first leaf, and every leaf's bytes."""
+    leaves = jax.tree_util.tree_leaves(feed)
+    rows = leaves[0].shape[0] if leaves and leaves[0].ndim else 0
+    return rows, sum(x.nbytes for x in leaves)
 
 
 class SGD:
@@ -130,8 +104,9 @@ class SGD:
         chunk-granular: LR backoff takes effect on the NEXT chunk,
         preemption checkpoints at chunk boundaries (un-dispatched
         buffered batches are replayed by the deterministic reader —
-        still exactly-once), and per-step span trees are not emitted
-        (a scan dispatch has no per-batch host boundary to stamp)."""
+        still exactly-once), and a `train.step` span covers a chunk,
+        labelled with its `steps` (a scan dispatch has no per-batch
+        host boundary)."""
         if watchdog is None:
             watchdog = bool(_flags.get_flag("watchdog"))
         if watchdog is True:
@@ -260,42 +235,47 @@ class SGD:
         made — and a non-finite batch's update was already skipped on
         device.
 
-        `timeline`: an obs.StepTimeline splitting this step's wall
-        time into host-dispatch (submitting the jitted program) vs
-        device-step (blocked on results). On the timeline's sampled
-        steps the params are fenced with block_until_ready so the
-        update tail is measured too; every other step stays async
-        beyond the loss fetch."""
-        rng = _rng.split_for_step(self.step_key, self.global_step)
-        t0 = time.perf_counter() if timeline is not None else 0.0
-        (
-            self.params,
-            self.opt_state,
-            self.state,
-            loss,
-            outs,
-        ) = self.step_fn(
-            self.params, self.opt_state, self.state, feed,
-            self.global_step, rng, lr_scale=lr_scale,
-        )
+        Spans: `train.dispatch` (argument transfer and enqueue: the
+        jitted call returns before the device finishes) and
+        `train.fetch` (the host blocked on the loss). `timeline`: an
+        obs.StepTimeline that is handed both; on its fenced steps the
+        params are fenced with block_until_ready under `train.fence`,
+        so the update tail is measured too; every other step stays
+        async beyond the loss fetch."""
+        with _tracing.span("train.dispatch") as dispatched:
+            rng = _rng.split_for_step(self.step_key, self.global_step)
+            (
+                self.params,
+                self.opt_state,
+                self.state,
+                loss,
+                outs,
+            ) = self.step_fn(
+                self.params, self.opt_state, self.state, feed,
+                self.global_step, rng, lr_scale=lr_scale,
+            )
         self.global_step += 1
-        if timeline is None:
+        with _tracing.span("train.fetch") as fetched:
             if self.step_fn.watchdog:
                 health = np.asarray(loss)  # the single host fetch
-                return float(health[0]), bool(health[1]), outs
-            return float(loss), True, outs
-        t1 = time.perf_counter()
-        timeline.add_dispatch(t1 - t0)
-        if self.step_fn.watchdog:
-            health = np.asarray(loss)
-            result = float(health[0]), bool(health[1]), outs
-        else:
-            result = float(loss), True, outs
-        if timeline.fence_now(self.global_step):
-            jax.block_until_ready(self.params)
-        timeline.add_device(time.perf_counter() - t1)
-        timeline.step_done()
+                result = float(health[0]), bool(health[1]), outs
+            else:
+                result = float(loss), True, outs
+        self._after_step(timeline, 1, dispatched, fetched)
         return result
+
+    def _after_step(self, timeline, n, dispatched, fetched) -> None:
+        """Hand a dispatch's spans to the timeline, and fence where it
+        says so."""
+        if timeline is None:
+            return
+        timeline.add(dispatched)
+        timeline.add(fetched)
+        if timeline.fence_now(self.global_step):
+            with _tracing.span("train.fence") as fenced:
+                jax.block_until_ready(self.params)
+            timeline.add(fenced)
+        timeline.step_done(n)
 
     def run_steps(self, feeds, lr_scale: float = 1.0,
                   timeline=None) -> tuple:
@@ -307,39 +287,34 @@ class SGD:
         stacked [n, ...] (slice leaf[i] for batch i's evaluator view).
         The per-step RNG/optimizer trajectory is identical to calling
         run_step n times. All feeds in one call must share one shape
-        signature (they compile per distinct stacked shape)."""
+        signature (they compile per distinct stacked shape). Spans
+        and `timeline` as in run_step; stacking the feeds is part of
+        `train.dispatch`."""
         n = len(feeds)
-        stacked = jax.tree_util.tree_map(
-            lambda *xs: jnp.stack(xs), *feeds
-        )
-        t0 = time.perf_counter() if timeline is not None else 0.0
-        (
-            self.params,
-            self.opt_state,
-            self.state,
-            losses,
-            outs,
-        ) = self.step_fn.multi(
-            self.params, self.opt_state, self.state, stacked,
-            self.global_step, self.step_key, lr_scale=lr_scale,
-        )
+        with _tracing.span("train.dispatch", steps=n) as dispatched:
+            stacked = jax.tree_util.tree_map(
+                lambda *xs: jnp.stack(xs), *feeds
+            )
+            (
+                self.params,
+                self.opt_state,
+                self.state,
+                losses,
+                outs,
+            ) = self.step_fn.multi(
+                self.params, self.opt_state, self.state, stacked,
+                self.global_step, self.step_key, lr_scale=lr_scale,
+            )
         self.global_step += n
-        t1 = time.perf_counter()
-        if timeline is not None:
-            timeline.add_dispatch(t1 - t0)
-        health = np.asarray(losses)  # the single host fetch
+        with _tracing.span("train.fetch") as fetched:
+            health = np.asarray(losses)  # the single host fetch
         if self.step_fn.watchdog:
             costs = [float(h) for h in health[:, 0]]
             finites = [bool(h) for h in health[:, 1]]
         else:
             costs = [float(h) for h in health]
             finites = [True] * n
-        if timeline is not None:
-            if timeline.fence_now(self.global_step):
-                jax.block_until_ready(self.params)
-            timeline.add_device(time.perf_counter() - t1)
-            for _ in range(n):
-                timeline.step_done()
+        self._after_step(timeline, n, dispatched, fetched)
         return costs, finites, outs
 
     def train(
@@ -382,27 +357,22 @@ class SGD:
         )
         if wd is not None:
             self.last_watchdog_report = wd.report
-        # per-step wall-time attribution (ISSUE 10): data-wait vs
-        # host-dispatch vs device-step vs checkpoint-stall, fenced
-        # every `timeline_sample_period` steps. Exposed for bench
-        # drivers as `last_timeline`; totals feed the `trainer.*`
-        # registry counters and one `timeline` event per pass.
+        # per-step wall-time attribution: every piece of a step is
+        # timed once, by a `tracing.span`, and the same span feeds the
+        # timeline (the `trainer.*` counters, `last_timeline`, one
+        # `timeline` event per pass), the event stream or flight
+        # recorder where one is attached, and the profiler's trace
+        # where a session is on. The device is fenced every
+        # `timeline_sample_period` steps.
         tl = StepTimeline(
             sample_period=_flags.get_flag("timeline_sample_period")
         )
         self.last_timeline = tl
-        # one trace per train() call; sampled steps (the timeline's
-        # fence points) each emit a span tree — train.step over
-        # data_wait / host_dispatch / device_step — aligned with the
-        # very timestamps the timeline accumulated, so the span view
-        # and the fraction view can never disagree about a step.
-        # Joins the launching process's trace when the carrier env
-        # var is set (tracing.CARRIER_ENV), else starts its own.
-        with _tracing.attach_from_env():
-            cur = _tracing.current()
-        trace_id = cur[0] if cur else _tracing.new_trace_id()
-        trace_parent = cur[1] if cur else ""
-        self.last_trace_id = trace_id
+        # one trace per train() call, every step a `train.step` root
+        # in it. Joins the launching process's trace when the carrier
+        # env var is set (tracing.CARRIER_ENV), or the caller's where
+        # the thread is in one, else begins its own.
+        trace = _tracing.attach_from_env(or_begin=True)
         # SIGTERM -> flag; checked at batch boundaries only, so the
         # in-flight jitted step always completes before the flush.
         # Installed only when there is somewhere to flush to.
@@ -412,7 +382,8 @@ class SGD:
         )
         ok = False
         try:
-          with guard:
+          with guard, trace:
+            self.last_trace_id = _tracing.current()[0]
             for pass_id in range(start_pass, num_passes):
                 event_handler(BeginPass(pass_id))
                 evals = self._make_evaluators()
@@ -427,77 +398,52 @@ class SGD:
                     batch_iter = None  # drained
                 batch_id = -1
                 while batch_iter is not None:
-                    t_data = time.perf_counter()
-                    try:
-                        raw = next(batch_iter)
-                    except StopIteration:
-                        break
-                    batch_id += 1
-                    if pass_id == start_pass and batch_id < skip_batches:
-                        # already trained before the preemption (their
-                        # work lives in the flushed checkpoint) — the
-                        # deterministic reader replays them, the loop
-                        # drops them
-                        continue
-                    # reader-next + feeder conversion = the input
-                    # pipeline's blocking share of this step; the
-                    # user's BeginIteration handler is deliberately
-                    # outside it (its cost is not the reader's)
-                    dt_reader = time.perf_counter() - t_data
-                    event_handler(BeginIteration(pass_id, batch_id))
-                    t_feed = time.perf_counter()
-                    feed = feeder(raw)
-                    tl.add_data_wait(
-                        dt_reader + time.perf_counter() - t_feed
-                    )
-                    t_rs = time.perf_counter()
-                    with GLOBAL_STATS.timer("train_step"):
+                    with _tracing.span(
+                        "train.step", step_num=self.global_step,
+                        pass_id=pass_id, batch_id=batch_id + 1,
+                    ) as step:
+                        # the training thread blocked obtaining the
+                        # next fed batch: the reader, then the feeder;
+                        # the user's BeginIteration handler between
+                        # them is deliberately in neither (its cost is
+                        # not the input path's)
+                        with _tracing.span(
+                                "train.input_wait.reader") as waited:
+                            raw = next(batch_iter, _PASS_OVER)
+                            batch_id += 1
+                            # a batch trained before the preemption
+                            # (its work lives in the flushed
+                            # checkpoint): the deterministic reader
+                            # replays it, the loop drops it
+                            no_step = raw is _PASS_OVER or (
+                                pass_id == start_pass
+                                and batch_id < skip_batches)
+                            if no_step:
+                                waited.discard()
+                        if no_step:
+                            step.discard()
+                            if raw is _PASS_OVER:
+                                break
+                            continue
+                        tl.add(waited)
+                        event_handler(BeginIteration(pass_id, batch_id))
+                        with _tracing.span(
+                                "train.input_wait.feeder") as waited:
+                            feed = feeder(raw)
+                        tl.add(waited)
+                        self._count_feed(feed)
                         cost, finite, outs = self.run_step(
                             feed, wd.lr_scale() if wd else 1.0,
                             timeline=tl,
                         )
-                    if (tl.sample_period > 0
-                            and self.global_step % tl.sample_period
-                            == 0):
-                        # sampled (fenced) step: the device is quiet
-                        # and every segment of this step is measured —
-                        # emit its span tree (no-op without a stream
-                        # or flight recorder attached)
-                        _emit_step_spans(
-                            trace_id, trace_parent, tl, pass_id,
-                            batch_id, self.global_step - 1, t_data,
-                            t_rs,
-                        )
-                    if finite:
-                        costs.append(cost)
-                        for ev in evals:
-                            ev.add_batch(outs, feed)
-                    if wd is not None:
-                        self._watchdog_act(
-                            wd, cost, finite, save_dir, ckpt_mode,
-                        )
-                    results = (
-                        {ev.name: ev.result() for ev in evals}
-                        if (batch_id + 1) % log_period == 0
-                        else {}
-                    )
-                    event_handler(
-                        EndIteration(pass_id, batch_id, cost, results)
-                    )
-                    if (batch_id + 1) % log_period == 0:
-                        log.info(
-                            "pass %d batch %d cost %.5f %s",
-                            pass_id,
-                            batch_id,
-                            float(np.mean(costs[-log_period:]))
-                            if costs else float("nan"),
-                            results,
-                        )
-                    stats_period = _flags.get_flag(
-                        "show_parameter_stats_period"
-                    )
-                    if stats_period and (batch_id + 1) % stats_period == 0:
-                        self._log_parameter_stats(pass_id, batch_id)
+                        with _tracing.span("train.handlers") as handled:
+                            self._after_batch(
+                                pass_id, batch_id, cost, finite, outs,
+                                feed, evals, costs, wd, save_dir,
+                                ckpt_mode, event_handler, log_period,
+                            )
+                        tl.add(handled)
+                    tl.end_step(step)
                     if guard.preempted:
                         # the in-flight batch completed and is counted
                         # in batch_id+1: the flush loses zero
@@ -516,8 +462,10 @@ class SGD:
                         TestResult(pass_id, tr["cost"], tr["evaluators"])
                     )
                 if save_dir:
-                    t_ck = time.perf_counter()
-                    with GLOBAL_STATS.timer("checkpoint_save"):
+                    with _tracing.span(
+                        "train.checkpoint", pass_id=pass_id,
+                        mode=ckpt_mode,
+                    ) as saved, GLOBAL_STATS.timer("checkpoint_save"):
                         if ckpt_mode == "async":
                             # every process commits its own shard; only the
                             # host snapshot inside save() blocks the loop
@@ -538,14 +486,7 @@ class SGD:
                                 meta={"global_step": self.global_step},
                                 save_only_one=_flags.get_flag("save_only_one"),
                             )
-                    dt_ck = time.perf_counter() - t_ck
-                    tl.add_checkpoint(dt_ck)
-                    _tracing.emit_span(
-                        "train.checkpoint", trace_id,
-                        _tracing.new_span_id(), trace_parent,
-                        dur_s=dt_ck,
-                        labels={"pass_id": pass_id, "mode": ckpt_mode},
-                    )
+                    tl.add(saved)
                     if wd is not None:
                         # candidate only: promoted to the rollback
                         # target after `good_batches` healthy batches
@@ -594,6 +535,46 @@ class SGD:
                             "handling a training error"
                         )
 
+    def _count_feed(self, feed) -> None:
+        """A fed batch goes to the step: count its rows and bytes."""
+        rows, nbytes = _feed_size(feed)
+        reg = _obs.get_registry()
+        reg.counter("trainer.rows").inc(rows)
+        reg.counter("trainer.feed_bytes").inc(nbytes)
+
+    def _after_batch(self, pass_id, batch_id, cost, finite, outs, feed,
+                     evals, costs, wd, save_dir, ckpt_mode,
+                     event_handler, log_period, observe=True):
+        """What the loop does with one batch's result (the body of
+        `train.handlers`): evaluators, the watchdog's ladder (unless
+        `observe` is off), the EndIteration handler, the log lines.
+        Returns the ladder's action."""
+        if finite:
+            costs.append(cost)
+            for ev in evals:
+                ev.add_batch(outs, feed)
+        action = None
+        if wd is not None and observe:
+            action = self._watchdog_act(
+                wd, cost, finite, save_dir, ckpt_mode,
+            )
+        logged = (batch_id + 1) % log_period == 0
+        results = (
+            {ev.name: ev.result() for ev in evals} if logged else {}
+        )
+        event_handler(EndIteration(pass_id, batch_id, cost, results))
+        if logged:
+            log.info(
+                "pass %d batch %d cost %.5f %s", pass_id, batch_id,
+                float(np.mean(costs[-log_period:]))
+                if costs else float("nan"),
+                results,
+            )
+        stats_period = _flags.get_flag("show_parameter_stats_period")
+        if stats_period and (batch_id + 1) % stats_period == 0:
+            self._log_parameter_stats(pass_id, batch_id)
+        return action
+
     def _run_pass_pipelined(self, pass_id, start_pass, skip_batches,
                             batch_iter, feeder, event_handler, evals,
                             costs, tl, wd, guard, save_dir, ckpt_mode,
@@ -605,13 +586,12 @@ class SGD:
         watchdog observe every batch in order after its chunk lands.
         Chunk-granular differences are documented on __init__. A
         shape-signature change (e.g. a ragged final reader batch)
-        flushes the buffer early, so mixed shapes cost one extra
-        compile, never an error."""
+        closes the chunk early and opens the next with the odd batch,
+        so mixed shapes cost one extra compile, never an error. One
+        `train.step` span covers a chunk: its batches' input waits,
+        one dispatch, one fetch, the handlers of all its batches."""
         spd = self.steps_per_dispatch
-        buf = []  # (batch_id, feed)
-        sig = None
         done_upto = skip_batches  # batches of this pass fully trained
-        stats_period = _flags.get_flag("show_parameter_stats_period")
 
         def _sig(feed):
             return (
@@ -632,77 +612,75 @@ class SGD:
                 )
                 raise wdg.Preempted(pass_id, done_upto, save_dir)
 
-        def flush():
-            nonlocal buf, sig, done_upto
-            if not buf:
-                return
-            with GLOBAL_STATS.timer("train_step"):
-                cs, fs, outs = self.run_steps(
-                    [f for _, f in buf],
-                    wd.lr_scale() if wd else 1.0, timeline=tl,
-                )
-            observe = True
-            for j, (bid, feed) in enumerate(buf):
-                cost, finite = cs[j], fs[j]
-                if finite:
-                    costs.append(cost)
-                    for ev in evals:
-                        ev.add_batch(
-                            jax.tree_util.tree_map(
-                                lambda x: x[j], outs
-                            ),
-                            feed,
-                        )
-                if wd is not None and observe:
-                    action = self._watchdog_act(
-                        wd, cost, finite, save_dir, ckpt_mode
+        def flush(buf):
+            cs, fs, outs = self.run_steps(
+                [f for _, f in buf],
+                wd.lr_scale() if wd else 1.0, timeline=tl,
+            )
+            with _tracing.span("train.handlers") as handled:
+                observe = True
+                for j, (bid, feed) in enumerate(buf):
+                    action = self._after_batch(
+                        pass_id, bid, cs[j], fs[j],
+                        jax.tree_util.tree_map(lambda x: x[j], outs)
+                        if fs[j] and evals else None,
+                        feed, evals, costs, wd, save_dir, ckpt_mode,
+                        event_handler, log_period, observe=observe,
                     )
                     if action == wdg.ROLLBACK:
                         # the chunk's remaining batches trained on the
                         # now-rolled-back trajectory; their costs are
                         # discarded progress — stop feeding the ladder
                         observe = False
-                results = (
-                    {ev.name: ev.result() for ev in evals}
-                    if (bid + 1) % log_period == 0 else {}
-                )
-                event_handler(EndIteration(pass_id, bid, cost, results))
-                if (bid + 1) % log_period == 0:
-                    log.info(
-                        "pass %d batch %d cost %.5f %s", pass_id, bid,
-                        float(np.mean(costs[-log_period:]))
-                        if costs else float("nan"),
-                        results,
-                    )
-                if stats_period and (bid + 1) % stats_period == 0:
-                    self._log_parameter_stats(pass_id, bid)
-            done_upto = buf[-1][0] + 1
-            buf, sig = [], None
+            tl.add(handled)
 
         batch_id = -1
-        while True:
-            _check_preempt()
-            t_data = time.perf_counter()
-            try:
-                raw = next(batch_iter)
-            except StopIteration:
-                break
-            batch_id += 1
-            if pass_id == start_pass and batch_id < skip_batches:
-                continue
-            dt_reader = time.perf_counter() - t_data
-            event_handler(BeginIteration(pass_id, batch_id))
-            t_feed = time.perf_counter()
-            feed = feeder(raw)
-            tl.add_data_wait(dt_reader + time.perf_counter() - t_feed)
-            fsig = _sig(feed)
-            if buf and fsig != sig:
-                flush()
-            buf.append((batch_id, feed))
-            sig = fsig
-            if len(buf) >= spd:
-                flush()
-        flush()
+        held = None  # (batch_id, feed, sig) whose signature closed a chunk
+        pass_over = False
+        while not pass_over:
+            buf, sig = ([held[:2]], held[2]) if held else ([], None)
+            held = None
+            with _tracing.span(
+                "train.step", step_num=self.global_step, pass_id=pass_id,
+            ) as step:
+                while len(buf) < spd:
+                    _check_preempt()
+                    with _tracing.span(
+                            "train.input_wait.reader") as waited:
+                        raw = next(batch_iter, _PASS_OVER)
+                        batch_id += 1
+                        no_step = raw is _PASS_OVER or (
+                            pass_id == start_pass
+                            and batch_id < skip_batches)
+                        if no_step:
+                            waited.discard()
+                    if raw is _PASS_OVER:
+                        pass_over = True
+                        break
+                    if no_step:
+                        continue
+                    tl.add(waited)
+                    event_handler(BeginIteration(pass_id, batch_id))
+                    with _tracing.span(
+                            "train.input_wait.feeder") as waited:
+                        feed = feeder(raw)
+                    tl.add(waited)
+                    self._count_feed(feed)
+                    fsig = _sig(feed)
+                    if buf and fsig != sig:
+                        held = (batch_id, feed, fsig)
+                        break
+                    buf.append((batch_id, feed))
+                    sig = fsig
+                if buf:
+                    step.set_label("batch_id", buf[0][0])
+                    step.set_label("steps", len(buf))
+                    flush(buf)
+                    done_upto = buf[-1][0] + 1
+                else:
+                    step.discard()
+            if buf:
+                tl.end_step(step)
         _check_preempt()
 
     def _watchdog_act(self, wd, cost, finite, save_dir, ckpt_mode):

@@ -744,7 +744,9 @@ class TestAnomalyWatch:
 
 # ======================================================= trainer spans
 class TestTrainerStepSpans:
-    def test_sampled_steps_emit_span_trees(self, global_recorder):
+    def test_every_step_emits_a_span_tree(self, global_recorder):
+        """(tests/test_train_spans.py holds the tree's shape; this is
+        the stream's view: one trace a train() call, a root a step.)"""
         from paddle_tpu import dsl
         from paddle_tpu.core.config import OptimizationConf
         from paddle_tpu.data import reader as R
@@ -783,17 +785,18 @@ class TestTrainerStepSpans:
             _flags.set_flag("timeline_sample_period", prev)
         by = _spans_by_name(global_recorder)
         steps = by["train.step"]
-        assert len(steps) == 3  # 12 steps / period 4
+        assert len(steps) == 12  # every step, fenced or not
         assert {s["trace_id"] for s in steps} == {t.last_trace_id}
         kids = [s for s in global_recorder.spans()
-                if s["parent_id"] == steps[0]["span_id"]]
+                if s["parent_id"] == steps[3]["span_id"]]
         assert {k["name"] for k in kids} == {
-            "train.data_wait", "train.host_dispatch",
-            "train.device_step",
+            "train.input_wait.reader", "train.input_wait.feeder",
+            "train.dispatch", "train.fetch", "train.fence",
+            "train.handlers",
         }
-        # labels align the span tree with the timeline's fences
-        assert steps[0]["labels"]["sampled"] is True
-        assert steps[-1]["labels"]["global_step"] == 11
+        assert len(by["train.fence"]) == 3  # 12 steps / period 4
+        assert steps[-1]["labels"]["step_num"] == 11
+        assert steps[-1]["labels"]["batch_id"] == 5
 
 
 # ========================================================== CLI modes

@@ -1,0 +1,274 @@
+"""The training step is spanned from inside `SGD.train`: every step one
+`train.step` root whose children are where the loop's time goes, read
+here from a ring-only flight recorder, and the same durations feed
+`StepTimeline`."""
+
+import logging
+import os
+import time
+
+import jax
+import numpy as np
+import pytest
+
+from paddle_tpu import dsl
+from paddle_tpu.core import flags as _flags
+from paddle_tpu.core.config import OptimizationConf
+from paddle_tpu.core.mesh import make_mesh
+from paddle_tpu.data.feeder import DataFeeder, dense_vector, integer_value
+from paddle_tpu.obs import flight_recorder as fr
+from paddle_tpu.obs import metrics as om
+from paddle_tpu.obs import tracing
+from paddle_tpu.obs.timeline import SPAN_PART
+from paddle_tpu.trainer import SGD
+
+STEP_S = 0.02        # the reader's sleep a batch: a step well over the glue
+CHILDREN = {"train.input_wait.reader", "train.input_wait.feeder",
+            "train.dispatch", "train.fetch", "train.handlers"}
+
+
+@pytest.fixture
+def recorder():
+    rec = fr.enable_flight_recorder()
+    try:
+        yield rec
+    finally:
+        fr.disable_flight_recorder()
+
+
+@pytest.fixture
+def fence_every_4():
+    prev = _flags.get_flag("timeline_sample_period")
+    _flags.set_flag("timeline_sample_period", 4)
+    try:
+        yield
+    finally:
+        _flags.set_flag("timeline_sample_period", prev)
+
+
+def _train(stall_at=None, **sgd):
+    """12 steps (2 passes of 6 batches of 4) of a tiny classifier; the
+    reader sleeps STEP_S a batch, and 10 times that at `stall_at`."""
+    with dsl.model() as g:
+        x = dsl.data("x", (4,))
+        y = dsl.data("y", (1,), is_ids=True)
+        o = dsl.fc(x, size=3, name="output")
+        dsl.classification_cost(o, y)
+    rng = np.random.default_rng(0)
+    xs = rng.standard_normal((24, 4)).astype(np.float32)
+    ys = np.argmax(xs[:, :3], axis=1).astype(np.int64)
+    yielded = []
+
+    def batches():
+        for i in range(0, 24, 4):
+            time.sleep(STEP_S * (10 if len(yielded) == stall_at else 1))
+            yielded.append(i)
+            yield [(xs[j], int(ys[j])) for j in range(i, i + 4)]
+
+    feeder = DataFeeder({"x": 0, "y": 1},
+                        {"x": dense_vector(4), "y": integer_value(3)})
+    t = SGD(g.conf, OptimizationConf(learning_method="sgd",
+                                     learning_rate=0.1), seed=3, **sgd)
+    t.train(reader=batches, feeder=feeder, num_passes=2)
+    return t
+
+
+def _trees(rec, trace_id):
+    """[(root, children in order of start)] of one train() call."""
+    spans = [s for s in rec.spans() if s["trace_id"] == trace_id]
+    roots = sorted((s for s in spans if s["name"] == "train.step"),
+                   key=lambda s: s["t0_ns"])
+    return [(r, sorted((s for s in spans
+                        if s["parent_id"] == r["span_id"]),
+                       key=lambda s: s["t0_ns"])) for r in roots]
+
+
+def _assert_tree(root, kids, share=0.05):
+    """Children lie inside the root, do not overlap, and leave under
+    `share` of it uncovered."""
+    at = root["t0_ns"]
+    for k in kids:
+        assert k["t0_ns"] >= at, (k["name"], "overlaps what came before")
+        assert k["t1_ns"] >= k["t0_ns"]
+        at = k["t1_ns"]
+    assert at <= root["t1_ns"]
+    wall = root["t1_ns"] - root["t0_ns"]
+    covered = sum(k["t1_ns"] - k["t0_ns"] for k in kids)
+    assert wall - covered <= share * wall, (wall, covered)
+
+
+def _assert_timeline_is_the_spans(t, trees):
+    got = {p: 0 for p in set(SPAN_PART.values())}
+    for _, kids in trees:
+        for k in kids:
+            got[SPAN_PART[k["name"]]] += k["t1_ns"] - k["t0_ns"]
+    assert t.last_timeline.totals() == {p: ns * 1e-9
+                                        for p, ns in got.items()}
+
+
+def test_every_step_has_one_root_with_the_tables_children(
+        recorder, fence_every_4):
+    t = _train()
+    trees = _trees(recorder, t.last_trace_id)
+    assert [r["labels"]["step_num"] for r, _ in trees] == list(range(12))
+    assert [(r["labels"]["pass_id"], r["labels"]["batch_id"])
+            for r, _ in trees] == [(p, b) for p in (0, 1) for b in range(6)]
+    for i, (root, kids) in enumerate(trees):
+        fenced = (i + 1) % 4 == 0
+        assert [k["name"] for k in kids] == [
+            "train.input_wait.reader", "train.input_wait.feeder",
+            "train.dispatch", "train.fetch",
+            *(["train.fence"] if fenced else []), "train.handlers"]
+        assert root["status"] == "ok" and root["parent_id"] == ""
+        _assert_tree(root, kids)
+    # nothing for the reader's last call of a pass, which found it over
+    assert len([s for s in recorder.spans()
+                if s["name"] == "train.input_wait.reader"]) == 12
+    _assert_timeline_is_the_spans(t, trees)
+    assert t.last_timeline.steps == 12
+    assert sum(t.last_timeline.fractions().values()) == pytest.approx(
+        1.0, abs=1e-3)
+
+
+@pytest.mark.parametrize("spd", [1, 4])
+def test_a_mesh_puts_the_transfer_under_dispatch(recorder, spd):
+    t = _train(mesh=make_mesh({"data": 4}, devices=jax.devices()[:4]),
+               steps_per_dispatch=spd)
+    spans = recorder.spans()
+    trees = _trees(recorder, t.last_trace_id)
+    assert sum(r["labels"].get("steps", 1) for r, _ in trees) == 12
+    for root, kids in trees:
+        assert {k["name"] for k in kids} >= CHILDREN
+        dispatch = next(k for k in kids if k["name"] == "train.dispatch")
+        h2d = [s for s in spans if s["parent_id"] == dispatch["span_id"]]
+        assert [s["name"] for s in h2d] == ["train.h2d"]
+        # a batch: 4 rows of 4 float32 and 4 int32 ids
+        assert h2d[0]["labels"]["bytes"] == (
+            root["labels"].get("steps", 1) * (4 * 4 * 4 + 4 * 4))
+        assert dispatch["t0_ns"] <= h2d[0]["t0_ns"] \
+            and h2d[0]["t1_ns"] <= dispatch["t1_ns"]
+
+
+def test_without_a_mesh_there_is_no_transfer_span(recorder):
+    _train()
+    assert not [s for s in recorder.spans() if s["name"] == "train.h2d"]
+
+
+def test_a_chunk_of_steps_is_one_root(recorder, fence_every_4):
+    t = _train(steps_per_dispatch=4)
+    trees = _trees(recorder, t.last_trace_id)
+    # 6 batches a pass: a chunk of 4 and the pass's last 2
+    assert [r["labels"]["steps"] for r, _ in trees] == [4, 2, 4, 2]
+    assert [r["labels"]["batch_id"] for r, _ in trees] == [0, 4, 0, 4]
+    assert [r["labels"]["step_num"] for r, _ in trees] == [0, 4, 6, 10]
+    for root, kids in trees:
+        n = root["labels"]["steps"]
+        names = [k["name"] for k in kids]
+        assert names[:2 * n] == ["train.input_wait.reader",
+                                 "train.input_wait.feeder"] * n
+        rest = names[2 * n:]
+        assert rest[:2] == ["train.dispatch", "train.fetch"]
+        assert rest[-1] == "train.handlers"
+        assert rest[2:-1] in ([], ["train.fence"])
+        _assert_tree(root, kids)
+    # global_step 4 and 12 are fence points; 6 and 10 are not
+    assert [("train.fence" in [k["name"] for k in kids])
+            for _, kids in trees] == [True, False, False, True]
+    _assert_timeline_is_the_spans(t, trees)
+    assert t.last_timeline.steps == 12
+
+
+def test_no_sink_and_no_session_draws_no_random_id(monkeypatch):
+    def no_urandom(n):
+        raise AssertionError("os.urandom called with tracing off")
+
+    reg = om.get_registry()
+    assert reg.stream is None and reg.recorder is None
+    monkeypatch.setattr(os, "urandom", no_urandom)
+    with tracing.span("outer", step_num=3, k=1) as outer:
+        with tracing.span("inner") as inner:
+            pass
+    assert inner.trace_id == outer.trace_id
+    assert inner.parent_id == outer.span_id != inner.span_id
+    assert 0 <= inner.dur_ns <= outer.dur_ns
+    t = _train()
+    assert t.last_timeline.steps == 12
+
+
+def test_ids_are_random_only_inside_a_carriers_trace(monkeypatch):
+    drawn = []
+    urandom = os.urandom
+    monkeypatch.setattr(os, "urandom",
+                        lambda n: drawn.append(n) or urandom(n))
+    with tracing.span("begun_here"):
+        pass
+    assert not drawn
+    with tracing.attach({"trace_id": "a" * 32, "span_id": "b" * 16}):
+        with tracing.span("joined") as s:
+            pass
+    assert drawn == [8] and s.trace_id == "a" * 32
+    assert s.parent_id == "b" * 16
+
+
+def test_a_stalled_reader_is_one_slow_step_with_its_split(
+        recorder, caplog):
+    slow = om.get_registry().counter("trainer.slow_steps")
+    rows = om.get_registry().counter("trainer.rows")
+    nbytes = om.get_registry().counter("trainer.feed_bytes")
+    before = slow.get(), rows.get(), nbytes.get()
+    with caplog.at_level(logging.WARNING, logger="paddle_tpu.trainer"):
+        _train(stall_at=8)
+    assert slow.get() - before[0] == 1
+    assert rows.get() - before[1] == 48
+    assert nbytes.get() - before[2] == 12 * (4 * 4 * 4 + 4 * 4)
+    events = [e for e in recorder.snapshot() if e["kind"] == "slow_step"]
+    assert len(events) == 1
+    e = events[0]
+    assert e["step_num"] == 8 and (e["pass_id"], e["batch_id"]) == (1, 2)
+    assert e["input_wait_s"] >= 9 * STEP_S
+    assert e["wall_s"] > 3 * e["median_s"]
+    parts = sum(e[k] for k in ("input_wait_s", "dispatch_s", "fetch_s",
+                               "fence_s", "handlers_s"))
+    assert parts == pytest.approx(e["wall_s"], rel=0.05)
+    lines = [r.getMessage() for r in caplog.records
+             if "slow step" in r.getMessage()]
+    assert len(lines) == 1 and "input_wait_s" in lines[0]
+
+
+def test_spans_lie_in_the_profilers_trace(tmp_path):
+    """With a profiler session on and no sink, the spans are in the
+    `.xplane.pb`, all on one line (the training thread's), their labels
+    as arguments and their names bare."""
+    from jax.profiler import ProfileData
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        _train()
+    finally:
+        jax.profiler.stop_trace()
+    files = list(tmp_path.glob("plugins/profile/*/*.xplane.pb"))
+    assert len(files) == 1
+    lines = {}
+    for plane in ProfileData.from_file(str(files[0])).planes:
+        for i, line in enumerate(plane.lines):
+            for ev in line.events:
+                if ev.name.startswith("train."):
+                    lines.setdefault((plane.name, i), []).append(ev)
+    assert len(lines) == 1
+    events = next(iter(lines.values()))
+    roots = [ev for ev in events if ev.name == "train.step"]
+    # 12 steps, and the two calls of the reader that found a pass over
+    assert len(roots) == 14
+    stats = [dict(ev.stats) for ev in roots]
+    assert sorted(s["step_num"] for s in stats)[-1] == 12
+    assert {s["pass_id"] for s in stats} == {0, 1}
+    for name in CHILDREN:
+        inside = [ev for ev in events if ev.name == name]
+        assert len(inside) >= 12, name
+        for ev in inside:
+            assert any(r.start_ns <= ev.start_ns
+                       and ev.start_ns + ev.duration_ns
+                       <= r.start_ns + r.duration_ns for r in roots)

@@ -10,8 +10,8 @@ JSON, schema paddle-tpu-flight-bundle/v1), this tool
   passed together, so a trace that crosses the client/server or
   trainer/master boundary reassembles into one tree;
 - picks each trace's root (the span whose parent is not in the trace;
-  longest wins when a trace has several, e.g. a trainer trace made of
-  many sampled train.step roots);
+  longest wins when a trace has several, e.g. a trainer trace, which
+  holds one train.step root for every step of a train() call);
 - walks the tree into a **critical path**: the time-ordered leaf
   segments that cover the root's duration, with uncovered gaps
   attributed to the enclosing span as "<name> (self)" — the
