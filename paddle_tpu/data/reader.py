@@ -6,7 +6,8 @@ python/paddle/v2/reader/creator.py. A reader is a zero-arg callable
 returning an iterator over samples; combinators wrap readers. The
 double-buffer thread of the reference's C++ DataProvider
 (gserver/dataproviders/DataProvider.h:249 DoubleBuffer) maps to
-`buffered`, which prefetches on a background thread.
+`Buffered`: one producer thread and a bounded queue, behind `buffered`
+for a reader's samples and behind `SGD.train` for its fed batches.
 """
 
 from __future__ import annotations
@@ -14,6 +15,7 @@ from __future__ import annotations
 import itertools
 import queue
 import random as _random
+import sys
 import threading
 
 
@@ -248,38 +250,130 @@ def compose(*readers, check_alignment=True):
     return reader
 
 
+_END = object()  # the producer's last item where the source ended
+
+
+class _Raised:
+    """The producer's last item where the source raised."""
+
+    __slots__ = ("exc",)
+
+    def __init__(self, exc):
+        self.exc = exc
+
+
+def _produce(source, q, stop):
+    """The producer thread of a `Buffered`: the items of `source()` into
+    `q` until the source ends or raises (the last item says which) or
+    `stop` is set. It holds the queue and the event, never the
+    `Buffered`, so that an abandoned consumer can be collected."""
+    it = None
+    try:
+        it = iter(source())
+        for item in it:
+            q.put(item)
+            if stop.is_set():
+                return
+        q.put(_END)
+    except BaseException as exc:  # raised again by the consumer's next()
+        q.put(_Raised(exc))
+    finally:
+        # a generator's clean-up runs here, on the thread that ran it
+        close = getattr(it, "close", None)
+        if close is not None:
+            close()
+
+
+class Buffered:
+    """Iterator over the items of `source()`, made on ONE background
+    thread and handed over through a FIFO queue of at most `size`
+    items (one more may be finished in the producer's hand, waiting for
+    room): the DoubleBuffer of DataProvider.h:249. One producer and
+    one queue, so the items come in the source's order; an exception
+    of the source is raised by `next()` where its item would have
+    come, the same object, its traceback leading into the producer.
+
+    `close()` stops the producer and joins it: after the source's end
+    or its exception, on leaving a `with` block, when the iterator is
+    collected, or by hand. What was made ahead and not taken is
+    dropped. The producer stops between items, so `close()` waits out
+    the source's `next()` in flight; a source that never returns holds
+    it as it would hold a caller that ran it inline."""
+
+    _thread = None  # also where __init__ did not get as far as a thread
+
+    def __init__(self, source, size, name="buffered"):
+        self._q = queue.Queue(maxsize=size)
+        self._stop = threading.Event()
+        self._thread = threading.Thread(
+            target=_produce, args=(source, self._q, self._stop),
+            name=name, daemon=True)
+        self._thread.start()
+
+    def __iter__(self):
+        return self
+
+    def __next__(self):
+        if self._thread is None:
+            raise StopIteration
+        item = self._q.get()
+        if item is _END:
+            self.close()
+            raise StopIteration
+        if isinstance(item, _Raised):
+            self.close()
+            raise item.exc
+        return item
+
+    def ready(self) -> bool:
+        """Whether `next()` would return without waiting for the
+        producer: an item, the end or an exception is in the queue."""
+        return not self._q.empty()
+
+    def _drop(self):
+        try:
+            while True:
+                self._q.get_nowait()
+        except queue.Empty:
+            pass
+
+    def close(self):
+        thread, self._thread = self._thread, None
+        if thread is None:
+            return
+        # the producer looks at `stop` after every put, so from here it
+        # puts once more at most; emptying the queue makes room for
+        # that put, which may be the one it is blocked in
+        self._stop.set()
+        self._drop()
+        thread.join()
+        self._drop()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        self.close()
+        return False
+
+    def __del__(self):
+        # not at interpreter exit: a daemon thread is frozen by then,
+        # and a join would wait for it for ever
+        if not sys.is_finalizing():
+            self.close()
+
+
 def buffered(reader_fn, size):
-    """(decorator.py:162) background-thread prefetch — the DoubleBuffer
-    equivalent (DataProvider.h:249)."""
-
-    class _End:
-        pass
-
-    class _Raise:
-        def __init__(self, exc):
-            self.exc = exc
+    """(decorator.py:162) background-thread prefetch, the DoubleBuffer
+    equivalent (DataProvider.h:249): each call of the returned reader
+    starts one producer thread over `reader_fn()` and gives a
+    `Buffered`, at most `size` samples ahead. A consumer that stops
+    early (`close()`, a `with` block, or dropping the iterator) stops
+    and joins its producer; `SGD.train` feeds its batches a step ahead
+    through the same class."""
 
     def reader():
-        q = queue.Queue(maxsize=size)
-
-        def producer():
-            try:
-                for e in reader_fn():
-                    q.put(e)
-            except BaseException as exc:  # propagate to the consumer
-                q.put(_Raise(exc))
-            else:
-                q.put(_End)
-
-        t = threading.Thread(target=producer, daemon=True)
-        t.start()
-        while True:
-            e = q.get()
-            if e is _End:
-                break
-            if isinstance(e, _Raise):
-                raise e.exc
-            yield e
+        return Buffered(reader_fn, size)
 
     return reader
 

@@ -6,9 +6,11 @@ vibe. The trainer times each piece once, with `obs.tracing.span`, and
 hands the finished span to `add`: the timeline's totals are the sums
 of the very durations the spans carry. Which span feeds which part:
 
-- **data_wait**        — `train.input_wait.reader`, `.feeder`: the
-                         training thread blocked obtaining the next
-                         fed batch
+- **data_wait**        — `train.input_wait.feeder`: the training
+                         thread blocked on the queue for the next fed
+                         batch (a worker thread reads and feeds a step
+                         ahead; its own time is `<prefix>
+                         .feed_worker_s`, no part of a step's wall)
 - **host_dispatch**    — `train.dispatch`: Python + runtime time to
                          *submit* the jitted step, argument transfer
                          included (async dispatch: this returns before
@@ -46,7 +48,6 @@ from paddle_tpu.obs import metrics as _metrics
 PARTS = ("data_wait", "host_dispatch", "device_step", "handlers",
          "checkpoint_stall")
 SPAN_PART = {
-    "train.input_wait.reader": "data_wait",
     "train.input_wait.feeder": "data_wait",
     "train.dispatch": "host_dispatch",
     "train.fetch": "device_step",
@@ -100,8 +101,7 @@ class StepTimeline:
         if slow:
             median_s = median * 1e-9
             parts = {
-                "input_wait_s": split.get("train.input_wait.reader", 0)
-                + split.get("train.input_wait.feeder", 0),
+                "input_wait_s": split.get("train.input_wait.feeder", 0),
                 "dispatch_s": split.get("train.dispatch", 0),
                 "fetch_s": split.get("train.fetch", 0),
                 "fence_s": split.get("train.fence", 0),
@@ -140,8 +140,8 @@ class StepTimeline:
 
     def fractions(self) -> dict:
         """Shares of the loop's wall: the parts cover a step from the
-        reader's call to the last handler's return, but for the
-        BeginIteration handler. All zero before the first step."""
+        wait for its fed batch to the last handler's return, but for
+        the BeginIteration handler. All zero before the first step."""
         wall = sum(self._totals_ns.values())
         names = {"data_wait": "data_wait_frac",
                  "host_dispatch": "host_overhead_frac",

@@ -117,6 +117,13 @@ def current() -> Optional[tuple]:
     return _ctx.stack[-1][:2] if _ctx.stack else None
 
 
+def context() -> Optional[tuple]:
+    """This thread's innermost context as it is, for `attach` on
+    another thread of THIS process (a worker's spans join the trace
+    and keep its counted ids). Across processes, `inject` a carrier."""
+    return _ctx.stack[-1] if _ctx.stack else None
+
+
 def inject() -> Optional[dict]:
     """Current context as a carrier dict, or None outside any trace."""
     cur = current()
@@ -140,7 +147,8 @@ def extract(carrier) -> Optional[tuple]:
 
 
 class attach:
-    """Context manager: make `carrier` the current context WITHOUT
+    """Context manager: make `carrier` (or what `context()` gave on
+    another thread of this process) the current context WITHOUT
     opening a span — spans created inside become children of the
     remote parent. A None/malformed carrier attaches nothing (the
     body still runs), unless `or_begin`: then, where the thread is in
@@ -148,6 +156,9 @@ class attach:
     counter) and the spans inside are its roots."""
 
     def __init__(self, carrier, or_begin: bool = False):
+        if isinstance(carrier, tuple):  # another thread's `context()`
+            self._entry = carrier
+            return
         parsed = extract(carrier)
         if parsed is not None:
             self._entry = parsed + (False,)

@@ -9,6 +9,8 @@ forwardBackward + updater; the whole mesh runs it SPMD.
 
 from __future__ import annotations
 
+import contextlib
+import itertools
 import logging
 from typing import Callable, Optional
 
@@ -20,6 +22,7 @@ from paddle_tpu.core import flags as _flags
 from paddle_tpu.core import rng as _rng
 from paddle_tpu.core.config import ModelConf, OptimizationConf
 from paddle_tpu.core.stat import GLOBAL_STATS
+from paddle_tpu.data.reader import Buffered
 from paddle_tpu.obs import metrics as _obs
 from paddle_tpu.obs import tracing as _tracing
 from paddle_tpu.obs.timeline import StepTimeline
@@ -57,6 +60,13 @@ class _NullPreemptionGuard:
 
 
 _PASS_OVER = object()  # what next() gives when the reader has ended
+
+# Fed batches that may wait in the queue between the pass's worker and
+# the training thread: one to be taken at once and one behind it, so
+# that a feeder a little slower than the step for a batch or two does
+# not reach the chip. Beside them one is in the step and one may be in
+# the worker's hand. A constant: nothing is better served by another.
+FEED_AHEAD = 2
 
 
 def _feed_size(feed) -> tuple:
@@ -332,6 +342,46 @@ class SGD:
         """reader yields raw batches (lists of sample tuples); feeder
         converts them to Arg dicts.
 
+        The input path runs a step ahead, off this thread. Every pass
+        starts ONE worker thread (`feed-ahead`, after its BeginPass)
+        that calls `reader()`, takes its batches in order, skips those
+        a resume has trained already, calls `feeder(raw)` and puts the
+        result into a FIFO queue of FEED_AHEAD fed batches
+        (`data.reader.Buffered`, the reference's DoubleBuffer,
+        DataProvider.h:249). This thread takes them out in the same
+        order. So for every batch k: reader -> feeder (worker) ->
+        BeginIteration(k) -> step -> EndIteration(k) (this thread), the
+        batches, their order and every loss being what an inline
+        feeder gives; what is new is that the reader and the feeder of
+        the batches after k, as far as the queue lets the worker run,
+        may run BEFORE BeginIteration(k+1) and while batch k is in its
+        step. A reader or feeder may assume: it is called from one
+        thread at a time, in order, once a batch; it may not assume
+        that thread to be the main one, nor read what a BeginIteration
+        handler of its own batch writes, and should keep to numpy (the
+        transfer to the device is the step's). Its exception is raised
+        here, by the wait for the batch it belonged to, after every
+        batch before it has been trained. Handlers, evaluators, the
+        watchdog, checkpoints and every JAX call stay on this thread.
+
+        However the call ends (the last pass over, a handler's
+        exception, `Preempted`, `WatchdogAbort`) the worker is stopped
+        and joined first. Batches fed ahead and not trained are
+        dropped: a preemption's checkpoint counts trained batches
+        only, and the deterministic reader replays the rest after the
+        resume. A watchdog rollback keeps the queue: the data stream
+        does not roll back.
+
+        Spans: `train.input_wait.feeder` is this thread blocked on the
+        queue for the next fed batch (nothing of the reader runs here,
+        so `train.input_wait.reader` is not opened); the worker's own
+        work is `feed_ahead.reader` and `feed_ahead.feeder`, in the
+        call's trace and under no step. Counters: `trainer
+        .feed_ahead_ready` / `.feed_ahead_waited` (steps whose fed
+        batch was, or was not, in the queue when asked for) and
+        `trainer.feed_worker_s` (the worker's seconds in the reader
+        and the feeder).
+
         checkpoint_mode: None = the `checkpoint_mode` flag; "sync" =
         blocking per-pass save_pass; "async" = overlapped sharded
         writes (trainer/async_checkpoint.py) where only the
@@ -384,77 +434,26 @@ class SGD:
         try:
           with guard, trace:
             self.last_trace_id = _tracing.current()[0]
+            context = _tracing.context()  # the workers' spans join it
             for pass_id in range(start_pass, num_passes):
                 event_handler(BeginPass(pass_id))
                 evals = self._make_evaluators()
                 costs = []
-                batch_iter = iter(reader())
-                if self.steps_per_dispatch > 1:
-                    self._run_pass_pipelined(
-                        pass_id, start_pass, skip_batches, batch_iter,
-                        feeder, event_handler, evals, costs, tl, wd,
-                        guard, save_dir, ckpt_mode, log_period,
+                first = skip_batches if pass_id == start_pass else 0
+                with contextlib.closing(self._fed_batches(
+                    reader, feeder, first, pass_id, event_handler, tl,
+                    context,
+                )) as fed:
+                    run_pass = (
+                        self._run_pass_pipelined
+                        if self.steps_per_dispatch > 1
+                        else self._run_pass
                     )
-                    batch_iter = None  # drained
-                batch_id = -1
-                while batch_iter is not None:
-                    with _tracing.span(
-                        "train.step", step_num=self.global_step,
-                        pass_id=pass_id, batch_id=batch_id + 1,
-                    ) as step:
-                        # the training thread blocked obtaining the
-                        # next fed batch: the reader, then the feeder;
-                        # the user's BeginIteration handler between
-                        # them is deliberately in neither (its cost is
-                        # not the input path's)
-                        with _tracing.span(
-                                "train.input_wait.reader") as waited:
-                            raw = next(batch_iter, _PASS_OVER)
-                            batch_id += 1
-                            # a batch trained before the preemption
-                            # (its work lives in the flushed
-                            # checkpoint): the deterministic reader
-                            # replays it, the loop drops it
-                            no_step = raw is _PASS_OVER or (
-                                pass_id == start_pass
-                                and batch_id < skip_batches)
-                            if no_step:
-                                waited.discard()
-                        if no_step:
-                            step.discard()
-                            if raw is _PASS_OVER:
-                                break
-                            continue
-                        tl.add(waited)
-                        event_handler(BeginIteration(pass_id, batch_id))
-                        with _tracing.span(
-                                "train.input_wait.feeder") as waited:
-                            feed = feeder(raw)
-                        tl.add(waited)
-                        self._count_feed(feed)
-                        cost, finite, outs = self.run_step(
-                            feed, wd.lr_scale() if wd else 1.0,
-                            timeline=tl,
-                        )
-                        with _tracing.span("train.handlers") as handled:
-                            self._after_batch(
-                                pass_id, batch_id, cost, finite, outs,
-                                feed, evals, costs, wd, save_dir,
-                                ckpt_mode, event_handler, log_period,
-                            )
-                        tl.add(handled)
-                    tl.end_step(step)
-                    if guard.preempted:
-                        # the in-flight batch completed and is counted
-                        # in batch_id+1: the flush loses zero
-                        # completed-batch work
-                        self._preempt_flush(
-                            save_dir, ckpt_mode, pass_id, batch_id + 1
-                        )
-                        raise wdg.Preempted(
-                            pass_id, batch_id + 1, save_dir
-                        )
-                skip_batches = 0
+                    run_pass(
+                        pass_id, first, fed, event_handler, evals,
+                        costs, tl, wd, guard, save_dir, ckpt_mode,
+                        log_period,
+                    )
                 results = {ev.name: ev.result() for ev in evals}
                 if test_reader is not None:
                     tr = self.test(test_reader, feeder)
@@ -535,6 +534,63 @@ class SGD:
                             "handling a training error"
                         )
 
+    def _feed_ahead(self, reader, feeder, first, context):
+        """A pass's `(batch_id, feed)` in the reader's order, made on
+        the pass's worker thread (numpy only, no JAX): the reader's
+        next batch under `feed_ahead.reader`, the feeder's call under
+        `feed_ahead.feeder`. Batches before `first` are read and not
+        fed. `trainer.feed_worker_s` counts the seconds in both."""
+        busy = _obs.get_registry().counter("trainer.feed_worker_s")
+        batch_iter = iter(reader())
+        for batch_id in itertools.count():
+            with _tracing.attach(context):
+                with _tracing.span("feed_ahead.reader") as read:
+                    raw = next(batch_iter, _PASS_OVER)
+                busy.inc(read.dur_s)
+                if raw is _PASS_OVER:
+                    return
+                if batch_id < first:
+                    continue
+                with _tracing.span(
+                        "feed_ahead.feeder", batch_id=batch_id) as fed:
+                    feed = feeder(raw)
+                busy.inc(fed.dur_s)
+            yield batch_id, feed
+
+    def _fed_batches(self, reader, feeder, first, pass_id,
+                     event_handler, tl, context):
+        """The one source of a pass's fed batches, for both loops:
+        `(batch_id, feed)` in the reader's order from batch `first`
+        on. A worker thread runs `_feed_ahead` up to FEED_AHEAD batches
+        ahead; here, on the training thread, each `next()` blocks for
+        the next fed batch under `train.input_wait.feeder` (counted
+        `trainer.feed_ahead_ready` where it was waiting already, else
+        `trainer.feed_ahead_waited`), fires its BeginIteration and
+        counts its rows and bytes. Closing it, on any way out of the
+        pass, stops and joins the worker and drops what was fed ahead."""
+        reg = _obs.get_registry()
+        with Buffered(
+            lambda: self._feed_ahead(reader, feeder, first, context),
+            FEED_AHEAD, name="feed-ahead",
+        ) as ahead:
+            while True:
+                ready = ahead.ready()
+                with _tracing.span(
+                        "train.input_wait.feeder") as waited:
+                    item = next(ahead, _PASS_OVER)
+                    if item is _PASS_OVER:
+                        waited.discard()
+                if item is _PASS_OVER:
+                    return
+                tl.add(waited)
+                reg.counter(
+                    "trainer.feed_ahead_ready" if ready
+                    else "trainer.feed_ahead_waited").inc()
+                batch_id, feed = item
+                event_handler(BeginIteration(pass_id, batch_id))
+                self._count_feed(feed)
+                yield item
+
     def _count_feed(self, feed) -> None:
         """A fed batch goes to the step: count its rows and bytes."""
         rows, nbytes = _feed_size(feed)
@@ -575,10 +631,46 @@ class SGD:
             self._log_parameter_stats(pass_id, batch_id)
         return action
 
-    def _run_pass_pipelined(self, pass_id, start_pass, skip_batches,
-                            batch_iter, feeder, event_handler, evals,
-                            costs, tl, wd, guard, save_dir, ckpt_mode,
-                            log_period):
+    def _run_pass(self, pass_id, first, fed, event_handler, evals,
+                  costs, tl, wd, guard, save_dir, ckpt_mode, log_period):
+        """One pass, a step a batch: each `train.step` span covers the
+        wait for the batch's feed, its dispatch and fetch, and its
+        handlers."""
+        batch_id = first - 1
+        while True:
+            with _tracing.span(
+                "train.step", step_num=self.global_step,
+                pass_id=pass_id, batch_id=batch_id + 1,
+            ) as step:
+                item = next(fed, None)
+                if item is None:
+                    step.discard()
+                    return
+                batch_id, feed = item
+                cost, finite, outs = self.run_step(
+                    feed, wd.lr_scale() if wd else 1.0, timeline=tl,
+                )
+                with _tracing.span("train.handlers") as handled:
+                    self._after_batch(
+                        pass_id, batch_id, cost, finite, outs, feed,
+                        evals, costs, wd, save_dir, ckpt_mode,
+                        event_handler, log_period,
+                    )
+                tl.add(handled)
+            tl.end_step(step)
+            if guard.preempted:
+                # the in-flight batch completed and is counted in
+                # batch_id+1: the flush loses zero completed-batch
+                # work; what was fed ahead is dropped, and the
+                # deterministic reader replays it after the resume
+                self._preempt_flush(
+                    save_dir, ckpt_mode, pass_id, batch_id + 1
+                )
+                raise wdg.Preempted(pass_id, batch_id + 1, save_dir)
+
+    def _run_pass_pipelined(self, pass_id, first, fed, event_handler,
+                            evals, costs, tl, wd, guard, save_dir,
+                            ckpt_mode, log_period):
         """One pass with steps_per_dispatch > 1: batches are buffered
         and dispatched as scan-of-steps chunks (run_steps). Per-batch
         semantics preserved: BeginIteration fires when a batch is
@@ -591,7 +683,7 @@ class SGD:
         `train.step` span covers a chunk: its batches' input waits,
         one dispatch, one fetch, the handlers of all its batches."""
         spd = self.steps_per_dispatch
-        done_upto = skip_batches  # batches of this pass fully trained
+        done_upto = first  # batches of this pass fully trained
 
         def _sig(feed):
             return (
@@ -634,7 +726,6 @@ class SGD:
                         observe = False
             tl.add(handled)
 
-        batch_id = -1
         held = None  # (batch_id, feed, sig) whose signature closed a chunk
         pass_over = False
         while not pass_over:
@@ -645,27 +736,11 @@ class SGD:
             ) as step:
                 while len(buf) < spd:
                     _check_preempt()
-                    with _tracing.span(
-                            "train.input_wait.reader") as waited:
-                        raw = next(batch_iter, _PASS_OVER)
-                        batch_id += 1
-                        no_step = raw is _PASS_OVER or (
-                            pass_id == start_pass
-                            and batch_id < skip_batches)
-                        if no_step:
-                            waited.discard()
-                    if raw is _PASS_OVER:
+                    item = next(fed, None)
+                    if item is None:
                         pass_over = True
                         break
-                    if no_step:
-                        continue
-                    tl.add(waited)
-                    event_handler(BeginIteration(pass_id, batch_id))
-                    with _tracing.span(
-                            "train.input_wait.feeder") as waited:
-                        feed = feeder(raw)
-                    tl.add(waited)
-                    self._count_feed(feed)
+                    batch_id, feed = item
                     fsig = _sig(feed)
                     if buf and fsig != sig:
                         held = (batch_id, feed, fsig)

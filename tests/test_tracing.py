@@ -790,7 +790,7 @@ class TestTrainerStepSpans:
         kids = [s for s in global_recorder.spans()
                 if s["parent_id"] == steps[3]["span_id"]]
         assert {k["name"] for k in kids} == {
-            "train.input_wait.reader", "train.input_wait.feeder",
+            "train.input_wait.feeder",
             "train.dispatch", "train.fetch", "train.fence",
             "train.handlers",
         }
