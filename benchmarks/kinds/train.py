@@ -5,8 +5,10 @@ steps by the window's own call and feed (`SGD.train` over
 `data.reader.batched` and `data.feeder.DataFeeder`), keeps what the
 comparison reads, and hands the same trainer to the window. The window is
 one more `SGD.train` call whose reader ends the pass when the time is up:
-the rate is all the rows (or real tokens) of the steps that ended in it
-over the seconds from its first BeginIteration to its last EndIteration.
+the rate (`train_units_per_s`, the kind's one rate) is all the units of
+the steps that ended in it, which are what the cell's `traffic.count`
+counts (rows, or the real steps of one length group: images, tokens), over
+the seconds from its first BeginIteration to its last EndIteration.
 The reference follows the first steps after the window has closed, the
 device's memory has been read and the trainer is freed.
 """
@@ -72,7 +74,9 @@ def build_trainer(cell, seed, devices, params):
 
 class Loop:
     """The reader, the feeder and the event handler of one `SGD.train`
-    call, with the harness's spans around its own calls."""
+    call, with the harness's spans around its own reader and feeder calls
+    (since the program feeds a step ahead these run on its worker thread;
+    `input_wait_share` alone still reads them)."""
 
     def __init__(self, pool, feeder, spans, unit, stream, stop):
         self.pool, self.feeder, self.spans = pool, feeder, spans
@@ -115,7 +119,6 @@ class Loop:
         with self.spans.span("feeder"):
             out = self.feeder(raw)
         self.feeds.append(time.perf_counter() - t0)
-        self.spans.begin("run_step")
         return out
 
     def handle(self, event):
@@ -124,7 +127,6 @@ class Loop:
         if isinstance(event, BeginIteration) and self.t_first is None:
             self.t_first = time.perf_counter()
         if isinstance(event, EndIteration):
-            self.spans.end("run_step")
             self.t_last = time.perf_counter()
             self.ends.append(self.t_last)
             self.work += self.pool.count(self.rows[event.batch_id], self.unit)
@@ -136,7 +138,6 @@ class Loop:
     def run(self, trainer):
         trainer.train(reader=self.reader, feeder=self.feed, num_passes=1,
                       event_handler=self.handle)
-        self.spans.end("run_step")
 
 
 def _program_readings(trainer, opt, params0_fn, loop):
@@ -272,7 +273,7 @@ def _by_tenth(ends, values):
 
 def _longest_step(loop):
     """Which step took longest, when it ended, and how much of it was the
-    feeder: the rest is `run_step` (transfer, dispatch, the loss fetch)."""
+    feeder's call for its batch (on the program's worker thread)."""
     if not loop.ends:
         return None
     steps = [b - a for a, b in zip([loop.t_first] + loop.ends, loop.ends)]

@@ -5,9 +5,10 @@ children, paddle_tpu/obs/tracing.py); under a profiler session each lies in
 the `.xplane.pb` as a host event of that name, on the clock of the device's
 operations. Here the device's idle time is put down to those spans exactly:
 an idle gap is split among the spans that overlap it, by overlap, and where
-spans nest the innermost takes what it covers. And the device's busy time is
-put down to the scope its operations carry (`<type>:<name>` of a layer,
-`optimizer`, `watchdog`).
+spans nest the innermost takes what it covers (trace_reduce.cover). The
+`feed-ahead` worker's spans lie on a thread of their own and are timed
+beside them. And the device's busy time is put down to the scope its
+operations carry (`<type>:<name>` of a layer, `optimizer`, `watchdog`).
 
 A trace of a program that has no such spans (the parent of the PR that
 brought them) gives None, and the readers then report nothing.
@@ -16,71 +17,35 @@ brought them) gives None, and the readers then report nothing.
 from __future__ import annotations
 
 import functools
-import glob
 import os
 import re
 
 from benchmarks import harness, trace_reduce
-from benchmarks.trace_reduce import clip, gaps, total, union
+from benchmarks.trace_reduce import (TOP_GAPS, UNCOVERED, clip, cover, gaps,
+                                     total, union)
 
-TRACE_DIR = os.path.join(harness.ROOT, ".bench_trace")   # run.py's
+# Nothing here reads it since run.py hands the readers the trace's path;
+# tests/test_benchmark_harness.py, which no `benchmark` PR may edit, patches it
+TRACE_DIR = os.path.join(harness.ROOT, ".bench_trace")
 WINDOW_SPAN = "bench_window"                             # run.py's
 ROOT_SPAN = "train.step"
-# innermost first: a span takes of a gap only what no span before it took
-SPANS = ("train.h2d", "train.input_wait.reader", "train.input_wait.feeder",
-         "train.dispatch", "train.fetch", "train.fence", "train.handlers",
-         "train.checkpoint", ROOT_SPAN)
-UNCOVERED = "uncovered"
-TOP_GAPS = 5
-
-
-def intersect(a, b):
-    """The parts of merged intervals `a` that lie in merged intervals `b`."""
-    out, j = [], 0
-    for s, e in a:
-        while j < len(b) and b[j][1] <= s:
-            j += 1
-        k = j
-        while k < len(b) and b[k][0] < e:
-            out.append((max(s, b[k][0]), min(e, b[k][1])))
-            k += 1
-    return out
-
-
-def subtract(a, b):
-    """The parts of merged intervals `a` that lie in none of merged `b`."""
-    out, j = [], 0
-    for s, e in a:
-        while j < len(b) and b[j][1] <= s:
-            j += 1
-        at, k = s, j
-        while k < len(b) and b[k][0] < e:
-            if b[k][0] > at:
-                out.append((at, b[k][0]))
-            at = max(at, b[k][1])
-            k += 1
-        if at < e:
-            out.append((at, e))
-    return out
-
-
-def cover(idle, spans, order=SPANS):
-    """-> {name: the parts of `idle` put down to that span}, `UNCOVERED`
-    for what lies in none. Exact: the parts are disjoint and add up to
-    `idle`. `idle`: merged intervals; `spans`: {name: merged intervals}."""
-    out, left = {}, list(idle)
-    for name in order:
-        out[name] = intersect(left, spans.get(name, []))
-        left = subtract(left, out[name])
-    out[UNCOVERED] = left
-    return out
+# the training thread's, innermost first: a span takes of a gap only what
+# no span before it took
+SPANS = ("train.h2d", "train.input_wait.feeder", "train.dispatch",
+         "train.fetch", "train.fence", "train.handlers", "train.checkpoint",
+         ROOT_SPAN)
+# the `feed-ahead` worker's, on a thread of its own: timed, and no part of
+# the cover, or the worker, which is always inside its feeder, would take
+# every idle gap
+WORKER_SPANS = ("feed_ahead.reader", "feed_ahead.feeder")
 
 
 def split(device_ops, host_spans, window, order=SPANS):
     """The arithmetic, on plain data (trace_reduce.read's), in its unit of
-    time. -> the window's length, the busy time of the fullest device, each
-    span's own time in the window, the idle time by span, and the longest
-    idle gaps with what covers each."""
+    time. -> the window's length, the busy time of the fullest device, the
+    time in the window of each span of `host_spans` (those of `order` and
+    any beside them, such as the worker's), the idle time by span of
+    `order`, and the longest idle gaps with what covers each."""
     lo, hi = window
     if not device_ops or hi <= lo:
         return None
@@ -94,7 +59,7 @@ def split(device_ops, host_spans, window, order=SPANS):
     return {
         "window": hi - lo,
         "busy": total(busy[fullest]),
-        "span_time": {n: total(spans.get(n, [])) for n in order},
+        "span_time": {n: total(iv) for n, iv in spans.items()},
         "idle_by_span": {n: total(iv) for n, iv in by_span.items()},
         "gaps": [(e - s, {n: total(iv)
                           for n, iv in cover([(s, e)], spans, order).items()
@@ -102,26 +67,17 @@ def split(device_ops, host_spans, window, order=SPANS):
     }
 
 
-def newest_trace():
-    """The `.xplane.pb` of this run: run.py traces into TRACE_DIR/<cell>,
-    which it empties first, so the newest file there is this run's."""
-    files = [trace_reduce.find_xplane(d)
-             for d in glob.glob(os.path.join(TRACE_DIR, "*"))]
-    files = [f for f in files if f]
-    return max(files, key=os.path.getmtime) if files else None
-
-
 def _trace_of(run):
-    """(path, mtime) of the traced run's file, or None where the run has
-    no trace."""
-    path = newest_trace() if run.get("trace") else None
+    """(path, mtime) of the traced run's file, which run.py hands over as
+    `trace_file`, or None where the run has no trace."""
+    path = run.get("trace_file") if run.get("trace") else None
     return (path, os.path.getmtime(path)) if path else None
 
 
 @functools.lru_cache(maxsize=1)
 def _read(path, mtime):
     """One pass over the file serves both reductions."""
-    return trace_reduce.read(path, SPANS, WINDOW_SPAN)
+    return trace_reduce.read(path, SPANS + WORKER_SPANS, WINDOW_SPAN)
 
 
 @functools.lru_cache(maxsize=1)

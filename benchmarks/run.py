@@ -101,6 +101,9 @@ def measure(cell, seed, seconds, trace, devices, t_start=T_START,
     """Everything after the look for a chip: -> the result as a dict (the
     last line's keys) plus `log`."""
     ctx = Context(cell, seed, seconds, trace, devices, t_start)
+    # what a "unit" of the kind's one rate is in this cell
+    harness.say(cell=cell.name, rate_metric=cell.workload.get("rate_metric"),
+                counts=cell.traffic.get("count", {}).get("unit"))
     if trace:
         ctx.seconds = min(seconds, cell.workload.get("trace_seconds", 3))
     run = cell.kind.run(ctx)
@@ -116,7 +119,7 @@ def measure(cell, seed, seconds, trace, devices, t_start=T_START,
         names = cell.workload.get("spans", [])
         reduced = (trace_reduce.reduce_file(ctx.trace_file, names, WINDOW_SPAN)
                    if ctx.trace_file else None)
-        run["trace"] = reduced
+        run.update(trace=reduced, trace_file=ctx.trace_file)
         if reduced:
             device.update(busy_s=reduced["busy_s"],
                           window_s=reduced["window_s"])

@@ -37,6 +37,13 @@ def test_named_files_exist_and_names_are_allowed(bench):
         cell = harness.Cell(w["name"])
         assert cell.kind.run and cell.model.program_conf
         assert cell.limits, f"{w['name']} compares nothing"
+        if cell.workload["kind"] == "train":
+            # the kind has one rate, named for no unit: the cell's file
+            # says what a unit is
+            assert cell.workload["rate_metric"] == "train_units_per_s"
+            assert NAME.match(cell.traffic["count"]["unit"])
+            assert "train_units_per_s" in {
+                m["name"] for m in cell.metrics("end_to_end")}
     for m in bench["end_to_end"] + bench["per_layer"]:
         assert NAME.match(m["name"]) and UNIT.match(m["unit"]), m
         assert m["better"] in ("lower", "higher")
@@ -62,6 +69,9 @@ def test_each_layer_metric_moves_a_metric_its_cells_report(bench):
         assert cell.metrics("per_layer")
         assert any("mfu" in m["name"].split(".")
                    for m in cell.metrics("per_layer"))
+        if cell.workload["kind"] == "train":
+            assert {m["moves"] for m in cell.metrics("per_layer")} == {
+                "train_units_per_s"}
 
 
 def test_new_cell_kind_and_reader_are_found_as_new_files(tmp_path):
@@ -104,6 +114,172 @@ def test_new_cell_kind_and_reader_are_found_as_new_files(tmp_path):
                          text=True, cwd=str(root))
     assert out.returncode == 0, out.stderr
     assert out.stdout.strip() == "3"
+
+
+# A training cell counted in tokens, as a later PR brings one: new files and
+# entries appended to lists. The program is a graph of the program's own
+# layers at toy sizes; the reference beside it imports nothing of it.
+TOKEN_CELL = "toy_tokens.train_len4to8"
+TOKEN_FILES = {
+    "configs/toy_tokens.json": json.dumps({
+        "name": "toy_tokens", "model": "toy_tokens", "vocab": 50,
+        "emb_dim": 16, "num_classes": 4, "param_dtype": "float32",
+        "matmul_precision": "float32", "reduced": [],
+        "optimizer": {"method": "adam", "learning_rate": 0.01, "beta1": 0.9,
+                      "beta2": 0.999, "epsilon": 1e-8}}),
+    f"workloads/{TOKEN_CELL}.json": json.dumps({
+        "kind": "train", "rate_metric": "train_units_per_s", "mesh": None,
+        "traffic": {
+            "batch": 8, "pool": 16, "lengths": {"words": [4, 8]},
+            "slots": [{"name": "words", "type": "ids_seq", "vocab": 50,
+                       "len": "words"},
+                      {"name": "label", "type": "ids", "vocab": 4}],
+            "count": {"unit": "tokens", "length_group": "words"}},
+        "limits": {"loss1_gap": 1e-4, "grad1_gap": 1e-3,
+                   "delta_gap": 1e-3}}),
+    "layer_metrics/mfu.tokens.json": json.dumps({"reader": "mfu"}),
+    "models/toy_tokens.py": """
+from benchmarks.reference import toy_tokens as reference
+
+
+def program_conf(cfg):
+    from paddle_tpu import dsl
+
+    with dsl.model() as g:
+        ids = dsl.data("words", (1,), is_seq=True, is_ids=True)
+        lbl = dsl.data("label", (1,), is_ids=True)
+        h = dsl.embedding(ids, size=cfg["emb_dim"], vocab_size=cfg["vocab"],
+                          name="emb")
+        out = dsl.fc(dsl.seq_pool(h, pool_type="average"),
+                     size=cfg["num_classes"], name="output")
+        dsl.classification_cost(out, lbl)
+        g.conf.output_layer_names.append("output")
+    return g.conf
+
+
+def reference_batch(cols):
+    return cols
+
+
+def train_flops_per_row(cfg, traffic):      # a row is a token here
+    return 6.0 * cfg["emb_dim"] * cfg["num_classes"]
+""",
+    "reference/toy_tokens.py": """
+import jax
+import jax.numpy as jnp
+
+
+def param_spec(cfg):
+    v, e, c = cfg["vocab"], cfg["emb_dim"], cfg["num_classes"]
+    return {"_emb.w0": ((v, e), ("normal", 1.0)),
+            "_output.w0": ((e, c), ("normal", e ** -0.5)),
+            "_output.wbias": ((c,), ("const", 0.0))}
+
+
+def loss(cfg, p, b, mode="f32"):
+    lens = b["words_lens"]
+    real = jnp.arange(b["words"].shape[1])[None, :] < lens[:, None]
+    pooled = jnp.sum(p["_emb.w0"][b["words"]] * real[..., None],
+                     axis=1) / lens[:, None]
+    logits = jnp.dot(pooled, p["_output.w0"],
+                     precision="highest") + p["_output.wbias"]
+    picked = jnp.take_along_axis(logits, b["label"][:, None], axis=1)[:, 0]
+    return jnp.mean(jax.nn.logsumexp(logits, axis=1) - picked)
+"""}
+
+
+def _tree(top):
+    out = {}
+    for d, _dirs, files in os.walk(top):
+        if "__pycache__" in d:
+            continue
+        for f in files:
+            path = os.path.join(d, f)
+            with open(path, "rb") as fh:
+                out[os.path.relpath(path, top)] = fh.read()
+    return out
+
+
+def test_a_token_counted_training_cell_is_new_files_and_appended_entries(
+        tmp_path):
+    """`train_units_per_s` is the kind's one rate: a cell counted in tokens
+    joins it by appending its name to the metric's `workloads`, brings its
+    own files, and edits none that is there. Measured here on the CPU, so
+    the rate is held to its arithmetic alone."""
+    from benchmarks import traffic
+
+    root = tmp_path / "repo"
+    shutil.copytree(os.path.join(ROOT, "benchmarks"), root / "benchmarks",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    before = harness.load_benchmark()
+    bench = json.loads(json.dumps(before))
+    bench["configs"].append({
+        "name": "toy_tokens", "source": "this test", "reduced": [],
+        "file": "benchmarks/configs/toy_tokens.json", "why": "test"})
+    bench["workloads"].append({
+        "name": TOKEN_CELL, "config": "toy_tokens", "chips": 1,
+        "traffic": "train_len4to8", "why": "test"})
+    bench["per_layer"].append({
+        "name": "mfu.tokens", "unit": "%", "better": "higher",
+        "source": "device_trace", "layer": "step program and model graph",
+        "moves": "train_units_per_s", "workloads": [TOKEN_CELL]})
+    rate = {m["name"]: m for m in bench["end_to_end"]}["train_units_per_s"]
+    rate["workloads"].append(TOKEN_CELL)
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    for rel, text in TOKEN_FILES.items():
+        (root / "benchmarks" / rel).write_text(text)
+
+    code = (
+        "import json, sys; sys.path[:0] = [%r, %r]\n"
+        "import jax\n"
+        "from benchmarks import harness, run\n"
+        "assert harness.ROOT == %r, harness.ROOT\n"
+        "cell = harness.Cell(%r)\n"
+        "res = run.measure(cell, 2 ** 31 + 5, 1.0, False,\n"
+        "                  jax.devices()[:1], peak={'bf16_flops': 1e12})\n"
+        "print(json.dumps(res))\n" % (str(root), ROOT, str(root), TOKEN_CELL))
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, cwd=str(root),
+                         env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert out.returncode == 0, out.stderr[-3000:]
+    lines = [json.loads(x) for x in out.stdout.splitlines()
+             if x.startswith("{")]
+    log = {k: v for line in lines[:-1] for k, v in line.items()}
+    res = lines[-1]
+    assert res["correct"], (res["compared"], res["problems"])
+    assert set(res["metrics"]) == {"train_units_per_s", "setup_s"}
+    assert res["metrics"]["train_units_per_s"]["unit"] == "units/s"
+    # an earlier line of the log says what a unit is in this cell
+    assert log["counts"] == "tokens" and log["cell"] == TOKEN_CELL
+    # the rate: the real tokens of the steps that ended, over the window
+    cell = harness.Cell(TOKEN_CELL, root=str(root))
+    pool = traffic.Pool(cell.traffic, 2 ** 31 + 5)
+    steps = log["steps_in_window"]
+    tokens = sum(pool.count(rows, cell.traffic["count"])
+                 for rows, _ in zip(pool.batches(1), range(steps)))
+    assert steps == res["attempted"] > 0 and log["work"] == tokens
+    assert tokens != steps * cell.traffic["batch"]      # tokens, not rows
+    assert res["metrics"]["train_units_per_s"]["value"] == pytest.approx(
+        tokens / log["window_s"], rel=1e-12)
+
+    # the listing of the temporary tree against the original: every file
+    # that was there is there unchanged, and the cell's own are new
+    was, now = _tree(os.path.join(ROOT, "benchmarks")), _tree(
+        root / "benchmarks")
+    assert {f for f in was if now.get(f) != was[f]} == set()
+    assert set(now) - set(was) == set(TOKEN_FILES)
+    # and in BENCHMARK.json every list only grew at its end
+    after = json.loads((root / "BENCHMARK.json").read_text())
+    grown = json.loads(json.dumps(after))
+    {m["name"]: m for m in grown["end_to_end"]}[
+        "train_units_per_s"]["workloads"].pop()
+    for key, value in before.items():
+        if isinstance(value, list) and key not in ("command", "paths"):
+            assert grown[key][:len(value)] == value, key
+            assert len(after[key]) - len(value) == {
+                "configs": 1, "workloads": 1, "per_layer": 1}.get(key, 0)
+        else:
+            assert after[key] == value, key
 
 
 def test_last_line_has_the_contracts_keys():
@@ -156,10 +332,12 @@ def test_trace_reduce_on_a_hand_built_trace():
 
 
 def test_readers_read_the_run_and_return_nothing_where_nothing_is():
+    """Held over the metrics it names: a later PR appends others, whose
+    readers need only read nothing where nothing is."""
     cell = harness.Cell("resnet50.train_bs256")
     run = {"flops": 3e12, "window_s": 4.0, "chips": 1,
            "peak": {"bf16_flops": 1e12},
-           "spans": {"reader": 0.5, "feeder": 1.5, "run_step": 2.0},
+           "spans": {"reader": 0.5, "feeder": 1.5},
            "trace": {"busy_s": 1.5, "window_s": 4.0, "idle_share": 0.625}}
     got = {}
     for m in cell.metrics("per_layer"):
@@ -167,9 +345,12 @@ def test_readers_read_the_run_and_return_nothing_where_nothing_is():
         got[m["name"]] = reader.read(run, data)
         bare = dict(run, trace=None, spans={}, flops=0)
         assert reader.read(bare, data) is None, m["name"]
-    assert got == {"input_wait_share.images": 50.0,
-                   "mfu.images": pytest.approx(200.0),   # 3e12/1.5 s/1e12
-                   "device_idle_share.images": 62.5}
+    want = {"input_wait_share.images": 50.0,
+            "mfu.images": pytest.approx(200.0),   # 3e12/1.5 s/1e12
+            "device_idle_share.images": 62.5}
+    assert {n: got[n] for n in want} == want
+    # the others read the trace's file, and this run has none
+    assert not any(v is not None for n, v in got.items() if n not in want)
 
 
 def test_analytic_flops_equal_their_closed_forms():

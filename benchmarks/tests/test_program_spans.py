@@ -13,20 +13,22 @@ from benchmarks.layer_metrics import program_span_share, scope_busy_share
 
 NEW = ["loop_input_wait_share.images", "idle_input_wait_share.images",
        "idle_dispatch_share.images", "idle_other_share.images",
-       "hbm_pass_busy_share.images"]
+       "hbm_pass_busy_share.images", "feed_worker_share.images"]
 
 # one device, busy 10..40 and 70..90 of a window 0..100: idle 0..10, 40..70
-# and 90..100. Two steps; the second's reader call is cut by the window.
+# and 90..100. Two steps of the training thread; the second is cut by the
+# window. The worker's spans lie on a thread of their own, across the steps.
 OPS = {"/device:TPU:0": [("conv", 10, 40), ("bn", 70, 90)],
        "/device:TPU:1": [("conv", 10, 20)]}
 SPANS = {
     "train.step": [(0, 64), (66, 120)],
-    "train.input_wait.reader": [(0, 2), (66, 67)],
     "train.input_wait.feeder": [(2, 8), (40, 58)],
     "train.dispatch": [(8, 12), (58, 62)],
     "train.h2d": [(9, 11), (59, 61)],
     "train.fetch": [(12, 40), (62, 63)],
     "train.handlers": [(63, 64)],
+    "feed_ahead.reader": [(-5, 1), (50, 52)],
+    "feed_ahead.feeder": [(1, 50), (52, 110)],
 }
 
 
@@ -39,35 +41,43 @@ def test_a_gap_two_spans_share_is_split_by_overlap():
     r = _split()
     # the gap 40..70: feeder 18, dispatch 2 + h2d 2 inside it, fetch 1,
     # handlers 1, the root alone 63..64 is the handlers', 64..66 no span,
-    # 66..67 the reader, 67..70 the root's own time
+    # 66..70 the root's own time; the worker, busy all through, takes none
     longest, cover = r["gaps"][0]
     assert longest == 30
     assert cover == {"train.input_wait.feeder": 18, "train.dispatch": 2,
                      "train.h2d": 2, "train.fetch": 1, "train.handlers": 1,
-                     "uncovered": 2, "train.input_wait.reader": 1,
-                     "train.step": 3}
+                     "uncovered": 2, "train.step": 4}
     assert sum(cover.values()) == longest
-    # winner-takes-all (trace_reduce.attribute) calls all 30 the feeder's
-    children = [n for n in SPANS if n != "train.step"]
-    assert trace_reduce.attribute(
-        (40, 70), {k: trace_reduce.union(v) for k, v in SPANS.items()},
-        children) == "train.input_wait.feeder"
+    # the breakdown's name for the gap (trace_reduce.attribute) is the span
+    # that holds most of that split; the root, which wraps 28 of the 30,
+    # holds its own 4, and the worker's spans are not in the cell's list
+    merged = {k: trace_reduce.union(v) for k, v in SPANS.items()}
+    names = harness.Cell("resnet50.train_bs256").workload["spans"]
+    assert tuple(names) == program_spans.SPANS
+    assert trace_reduce.attribute((40, 70), merged, names) == \
+        "train.input_wait.feeder"
+    assert trace_reduce.attribute((66, 70), merged, names) == "train.step"
+    assert trace_reduce.attribute((64, 66), merged, names) == "uncovered"
 
 
 def test_where_spans_nest_the_innermost_takes_what_it_covers():
     r = _split()
     idle = r["idle_by_span"]
-    # 0..10: reader 2, feeder 6, dispatch 8..10 of which h2d has 9..10
+    # 0..10: the root alone 2, feeder 6, dispatch 8..10 of which h2d has
+    # 9..10
     assert idle["train.h2d"] == 1 + 2
     assert idle["train.dispatch"] == 1 + 2
-    assert idle["train.input_wait.reader"] == 2 + 1
     assert idle["train.input_wait.feeder"] == 6 + 18
-    assert idle["train.step"] == 3 + 10        # 67..70, and 90..100
+    assert idle["train.step"] == 2 + 4 + 10    # 0..2, 66..70 and 90..100
     assert idle["uncovered"] == 2
     assert sum(idle.values()) == r["window"] - r["busy"] == 50
-    # a span's own time is clipped to the window, nested or not
+    assert not set(idle) & set(program_spans.WORKER_SPANS)
+    # a span's own time is clipped to the window, nested or not, on the
+    # training thread or the worker's
     assert r["span_time"]["train.step"] == 64 + 34
     assert r["span_time"]["train.fetch"] == 28 + 1
+    assert r["span_time"]["feed_ahead.reader"] == 1 + 2
+    assert r["span_time"]["feed_ahead.feeder"] == 49 + 48
 
 
 def _shares(monkeypatch):
@@ -77,7 +87,7 @@ def _shares(monkeypatch):
     monkeypatch.setattr(program_spans, "of_run", lambda run: ns)
     cell = harness.Cell("resnet50.train_bs256")
     out = {}
-    for name in NEW[:4]:
+    for name in NEW[:4] + NEW[5:]:
         reader, data = cell.layer_metric(name)
         assert reader is program_span_share
         out[name] = reader.read({"trace": {}}, data)
@@ -86,23 +96,38 @@ def _shares(monkeypatch):
 
 def test_the_four_shares_and_the_busy_share_add_to_100(monkeypatch):
     r, got = _shares(monkeypatch)
-    assert got == {"loop_input_wait_share.images": 27.0,
-                   "idle_input_wait_share.images": 27.0,
+    # the worker's share is of the window, beside the others and no part
+    # of what adds to 100
+    assert got.pop("feed_worker_share.images") == 100.0
+    assert got == {"loop_input_wait_share.images": 24.0,
+                   "idle_input_wait_share.images": 24.0,
                    "idle_dispatch_share.images": 7.0,
-                   "idle_other_share.images": 16.0}
+                   "idle_other_share.images": 19.0}
     busy_share = 100.0 * r["busy"] / r["window"]
     idle = [v for k, v in got.items() if k.startswith("idle_")]
     assert busy_share + sum(idle) == pytest.approx(100.0, abs=1e-9)
 
 
-def test_a_program_without_the_spans_reads_nothing(monkeypatch, tmp_path):
+def test_a_program_without_the_spans_reads_nothing(monkeypatch):
     cell = harness.Cell("resnet50.train_bs256")
-    monkeypatch.setattr(program_spans, "TRACE_DIR", str(tmp_path))
     for name in NEW:
         reader, data = cell.layer_metric(name)
-        assert reader.read({"trace": None}, data) is None      # no trace
+        assert reader.read({"trace": None, "trace_file": "x"},
+                           data) is None                       # no trace
         assert reader.read({"trace": {"busy_s": 1}}, data) is None  # no file
     assert program_spans.split({}, {}, (0, 1)) is None
+    # a program that feeds on the training thread has no worker to read: a
+    # share of the window is nothing there, never 0
+    r = _split()
+    for name in program_spans.WORKER_SPANS:
+        del r["span_time"][name]
+    monkeypatch.setattr(program_spans, "of_run", lambda run: {
+        "window_s": r["window"], "span_s": r["span_time"],
+        "idle_s": r["idle_by_span"]})
+    reader, data = cell.layer_metric("feed_worker_share.images")
+    assert reader.read({"trace": {}}, data) is None
+    reader, data = cell.layer_metric("loop_input_wait_share.images")
+    assert reader.read({"trace": {}}, data) == 24.0
 
 
 def test_a_scope_is_read_off_an_operations_op_name():
@@ -198,12 +223,12 @@ def test_scopes_are_read_off_an_xplanes_bytes(tmp_path):
 def test_every_new_metric_resolves_to_a_reader_and_a_data_file():
     bench = harness.load_benchmark()
     entries = {m["name"]: m for m in bench["per_layer"]}
-    assert [m["name"] for m in bench["per_layer"]][-5:] == NEW
+    assert [m["name"] for m in bench["per_layer"]][-len(NEW):] == NEW
     for name in NEW:
         m = entries[name]
         assert m["workloads"] == ["resnet50.train_bs256"]
         assert (m["unit"], m["better"], m["moves"]) == (
-            "%", "lower", "train_images_per_s")
+            "%", "lower", "train_units_per_s")
         reader, data = harness.Cell(m["workloads"][0]).layer_metric(name)
         assert callable(reader.read)
         assert os.path.isfile(os.path.join(

@@ -5,9 +5,10 @@ A device plane is one whose name starts with `/device:TPU:`; its line
 `XLA Ops` holds one event per operation that ran on the chip. Busy time is
 the union of those intervals (the interval-union arithmetic of
 tools/trace_attribution.py), so operations that overlap are counted once.
-Host spans are the events of the host planes whose names the harness gave
-(`jax.profiler.TraceAnnotation`): they share the trace's clock, so an idle
-gap of the device can be put down to what the host was doing in it.
+Host spans are the events of the host planes whose names the cell's file
+gives (`spans`: the program's own, innermost first): they share the trace's
+clock, so an idle gap of the device is put down to what the host was doing
+in it, split exactly among the spans that overlap it (`cover`).
 """
 
 from __future__ import annotations
@@ -20,6 +21,7 @@ DEVICE_PREFIX = "/device:TPU:"
 OPS_LINE = "XLA Ops"
 TOP_OPS = 8
 TOP_GAPS = 5
+UNCOVERED = "uncovered"
 
 
 def union(intervals):
@@ -55,15 +57,56 @@ def gaps(busy, lo, hi):
     return out
 
 
+def intersect(a, b):
+    """The parts of merged intervals `a` that lie in merged intervals `b`."""
+    out, j = [], 0
+    for s, e in a:
+        while j < len(b) and b[j][1] <= s:
+            j += 1
+        k = j
+        while k < len(b) and b[k][0] < e:
+            out.append((max(s, b[k][0]), min(e, b[k][1])))
+            k += 1
+    return out
+
+
+def subtract(a, b):
+    """The parts of merged intervals `a` that lie in none of merged `b`."""
+    out, j = [], 0
+    for s, e in a:
+        while j < len(b) and b[j][1] <= s:
+            j += 1
+        at, k = s, j
+        while k < len(b) and b[k][0] < e:
+            if b[k][0] > at:
+                out.append((at, b[k][0]))
+            at = max(at, b[k][1])
+            k += 1
+        if at < e:
+            out.append((at, e))
+    return out
+
+
+def cover(idle, spans, order):
+    """-> {name: the parts of `idle` put down to that span}, `UNCOVERED`
+    for what lies in none. A span takes of `idle` only what no span before
+    it in `order` took, so where spans nest, list the innermost first.
+    Exact: the parts are disjoint and add up to `idle`. `idle`: merged
+    intervals; `spans`: {name: merged intervals}."""
+    out, left = {}, list(idle)
+    for name in order:
+        out[name] = intersect(left, spans.get(name, []))
+        left = subtract(left, out[name])
+    out[UNCOVERED] = left
+    return out
+
+
 def attribute(gap, spans, names):
-    """The name of the host span (of `names`, in that order of rank) that
-    covers most of `gap`; `other` where none covers any of it."""
-    best, best_cover = "other", 0
-    for name in names:
-        cover = total(clip(spans.get(name, []), gap[0], gap[1]))
-        if cover > best_cover:
-            best, best_cover = name, cover
-    return best
+    """The name of the host span that holds most of `gap` by `cover`'s
+    exact split (`names` innermost first: a root span that wraps the others
+    holds only its own time); `UNCOVERED` where most of it lies in none."""
+    parts = {n: total(iv) for n, iv in cover([gap], spans, names).items()}
+    return max(parts, key=parts.get)      # the first of equals: by rank
 
 
 def reduce_events(device_ops, host_spans, window, span_names):
@@ -100,11 +143,10 @@ def reduce_events(device_ops, host_spans, window, span_names):
 
 
 def _idle_by_span(idle, host_spans, span_names):
-    out = {}
-    for g in idle:
-        name = attribute(g, host_spans, span_names)
-        out[name] = out.get(name, 0) + (g[1] - g[0])
-    return out
+    """The idle time by span, split exactly; spans that hold none of it
+    are left out."""
+    parts = cover(sorted(idle), host_spans, span_names)
+    return {n: total(iv) for n, iv in parts.items() if iv}
 
 
 _SHAPE = re.compile(r"[a-z]+[0-9]*\[[0-9,]*\]")
