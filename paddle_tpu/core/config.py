@@ -109,6 +109,10 @@ class ModelConf:
     input_layer_names: list = field(default_factory=list)
     output_layer_names: list = field(default_factory=list)
     sub_models: list = field(default_factory=list)  # list[SubModelConf]
+    # groups of consecutive layer names (a decoder block each) whose
+    # activations are not kept for the backward pass but recomputed in
+    # it: Network wraps each group in jax.checkpoint when training
+    recompute: list = field(default_factory=list)  # list[list[str]]
 
     def layer(self, name: str) -> LayerConf:
         for lc in self.layers:

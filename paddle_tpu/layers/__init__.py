@@ -6,6 +6,7 @@ from paddle_tpu.layers import (  # noqa: F401
     basic,
     conv,
     cost,
+    decoder,
     detection,
     extras,
     fused,

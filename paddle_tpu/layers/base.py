@@ -52,6 +52,11 @@ class Ctx:
     state: dict = field(default_factory=dict)
     updated_state: dict = field(default_factory=dict)
     extra: dict = field(default_factory=dict)
+    # the dtype Network's precision policy has compute layers work in
+    # (bfloat16 under AMP), None without a policy. A cost layer is handed
+    # float32 whatever the policy; one that holds a matmul rounds that
+    # matmul's operands to this
+    compute_dtype: Optional[object] = None
 
     def split(self, name: str) -> jax.Array:
         assert self.rng is not None, "layer needs rng but Ctx.rng is None"

@@ -1,11 +1,28 @@
-"""MoE layer: sparsely-activated expert FFN with load-balancing loss.
+"""MoE layer: sparsely-activated expert FFN. One layer type, two routings.
 
-Beyond-reference capability (expert parallelism). The layer emits its
-aux load-balancing loss as an extra output `<name>@aux` that the DSL
-wires into a sum_cost, so the trainer's multi-cost reduction (the same
-mechanism the VAE demo uses) applies it; expert weights carry an
-"expert" leading dim that parallel/sharding can place on the mesh model
-axis for EP.
+With `top_k` in its attrs it is the expert layer of a present-day decoder
+block (models/mellum.py): softmax-then-top-k routing over ALL
+`num_experts`, of which the layer HOLDS a contiguous share (`held`: first
+index and count, as one chip of an expert-parallel layer does), gated
+(SiLU) experts, renormalised weights, and no dropped token whatever the
+imbalance (ops/moe.py `dropless_moe`: a sort, grouped matrix products, a
+weighted gather). It computes its own experts' part of the result; what
+absent experts would add is left out, and no code stands in for the
+exchange. The router's weights stay the float32 masters under the bfloat16
+policy (`float32_params`): its logits are a float32 product. Its extra
+output `<name>@stats` carries the routing counts the trainer publishes at
+its fence (`moe.slots`, `moe.slots_here`, `moe.load_max_over_mean`); there
+is no count of dropped slots, because the row buffer is all the slots and
+nothing on this path can drop one.
+
+Without `top_k` it is the older Switch-style layer that
+tests/test_pipeline_moe.py drives: top-1, a fixed capacity with tokens
+dropped over it, a dense dispatch tensor, and its load-balancing loss as
+the extra output `<name>@aux` that the DSL wires into a sum_cost. Kept for
+those tests only; nothing new should route through it.
+
+Expert weights carry an "expert" leading dim that parallel/sharding can
+place on the mesh model axis for EP.
 """
 
 from __future__ import annotations
@@ -25,8 +42,44 @@ class MoELayer(Layer):
     expert_act. size = output dim (== input dim). Params: router w0
     [D, E]; experts w_in [E, D, H], w_out [E, H, D]."""
 
+    # the dropless path's router (the capacity path's is `w0`)
+    float32_params = ("router",)
+
+    @property
+    def dropless(self) -> bool:
+        return "top_k" in self.conf.attrs
+
+    def _held(self):
+        a = self.conf.attrs
+        first, count = a.get("held") or (0, a["num_experts"])
+        assert 0 <= first and first + count <= a["num_experts"], a["held"]
+        return int(first), int(count)
+
+    def _build_dropless(self, s):
+        """Router [D, E] over all the experts; w_gate, w_up [Eh, D, H] and
+        w_down [Eh, H, D] of the Eh held."""
+        d = s.size
+        a = self.conf.attrs
+        _, eh = self._held()
+        H = a["hidden"]
+        slots = {"router": (d, a["num_experts"]), "w_gate": (eh, d, H),
+                 "w_up": (eh, d, H), "w_down": (eh, H, d)}
+        pcs = {}
+        for slot, dims in slots.items():
+            pc = self.weight_conf(0, dims)
+            pc.name = f"_{self.name}.{slot}"
+            if slot != "router":
+                pc.expert_sharded = True
+                if pc.initial_std is None:     # one expert's fan-in
+                    pc.initial_std = 1.0 / (dims[1] ** 0.5)
+            pcs[slot] = pc
+        self._spec = s
+        return s, pcs
+
     def build(self, in_specs):
         (s,) = in_specs
+        if self.dropless:
+            return self._build_dropless(s)
         d = s.size
         a = self.conf.attrs
         E = a["num_experts"]
@@ -52,10 +105,42 @@ class MoELayer(Layer):
         return s, pcs
 
     def extra_output_specs(self):
+        if self.dropless:
+            return {f"{self.name}@stats": Spec(dim=(3,))}
         return {f"{self.name}@aux": Spec(dim=(1,))}
+
+    @property
+    def stats_output(self):
+        """The extra output that carries the routing counts (`Network`
+        lists it in `stat_outputs`; the trainer's fence publishes it)."""
+        return f"{self.name}@stats" if self.dropless else None
+
+    def publish_stats(self, values, registry) -> None:
+        slots, here, load = (float(v) for v in values)
+        registry.counter("moe.slots").inc(slots, layer=self.name)
+        registry.counter("moe.slots_here").inc(here, layer=self.name)
+        registry.gauge("moe.load_max_over_mean").set(load, layer=self.name)
+
+    def _forward_dropless(self, params, x):
+        a = self.conf.attrs
+        v = x.value
+        flat = v.reshape(-1, v.shape[-1])
+        y, stats = moe_ops.dropless_moe(
+            flat, params["router"], params["w_gate"], params["w_up"],
+            params["w_down"], top_k=a["top_k"], held_first=self._held()[0],
+            norm_topk=a.get("norm_topk", True),
+            activation=activations.get(a.get("expert_act", "silu")),
+            token_mask=x.mask(jnp.float32).reshape(-1) if x.is_seq else None,
+        )
+        self._extra_outs = {
+            f"{self.name}@stats": Arg(value=stats.reshape(1, 3))
+        }
+        return Arg(value=y.reshape(v.shape), seq_lens=x.seq_lens)
 
     def forward(self, params, inputs, ctx):
         (x,) = inputs
+        if self.dropless:
+            return self._forward_dropless(params, x)
         a = self.conf.attrs
         act = activations.get(a.get("expert_act", "relu"))
         v = x.value

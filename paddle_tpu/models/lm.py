@@ -1,4 +1,15 @@
-"""Decoder-only Transformer LM (ISSUE 19 tentpole).
+"""Decoder-only Transformer LM (ISSUE 19 tentpole): the PAGED SERVER'S
+model, and the older of the repo's two decoders.
+
+Beside it since PR 28 stands `models/mellum.py`, a present-day block (RMS
+norms, grouped-query attention with rotary positions and a sliding window,
+top-k routed experts, a chunked head) that TRAINS at a public model's
+widths and has no cache path yet. This one is multi-head attention plus one
+relu layer, with no normalisation and no positions: it stays because
+`decoding/kv_cache.py`, `serving/lm_engine.py` and their tests compile
+their prefill and decode programs against its flat parameters. Porting the
+server to the new block (fewer KV heads than query heads in the pool, two
+kinds of layer in one allocator) is ROADMAP Reach 2 and 5, not done here.
 
 The LM north star ROADMAP item 1 asks for, built from the layer
 inventory that already exists: `embedding` -> N causal

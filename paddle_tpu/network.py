@@ -85,6 +85,14 @@ class Network:
                     self._extra_producer[xname] = lc.name
             order.append(lc.name)
         self.order = order
+        # extra outputs that carry a layer's counters (`<name>@stats`),
+        # each with the layer that publishes it: the step keeps them, and
+        # the trainer hands them to their layers at its fence
+        self.stat_outputs = {
+            layer.stats_output: layer for layer in self.layers.values()
+            if getattr(layer, "stats_output", None)
+        }
+        self._groups = self._recompute_groups(conf.recompute)
         self.output_names = list(conf.output_layer_names) or (
             [order[-1]] if order else []
         )
@@ -124,6 +132,29 @@ class Network:
         self.input_names = list(conf.input_layer_names) or [
             lc.name for lc in conf.layers if lc.type == "data"
         ]
+
+    def _recompute_groups(self, groups) -> dict:
+        """first layer's name -> the group's names, each group checked: its
+        layers exist, follow one another in the graph's order, and keep no
+        state (a recomputed forward must be a pure function)."""
+        out = {}
+        for names in groups:
+            names = list(names)
+            at = self.order.index(names[0]) if names[0] in self.order else -1
+            if at < 0 or self.order[at: at + len(names)] != names:
+                raise ValueError(
+                    f"recompute group {names} is not a run of consecutive "
+                    f"layers of the graph"
+                )
+            stateful = [n for n in names if n in self._stateful]
+            if stateful or any(self.conf.layer(n).type == "data"
+                               for n in names):
+                raise ValueError(
+                    f"recompute group {names} holds data or stateful "
+                    f"layers {stateful}"
+                )
+            out[names[0]] = names
+        return out
 
     # ---- parameters & state ----
     def init_params(self, key: jax.Array, dtype=jnp.float32) -> dict:
@@ -167,7 +198,8 @@ class Network:
         # autodiff region, so grads flow back to the float32 masters
         # (classic master-weight AMP).
         amp = _flags.get_flag("matmul_precision") in ("bfloat16", "bf16")
-        ctx = Ctx(train=train, rng=rng, state=state)
+        ctx = Ctx(train=train, rng=rng, state=state,
+                  compute_dtype=jnp.bfloat16 if amp else None)
         outs: dict[str, Arg] = {}
         if outputs is not None:
             # run only the ancestor closure of the requested outputs
@@ -200,57 +232,102 @@ class Network:
                         f"missing from feed (fed: {sorted(feed)})"
                     )
                 continue
-            inputs = [outs[n] for n in lc.input_names()]
-            layer_params = self._layer_param_view(name, params)
-            layer = self.layers[name]
-            if amp:
-                # per consuming EDGE: cost layers see float32 (targets
-                # straight from the feed keep full precision even if the
-                # same data layer also feeds compute layers), everything
-                # else computes in bfloat16
-                to = (
-                    jnp.float32
-                    if getattr(layer, "is_cost", False)
-                    else jnp.bfloat16
-                )
-                inputs = [_cast_arg(a, to) for a in inputs]
-                layer_params = {
-                    k: (
-                        v.astype(to)
-                        if v.dtype in (jnp.float32, jnp.bfloat16)
-                        else v
-                    )
-                    for k, v in layer_params.items()
-                }
-            try:
-                with jax.named_scope(f"{lc.type}:{name}"):
-                    outs[name] = layer.forward(layer_params, inputs, ctx)
-            except Exception as e:
-                # the layer-stack-on-crash context of the reference's
-                # CustomStackTrace (utils/CustomStackTrace.h:51, pushed
-                # per layer in NeuralNetwork.cpp:249-251)
-                e.add_note(
-                    f"  while running layer {name!r} "
-                    f"(type={lc.type!r}, inputs={lc.input_names()})"
-                )
-                raise
-            spec = lc.attrs.get("out_sharding")
-            if spec is not None:
-                # Per-layer placement hint — the GSPMD replacement for the
-                # reference's ParallelNeuralNetwork per-layer `device` attr
-                # (gserver/gradientmachines/ParallelNeuralNetwork.h:34).
-                from jax.sharding import PartitionSpec
-                from paddle_tpu.core.mesh import get_mesh
-                from paddle_tpu.parallel.sharding import constrain
-
-                outs[name] = constrain(
-                    outs[name], get_mesh(), PartitionSpec(*spec)
-                )
-            extra = getattr(layer, "_extra_outs", None)
-            if extra:
-                outs.update(extra)
+            if train and outputs is None and name in self._groups:
+                self._run_group(self._groups[name], outs, params, ctx, amp)
+            elif name not in outs:
+                self._run_layer(name, outs, params, ctx, amp)
         new_state = {**ctx.state, **ctx.updated_state}
         return outs, new_state
+
+    def _run_layer(self, name, outs, params, ctx, amp) -> None:
+        """One layer: its inputs from `outs`, its output (and any extra
+        outputs) into `outs`."""
+        lc = self.conf.layer(name)
+        layer = self.layers[name]
+        inputs = [outs[n] for n in lc.input_names()]
+        layer_params = self._layer_param_view(name, params)
+        if amp:
+            # per consuming EDGE: cost layers see float32 (targets
+            # straight from the feed keep full precision even if the
+            # same data layer also feeds compute layers), everything
+            # else computes in bfloat16, but for the parameters a layer
+            # names in `float32_params` (a router's: its logits decide a
+            # discrete choice), which it gets as the float32 masters
+            to = (
+                jnp.float32
+                if getattr(layer, "is_cost", False)
+                else jnp.bfloat16
+            )
+            inputs = [_cast_arg(a, to) for a in inputs]
+            keep = getattr(layer, "float32_params", ())
+            layer_params = {
+                k: (
+                    v.astype(to)
+                    if v.dtype in (jnp.float32, jnp.bfloat16)
+                    and k not in keep
+                    else v
+                )
+                for k, v in layer_params.items()
+            }
+        try:
+            with jax.named_scope(f"{lc.type}:{name}"):
+                outs[name] = layer.forward(layer_params, inputs, ctx)
+        except Exception as e:
+            # the layer-stack-on-crash context of the reference's
+            # CustomStackTrace (utils/CustomStackTrace.h:51, pushed
+            # per layer in NeuralNetwork.cpp:249-251)
+            e.add_note(
+                f"  while running layer {name!r} "
+                f"(type={lc.type!r}, inputs={lc.input_names()})"
+            )
+            raise
+        spec = lc.attrs.get("out_sharding")
+        if spec is not None:
+            # Per-layer placement hint — the GSPMD replacement for the
+            # reference's ParallelNeuralNetwork per-layer `device` attr
+            # (gserver/gradientmachines/ParallelNeuralNetwork.h:34).
+            from jax.sharding import PartitionSpec
+            from paddle_tpu.core.mesh import get_mesh
+            from paddle_tpu.parallel.sharding import constrain
+
+            outs[name] = constrain(
+                outs[name], get_mesh(), PartitionSpec(*spec)
+            )
+        extra = getattr(layer, "_extra_outs", None)
+        if extra:
+            outs.update(extra)
+
+    def _run_group(self, names, outs, params, ctx, amp) -> None:
+        """A recompute group: its layers as one function of the group's
+        parameters and of what it reads from outside, under
+        jax.checkpoint, so that the backward pass keeps its inputs and
+        runs its forward again. The bfloat16 copies of its weights are
+        made inside and so are recomputed too."""
+        inside = set(names)
+        reads = sorted({
+            n for ln in names for n in self.conf.layer(ln).input_names()
+            if n not in inside
+        })
+        pnames = sorted({
+            g for ln in names for g in self.layer_params[ln].values()
+        })
+
+        def group(gparams, gin, rng):
+            local = dict(gin)
+            c = Ctx(train=ctx.train, rng=rng, state={},
+                    compute_dtype=ctx.compute_dtype)
+            for ln in names:
+                self._run_layer(ln, local, gparams, c, amp)
+            return {k: v for k, v in local.items() if k not in gin}
+
+        # in a trace the recomputed forward reads as
+        # `.../rematted_computation/<type>:<name>/...`, the first as
+        # `.../checkpoint/<type>:<name>/...`: no scope of its own, so that
+        # an operation's outermost scope stays its layer's
+        outs.update(jax.checkpoint(group)(
+            {g: params[g] for g in pnames},
+            {n: outs[n] for n in reads}, ctx.rng,
+        ))
 
     def loss_fn(self, params, feed, state=None, train=True, rng=None):
         """Scalar batch-mean cost over all cost layers — what

@@ -42,6 +42,7 @@ def get(name: str):
 register_activation("sigmoid")(jnn.sigmoid)
 register_activation("relu")(jnn.relu)
 register_activation("tanh")(jnp.tanh)
+register_activation("silu")(jnn.silu)  # the gated expert's; not of the 14
 register_activation("abs")(jnp.abs)
 register_activation("square")(jnp.square)
 register_activation("exponential")(jnp.exp)

@@ -1,0 +1,143 @@
+"""Causal grouped-query attention with an optional sliding window, never
+materialising the [T, T] scores or the KV heads repeated per query head.
+
+    q [B, T, H, D], k and v [B, T, KV, D]  ->  [B, T, H, D]
+
+Query head h attends KV head h // (H / KV); position i sees j <= i and,
+with `window`, also i - j < window. Two lowerings (`impl`):
+
+- "pallas": the TPU's splash-attention kernel (jax.experimental.pallas
+  .ops.tpu.splash_attention), as multi-query attention over the query heads
+  of one KV head, mapped over KV heads and rows. Its block-sparse mask
+  skips, forward and backward, every key block wholly outside the window
+  or the causal triangle.
+- "blocked": portable. A Python loop over query blocks, each against the
+  one key span its mask can reach (so a window layer does a window's work,
+  not the square's), each block rematerialised in the backward pass.
+
+None picks "pallas" on a TPU when the shapes fit its tiles, else "blocked".
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+
+from paddle_tpu import ops as _ops
+
+NEG_INF = -1e30
+LANES = 128
+# the kernel's tiles (query block, key block) and the portable loop's
+KERNEL_BLOCK_Q = KERNEL_BLOCK_KV = 1024
+BLOCKED_BLOCK_Q = 512
+
+
+def pallas_fits(t: int, d: int) -> bool:
+    """The kernel's tiles: the sequence in blocks of a lane multiple, the
+    head a lane multiple."""
+    return t % LANES == 0 and d % LANES == 0
+
+
+def _block(t: int, cap: int) -> int:
+    """The largest lane multiple <= cap that divides t."""
+    b = min(cap, t) // LANES * LANES
+    while t % b:
+        b -= LANES
+    return b
+
+
+@functools.lru_cache(maxsize=None)
+def _splash_kernel(t, group, window, block_q, block_kv, interpret):
+    from jax.experimental.pallas.ops.tpu import splash_attention as sa
+
+    if window is None or window >= t:
+        one = sa.CausalMask((t, t))
+    else:
+        one = sa.LocalMask((t, t), (window - 1, 0), 0)
+    bq, bkv = _block(t, block_q), _block(t, block_kv)
+    sizes = sa.BlockSizes(
+        block_q=bq, block_kv=bkv, block_kv_compute=bkv,
+        block_q_dkv=bq, block_kv_dkv=bkv, block_kv_dkv_compute=bkv,
+        block_q_dq=bq, block_kv_dq=bkv,
+    )
+    return sa.make_splash_mqa_single_device(
+        sa.MultiHeadMask([one] * group), block_sizes=sizes,
+        interpret=interpret,
+    )
+
+
+def _pallas(q, k, v, window, block_q, block_kv, interpret):
+    b, t, h, d = q.shape
+    kv = k.shape[2]
+    g = h // kv
+    # the mask's block tables are built with numpy when the kernel is
+    # made: outside any trace, so that they are constants of the program
+    with jax.ensure_compile_time_eval():
+        kernel = _splash_kernel(t, g, window, block_q, block_kv,
+                                bool(interpret))
+    # the kernel does not scale: fold 1/sqrt(D) into q
+    qg = (q * (1.0 / math.sqrt(d))).astype(q.dtype)
+    qg = qg.transpose(0, 2, 1, 3).reshape(b, kv, g, t, d)
+    kt, vt = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+    o = jax.vmap(jax.vmap(kernel))(qg, kt, vt)        # [B, KV, G, T, D]
+    return o.reshape(b, h, t, d).transpose(0, 2, 1, 3)
+
+
+def _blocked(q, k, v, window, block_q):
+    b, t, h, d = q.shape
+    kv = k.shape[2]
+    g = h // kv
+    scale = 1.0 / math.sqrt(d)
+    qg = q.reshape(b, t, kv, g, d)
+
+    @functools.partial(jax.checkpoint, static_argnums=(3, 4))
+    def one(qs, ks, vs, q0, k0):
+        s = jnp.einsum("bqkgd,bskd->bkgqs", qs, ks,
+                       preferred_element_type=jnp.float32) * scale
+        qi = q0 + jnp.arange(qs.shape[1])[:, None]
+        kj = k0 + jnp.arange(ks.shape[1])[None, :]
+        m = kj <= qi
+        if window is not None:
+            m = m & (qi - kj < window)
+        s = jnp.where(m[None, None, None], s, NEG_INF)
+        p = jax.nn.softmax(s, axis=-1)
+        return jnp.einsum("bkgqs,bskd->bqkgd", p.astype(vs.dtype), vs,
+                          preferred_element_type=jnp.float32
+                          ).astype(qs.dtype)
+
+    outs = []
+    for q0 in range(0, t, block_q):
+        q1 = min(q0 + block_q, t)
+        k0 = 0 if window is None else max(0, q0 - (window - 1))
+        outs.append(one(qg[:, q0:q1], k[:, k0:q1], v[:, k0:q1], q0, k0))
+    return jnp.concatenate(outs, axis=1).reshape(b, t, h, d)
+
+
+def gqa_attention(q, k, v, *, window=None, impl=None, block_q=None,
+                  block_kv=None, interpret=None):
+    """See the module's docstring. `block_q`/`block_kv`: tile sizes
+    (defaults 1024/1024 for the kernel, 512 for the portable loop)."""
+    b, t, h, d = q.shape
+    if h % k.shape[2]:
+        raise ValueError(f"{h} query heads do not divide over "
+                         f"{k.shape[2]} KV heads")
+    if window is not None and window >= t:
+        window = None
+    if impl is None:
+        on_tpu = jax.default_backend() == "tpu"
+        impl = "pallas" if on_tpu and pallas_fits(t, d) else "blocked"
+    if impl == "pallas":
+        if not pallas_fits(t, d):
+            raise ValueError(
+                f"the attention kernel needs T and the head size in "
+                f"multiples of {LANES}; got T={t}, D={d}")
+        return _pallas(q, k, v, window, block_q or KERNEL_BLOCK_Q,
+                       block_kv or KERNEL_BLOCK_KV,
+                       _ops.pallas_interpret(interpret))
+    if impl == "blocked":
+        return _blocked(q, k, v, window, block_q or BLOCKED_BLOCK_Q)
+    raise ValueError(f"unknown attention impl {impl!r}")
+

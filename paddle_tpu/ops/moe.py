@@ -8,12 +8,26 @@ when the expert axis of the expert weights is sharded over the mesh.
 Tokens route within fixed-size GROUPS (GShard's [G, S, ...] layout) so
 dispatch tensors stay O(N * group_size) instead of O(N^2). Aux
 load-balancing loss per GShard/Switch eq. 4.
+
+Beside it, the present-day path (`dropless_moe`): softmax-then-top-k
+routing over ALL the experts, for a layer that holds a contiguous share of
+them (`held_first`, as many as its weights have), with no capacity and no
+dropped token. The (token, expert) slots are sorted by expert, held experts
+first; one grouped matrix product a projection runs over the held experts'
+row groups (`grouped_matmul`: the TPU's megablox kernel, `lax.ragged_dot`
+elsewhere); a weighted gather brings the rows back. No tensor grows with
+experts x capacity: the row buffer is tokens x top-k, the worst case, and
+the kernel works only through the real group sizes.
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+
+from paddle_tpu import ops as _ops
 
 
 def top1_routing(
@@ -133,3 +147,196 @@ def moe_ffn(
     real_g = jnp.sum(mg, axis=1)
     aux = jnp.sum(aux * real_g) / jnp.maximum(jnp.sum(real_g), 1.0)
     return y.reshape(G * S, -1)[:N], aux
+
+
+# ---- dropless top-k routing over a held share of the experts ----
+
+def route_topk(x, router_w, top_k: int, norm_topk: bool = True):
+    """x [N, D], router_w [D, E] -> (weights [N, k] float32, experts
+    [N, k] int32). Logits, softmax and top-k are float32 whatever the
+    operands' dtypes: the product is taken of their float32 values at the
+    highest precision (a TPU's default rounds a float32 product's operands
+    to bfloat16), since a logit's last bits decide which experts a token
+    gets."""
+    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    prob = jax.nn.softmax(logits, axis=-1)
+    top, idx = jax.lax.top_k(prob, top_k)
+    if norm_topk:
+        top = top / jnp.sum(top, axis=-1, keepdims=True)
+    return top, idx.astype(jnp.int32)
+
+
+def _tile(n: int, cap: int) -> int:
+    """A tile of a lane multiple for a dimension of n: the largest <= cap
+    that divides n, else cap (the kernel masks the remainder)."""
+    if n <= cap:
+        return n
+    for t in range(cap // 128 * 128, 127, -128):
+        if n % t == 0:
+            return t
+    return cap // 128 * 128
+
+
+def _megablox():
+    """The kernels' module (the package's own `gmm` name is its wrapped
+    function, which hides the module of the same name)."""
+    import importlib
+
+    return importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+
+
+def _gmm_kernel(lhs, rhs, group_sizes, transpose_rhs, interpret):
+    backend = _megablox()
+
+    m, k = lhs.shape
+    n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
+    tiling = (_tile(m, 512), _tile(k, 1024), _tile(n, 1024))
+    return backend.gmm(lhs, rhs, group_sizes, lhs.dtype, tiling,
+                       transpose_rhs=transpose_rhs, interpret=interpret)
+
+
+def _tgmm_kernel(lhs, grad, group_sizes, interpret):
+    """[G, k, n]: lhs rows^T @ grad rows, group by group."""
+    backend = _megablox()
+
+    m, k = lhs.shape
+    n = grad.shape[1]
+    tiling = (_tile(m, 512), _tile(k, 1024), _tile(n, 1024))
+    return backend.tgmm(lhs.swapaxes(0, 1), grad, group_sizes, lhs.dtype,
+                        tiling, interpret=interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _gmm_pallas(lhs, rhs, group_sizes, interpret):
+    with jax.named_scope("moe.gmm"):
+        return _gmm_kernel(lhs, rhs, group_sizes, False, interpret)
+
+
+def _gmm_pallas_fwd(lhs, rhs, group_sizes, interpret):
+    return (_gmm_pallas(lhs, rhs, group_sizes, interpret),
+            (lhs, rhs, group_sizes))
+
+
+def _gmm_pallas_bwd(interpret, res, g):
+    lhs, rhs, group_sizes = res
+    g = g.astype(lhs.dtype)
+    with jax.named_scope("moe.gmm"):
+        dlhs = _gmm_kernel(g, rhs, group_sizes, True, interpret)
+        drhs = _tgmm_kernel(lhs, g, group_sizes, interpret)
+    return dlhs, drhs.astype(rhs.dtype), None
+
+
+_gmm_pallas.defvjp(_gmm_pallas_fwd, _gmm_pallas_bwd)
+
+
+def grouped_matmul(lhs, rhs, group_sizes, impl=None, interpret=None):
+    """out[r] = lhs[r] @ rhs[g] for the rows r of group g, groups laid end
+    to end from row 0 in the order of `group_sizes` [G] (int32). lhs
+    [m, k], rhs [G, k, n]. ROWS PAST THE LAST GROUP ARE NOT DEFINED, in
+    the result and in the gradient of `lhs` alike (the kernel never
+    visits them and leaves them as they lay in memory; no pass is spent
+    on zeroing them): the caller keeps them out of what it sums
+    (`dropless_moe` does, once on the way out and once on the way back).
+    `impl`: "pallas" (megablox, with its transposed kernels for the
+    backward pass), "ragged" (`lax.ragged_dot`), None = pallas on a TPU."""
+    if impl is None:
+        impl = "pallas" if jax.default_backend() == "tpu" else "ragged"
+    if impl == "pallas":
+        return _gmm_pallas(lhs, rhs, group_sizes,
+                           _ops.pallas_interpret(interpret))
+    if impl == "ragged":
+        with jax.named_scope("moe.gmm"):
+            return jax.lax.ragged_dot(lhs, rhs, group_sizes)
+    raise ValueError(f"unknown grouped matmul impl {impl!r}")
+
+
+@jax.custom_vjp
+def _take_tokens(x, order, inverse, here):
+    """x [N, D] -> the row of each sorted slot's token [N * k, D]. `here`:
+    how many of the sorted slots (the first) are on experts held."""
+    return x[order // (order.shape[0] // x.shape[0])]
+
+
+def _take_tokens_fwd(x, order, inverse, here):
+    return _take_tokens(x, order, inverse, here), (inverse, here, x.shape[0])
+
+
+def _take_tokens_bwd(res, g):
+    inverse, here, n = res
+    # a gather and a sum, not a scatter-add; the rows of slots no held
+    # expert owns were never defined (grouped_matmul) and are left out
+    g = jnp.where((inverse < here)[:, None], g[inverse], 0)
+    return g.reshape(n, -1, g.shape[-1]).sum(axis=1), None, None, None
+
+
+_take_tokens.defvjp(_take_tokens_fwd, _take_tokens_bwd)
+
+
+@jax.custom_vjp
+def _unsort(y, order, inverse, here):
+    """y [N * k, D] in sorted order -> slot order, the undefined rows of
+    slots no held expert owns as zeros."""
+    return jnp.where((inverse < here)[:, None], y[inverse], 0)
+
+
+def _unsort_fwd(y, order, inverse, here):
+    return _unsort(y, order, inverse, here), (order, here)
+
+
+def _unsort_bwd(res, g):
+    order, here = res
+    # rows past `here` get what their slots were handed: zeros times the
+    # weights' cotangent, defined, and never read by a kernel
+    return g[order], None, None, None
+
+
+_unsort.defvjp(_unsort_fwd, _unsort_bwd)
+
+
+def dropless_moe(x, router_w, w_gate, w_up, w_down, *, top_k: int,
+                 held_first: int = 0, norm_topk: bool = True,
+                 activation=jax.nn.silu, token_mask=None, impl=None):
+    """The held experts' part of a top-k mixture's result, no token dropped.
+
+    x [N, D]; router_w [D, E] over ALL E experts; w_gate, w_up [Eh, D, H]
+    and w_down [Eh, H, D] of the Eh experts held here, experts
+    `held_first` .. `held_first + Eh`; an expert is `(activation(x w_gate)
+    * (x w_up)) w_down`. `token_mask` [N] (1 = real) keeps padding out of
+    every expert. -> (y [N, D], stats [3] float32: slots routed, slots on
+    experts held here, the fullest held expert's load over the mean). The
+    row buffer is all N * k slots, so there is nothing to drop and no
+    count of it."""
+    n, d = x.shape
+    e, eh = router_w.shape[1], w_up.shape[0]
+    with jax.named_scope("moe.route"):
+        weight, expert = route_topk(x, router_w, top_k, norm_topk)
+        # held experts first: key 0 .. Eh-1; absent ones after; padding last
+        key = jnp.mod(expert - held_first, e).reshape(-1)
+        if token_mask is not None:
+            real = jnp.repeat(token_mask > 0, top_k)
+            key = jnp.where(real, key, e)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        inverse = jnp.zeros_like(order).at[order].set(
+            jnp.arange(order.shape[0], dtype=jnp.int32))
+        counts = jnp.bincount(key, length=e + 1).astype(jnp.int32)
+        group_sizes = counts[:eh]
+        here = jnp.sum(group_sizes)
+    with jax.named_scope("moe.dispatch"):
+        rows = _take_tokens(x, order, inverse, here)       # [N * k, D]
+    with jax.named_scope("moe.experts"):
+        up = grouped_matmul(rows, w_up, group_sizes, impl)
+        hidden = activation(
+            grouped_matmul(rows, w_gate, group_sizes, impl)) * up
+        out = grouped_matmul(hidden.astype(x.dtype), w_down, group_sizes,
+                             impl)
+    with jax.named_scope("moe.combine"):
+        slots = _unsort(out, order, inverse, here).reshape(n, top_k, d)
+        y = jnp.sum(slots.astype(jnp.float32) * weight[..., None], axis=1)
+    real_slots = jnp.sum(counts[:e])
+    stats = jnp.stack([
+        real_slots, here,
+        jnp.max(group_sizes) * eh / jnp.maximum(here, 1),
+    ]).astype(jnp.float32)
+    return y.astype(x.dtype), stats

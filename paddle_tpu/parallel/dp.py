@@ -101,7 +101,7 @@ class TrainStep:
         # fusion/rematerialization.
         keep = set(keep_outputs or []) | set(net.output_names) | set(
             net.cost_names
-        )
+        ) | set(getattr(net, "stat_outputs", ()))
         # jit-cache-miss tracker (ISSUE 13): note() runs at TRACE
         # time only (it is a plain Python call in the traced body),
         # so the cached dispatch path pays nothing. The trainer arms

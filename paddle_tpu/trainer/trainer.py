@@ -271,12 +271,16 @@ class SGD:
                 result = float(health[0]), bool(health[1]), outs
             else:
                 result = float(loss), True, outs
-        self._after_step(timeline, 1, dispatched, fetched)
+        self._after_step(timeline, 1, dispatched, fetched, outs)
         return result
 
-    def _after_step(self, timeline, n, dispatched, fetched) -> None:
+    def _after_step(self, timeline, n, dispatched, fetched,
+                    outs=None) -> None:
         """Hand a dispatch's spans to the timeline, and fence where it
-        says so."""
+        says so. The layers' counters (`Network.stat_outputs`: a few floats
+        a layer, extra outputs of the step) ride that fence: they are
+        read only there, once the device has been waited for anyway, so
+        no step gains a fetch."""
         if timeline is None:
             return
         timeline.add(dispatched)
@@ -284,8 +288,21 @@ class SGD:
         if timeline.fence_now(self.global_step):
             with _tracing.span("train.fence") as fenced:
                 jax.block_until_ready(self.params)
+                self._publish_layer_stats(outs)
             timeline.add(fenced)
         timeline.step_done(n)
+
+    def _publish_layer_stats(self, outs) -> None:
+        if not outs or not self.net.stat_outputs:
+            return
+        reg = _obs.get_registry()
+        for name, layer in self.net.stat_outputs.items():
+            if name not in outs:
+                continue
+            values = np.asarray(outs[name].value)
+            # a chunk of steps stacks them: the last step's
+            values = values.reshape(-1, values.shape[-1])[-1]
+            layer.publish_stats(values, reg)
 
     def run_steps(self, feeds, lr_scale: float = 1.0,
                   timeline=None) -> tuple:
@@ -324,7 +341,7 @@ class SGD:
         else:
             costs = [float(h) for h in health]
             finites = [True] * n
-        self._after_step(timeline, n, dispatched, fetched)
+        self._after_step(timeline, n, dispatched, fetched, outs)
         return costs, finites, outs
 
     def train(

@@ -1,37 +1,78 @@
 """The benchmark's cheap tests in tier-1: `benchmarks/tests/test_harness.py`
 (files and names, the last line's keys, the no-TPU refusal, `trace_reduce`,
-the readers, a cell added as new files) and `test_program_spans.py` (the
-readers of the program's spans and scopes). They run here as they stand
-there, but for one: `test_harness.py` holds the cell's per-layer metrics to
-the three of PR 24 by an exact comparison, and no PR but a `benchmark` PR may
-edit that file, so the same test is given here over the metrics it names."""
+the readers, a cell added as new files), `test_program_spans.py` (the
+readers of the program's spans and scopes) and `test_mellum_cell.py` (the
+cell PR 28 added, at a tiny size). They run here as they stand there, but
+for the two that `REPLACED` names with the reason: each fails as it stands
+since PR 28 appended the entries that ISSUE 28 named, no PR but a `benchmark`
+PR may edit those files, and so each is taken out of this module BY NAME (a
+test renamed there fails this module's collection, loudly) and its sense is
+held here by a test of another name. The repair of the two is the first item
+of the next `benchmark` PR (PERF.md section 7). (The override that PR 25
+needed of `test_readers_read_the_run_and_return_nothing_where_nothing_is` is
+gone: PR 27 repaired that test, and it runs here as it stands.)"""
 
-import pytest
+import os
 
 from benchmarks import harness
 from benchmarks.tests.test_harness import *  # noqa: F401,F403
 from benchmarks.tests.test_program_spans import *  # noqa: F401,F403
 from benchmarks.tests.test_program_spans import NEW
+from benchmarks.tests.test_mellum_cell import *  # noqa: F401,F403,E402
+
+REPLACED = {
+    "test_a_token_counted_training_cell_is_new_files_and_appended_entries":
+        "its toy token cell brings layer_metrics/mfu.tokens.json as a NEW "
+        "file, and the tree has that file since PR 28 (ISSUE 28 named it)",
+    "test_every_new_metric_resolves_to_a_reader_and_a_data_file":
+        "holds PR 25's metrics to be the LAST of per_layer, which no "
+        "appended metric leaves true",
+}
+for _name in REPLACED:
+    del globals()[_name]            # KeyError: renamed there; look again
 
 
-def test_readers_read_the_run_and_return_nothing_where_nothing_is(
-        monkeypatch, tmp_path):
-    from benchmarks import program_spans
+def test_a_toy_token_cell_is_new_files_beside_the_one_the_tree_has(
+        tmp_path, monkeypatch):
+    from benchmarks.tests import test_harness as H
 
-    monkeypatch.setattr(program_spans, "TRACE_DIR", str(tmp_path))
-    cell = harness.Cell("resnet50.train_bs256")
-    run = {"flops": 3e12, "window_s": 4.0, "chips": 1,
-           "peak": {"bf16_flops": 1e12},
-           "spans": {"reader": 0.5, "feeder": 1.5, "run_step": 2.0},
-           "trace": {"busy_s": 1.5, "window_s": 4.0, "idle_share": 0.625}}
-    got = {}
-    for m in cell.metrics("per_layer"):
-        reader, data = cell.layer_metric(m["name"])
-        got[m["name"]] = reader.read(run, data)
-        bare = dict(run, trace=None, spans={}, flops=0)
-        assert reader.read(bare, data) is None, m["name"]
-    # the new readers read the trace's file, and this run has none
-    assert [got.pop(n) for n in NEW] == [None] * len(NEW)
-    assert got == {"input_wait_share.images": 50.0,
-                   "mfu.images": pytest.approx(200.0),   # 3e12/1.5 s/1e12
-                   "device_idle_share.images": 62.5}
+    taken = "layer_metrics/mfu.tokens.json"
+    assert H.TOKEN_FILES[taken] == open(
+        os.path.join(H.ROOT, "benchmarks", taken)).read()
+    monkeypatch.setattr(H, "TOKEN_FILES", {
+        k: v for k, v in H.TOKEN_FILES.items() if k != taken})
+    H.test_a_token_counted_training_cell_is_new_files_and_appended_entries(
+        tmp_path)
+
+
+PR28 = ["mfu.tokens", "device_idle_share.tokens",
+        "loop_input_wait_share.tokens", "idle_input_wait_share.tokens",
+        "idle_dispatch_share.tokens", "idle_other_share.tokens",
+        "feed_worker_share.tokens", "hbm_pass_busy_share.tokens",
+        "moe_busy_share.tokens", "attention_busy_share.tokens",
+        "moe_gmm_roofline.tokens", "window_attention_roofline.tokens"]
+
+
+def test_pr25s_and_pr28s_metrics_resolve_in_their_order():
+    import json
+
+    bench = harness.load_benchmark()
+    names = [m["name"] for m in bench["per_layer"]]
+    entries = {m["name"]: m for m in bench["per_layer"]}
+    assert [n for n in names if n in NEW] == NEW
+    assert names[-len(PR28):] == PR28
+    for name, cell in ([(n, "resnet50.train_bs256") for n in NEW]
+                       + [(n, "mellum2_12b_ep4.train_seq8192")
+                          for n in PR28]):
+        m = entries[name]
+        assert m["workloads"] == [cell]
+        assert (m["unit"], m["moves"]) == ("%", "train_units_per_s")
+        reader, data = harness.Cell(cell).layer_metric(name)
+        assert callable(reader.read) and json.dumps(data)
+        # nothing to read: nothing read, and no raise
+        assert reader.read({"trace": None, "spans": {}, "flops": 0,
+                            "window_s": 0.0}, data) is None
+    assert {entries[n]["layer"] for n in NEW} == {
+        "entry points", "device", "step program and model graph"}
+    assert {entries[n]["layer"] for n in PR28} == {
+        "entry points", "device", "step program and model graph", "kernels"}

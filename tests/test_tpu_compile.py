@@ -210,3 +210,53 @@ def test_fused_lstm_that_falls_back_says_so(one_chip):
                  argnums=(0, 1)),
         *args)
     assert _fallbacks() > before
+
+
+# ---- the decoder block's kernels at the widths of the cell
+# `mellum2_12b_ep4.train_seq8192`: 2 rows of 8,192 positions, 32 query
+# heads on 4 KV heads of 128; 131,072 slots of 2,304 through 16 held
+# experts of 896
+
+@pytest.mark.parametrize("window", [1024, None])
+def test_window_attention_forward_and_gradient(one_chip, monkeypatch, window):
+    from paddle_tpu import ops
+    from paddle_tpu.ops.gqa_attention import gqa_attention
+
+    monkeypatch.setattr(ops, "pallas_interpret",
+                        lambda requested=None: False)
+    q = jax.ShapeDtypeStruct((2, 8192, 32, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((2, 8192, 4, 128), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def fwd(q, k, v):
+        return gqa_attention(q, k, v, window=window, impl="pallas")
+
+    def loss(q, k, v):
+        return jnp.sum(fwd(q, k, v).astype(jnp.float32))
+
+    assert _has_kernel(_compile(fwd, q, kv, kv))
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    assert text.count("tpu_custom_call") >= 3      # forward, dq, dk and dv
+
+
+def test_grouped_matmul_forward_and_gradient(one_chip, monkeypatch):
+    from paddle_tpu import ops
+    from paddle_tpu.ops.moe import grouped_matmul
+
+    monkeypatch.setattr(ops, "pallas_interpret",
+                        lambda requested=None: False)
+    rows = jax.ShapeDtypeStruct((131072, 2304), jnp.bfloat16,
+                                sharding=one_chip)
+    w = jax.ShapeDtypeStruct((16, 2304, 896), jnp.bfloat16, sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((16,), jnp.int32, sharding=one_chip)
+
+    def fwd(rows, w, sizes):
+        return grouped_matmul(rows, w, sizes, impl="pallas")
+
+    def loss(rows, w, sizes):
+        return jnp.sum(fwd(rows, w, sizes).astype(jnp.float32))
+
+    assert _has_kernel(_compile(fwd, rows, w, sizes))
+    text = _compile(jax.grad(loss, argnums=(0, 1)), rows, w, sizes)
+    assert text.count("tpu_custom_call") >= 2      # the rows', the weights'
