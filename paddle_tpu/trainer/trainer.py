@@ -376,7 +376,13 @@ class SGD:
         thread at a time, in order, once a batch; it may not assume
         that thread to be the main one, nor read what a BeginIteration
         handler of its own batch writes, and should keep to numpy (the
-        transfer to the device is the step's). Its exception is raised
+        transfer to the device is the step's). A fed batch is this
+        call's for as long as anything here refers to it (the queue,
+        the step and its transfer, a chunk of `steps_per_dispatch`
+        feeds, the evaluators, a handler that keeps it) and is let go
+        after its EndIteration: a feeder may hand out memory again
+        once nothing refers to it, as `data.feeder.DataFeeder` does,
+        and never before. A reader's or feeder's exception is raised
         here, by the wait for the batch it belonged to, after every
         batch before it has been trained. Handlers, evaluators, the
         watchdog, checkpoints and every JAX call stay on this thread.

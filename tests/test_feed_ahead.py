@@ -18,9 +18,12 @@ from paddle_tpu import dsl
 from paddle_tpu.core.arg import Arg
 from paddle_tpu.core.config import OptimizationConf
 from paddle_tpu.data import reader as rd
+from paddle_tpu.data.feeder import DataFeeder, dense_vector, integer_value
+from paddle_tpu.obs import metrics as om
 from paddle_tpu.trainer import trainer as trainer_mod
 from paddle_tpu.trainer import watchdog as wdg
-from paddle_tpu.trainer.events import BeginIteration, EndIteration
+from paddle_tpu.trainer.events import (
+    BeginIteration, EndIteration, EndPass)
 from paddle_tpu.trainer.trainer import FEED_AHEAD, SGD
 
 WAIT_S = 60          # an Event that is not set by then fails the test
@@ -109,6 +112,58 @@ def test_losses_and_parameters_are_those_of_feeding_inline(spd):
         np.testing.assert_array_equal(np.asarray(t.params[name]),
                                       np.asarray(value))
     assert t.global_step == inline.global_step == 14
+    assert not _workers()
+
+
+def _fresh_memory(feeder):
+    """The feeder's batches, each copied into memory of its own."""
+    return lambda raw: jax.tree_util.tree_map(np.array, feeder(raw))
+
+
+@pytest.mark.parametrize("spd", [1, 4], ids=["plain", "chunks"])
+def test_batches_in_memory_taken_again_train_what_fresh_memory_trains(spd):
+    """`DataFeeder` stacks array rows into memory it takes again once
+    nothing refers to a batch (ISSUE 30). Over many more batches than
+    the queue, the worker, the step, the chunk loop and the runtime
+    hold at once, every loss, the evaluator's sum over the fed `x`
+    (read AFTER the batch's step, in `_after_batch`) and the final
+    parameters are those of a feeder whose every batch is a copy in
+    fresh memory: no batch was written before its last reader was
+    done with it. And memory WAS taken again, so the test held it."""
+    n = 4 * (FEED_AHEAD + 4)
+    rng = np.random.default_rng(30)
+    rows = [(rng.standard_normal(8).astype(np.float32), int(rng.integers(4)))
+            for _ in range(8 * n)]
+
+    def run(wrap):
+        feeder = wrap(DataFeeder(
+            {"x": 0, "label": 1},
+            {"x": dense_vector(8), "label": integer_value(4)}))
+        t, costs, sums = _sgd(
+            spd, evaluators=[{"type": "sum", "name": "fed", "input": "x"}],
+        ), [], []
+
+        def handler(e):
+            if isinstance(e, EndIteration):
+                costs.append(e.cost)
+            if isinstance(e, EndPass):
+                sums.append(e.evaluator_results["fed"])
+
+        t.train(reader=rd.batched(lambda: iter(rows), 8), feeder=feeder,
+                num_passes=2, event_handler=handler)
+        return t, costs, sums
+
+    reg = om.get_registry()
+    reused0 = reg.counter("feeder.buffers_reused").get()
+    t, got, fed = run(lambda feeder: feeder)
+    reused = reg.counter("feeder.buffers_reused").get() - reused0
+    fresh, want, fed_fresh = run(_fresh_memory)
+    assert len(got) == 2 * n and got == want        # bit for bit
+    assert fed == fed_fresh
+    for name, value in fresh.params.items():
+        np.testing.assert_array_equal(np.asarray(t.params[name]),
+                                      np.asarray(value))
+    assert reused >= n
     assert not _workers()
 
 
