@@ -45,7 +45,7 @@ class Sizes:
     image: tuple = (224, 224, 3)
     classes: int = 1000
     resnet_batch: int = 256
-    # phase 2/3: attention NMT (bench.py bench_nmt widths)
+    # phase 2/3: attention NMT
     vocab: int = 30000
     hidden: int = 512
     emb: int = 512
@@ -158,7 +158,7 @@ def phase(clock: CompileClock, name: str):
 
 
 def setup():
-    """Process settings, as bench.py::_setup: bf16 compute over f32
+    """Process settings: bf16 compute over f32
     master params, the rbg PRNG, the persistent compile cache."""
     import jax
 

@@ -4,9 +4,8 @@ Reference: paddle/scripts/submit_local.sh.in:3-13 — subcommands
 train / pserver / merge_model / dump_config / make_diagram / version —
 plus trainer/TrainerMain.cpp and trainer/MergeModel.cpp. TPU-native
 differences: there is no pserver process (data parallelism is one pjit
-program; `master` serves the elastic-input role instead), `bench`
-wraps the repo benchmark harness, and `serve` runs the
-continuous-batching inference server (paddle_tpu/serving).
+program; `master` serves the elastic-input role instead), and `serve`
+runs the continuous-batching inference server (paddle_tpu/serving).
 
 A config file is a Python source that defines:
     get_config() -> (ModelConf, OptimizationConf)
@@ -745,14 +744,6 @@ def cmd_make_diagram(args):
     return 0
 
 
-def cmd_bench(args):
-    import runpy
-
-    sys.argv = ["bench.py"]
-    runpy.run_path(args.script, run_name="__main__")
-    return 0
-
-
 def _cmd_launch(args):
     from paddle_tpu import launch as _launch
 
@@ -873,10 +864,6 @@ def main(argv=None):
     sp.add_argument("--config_args", default="")
     sp.add_argument("--output", default="")
     sp.set_defaults(fn=cmd_make_diagram)
-
-    sp = sub.add_parser("bench", help="run the benchmark harness")
-    sp.add_argument("--script", default="bench.py")
-    sp.set_defaults(fn=cmd_bench)
 
     sp = sub.add_parser(
         "launch",

@@ -16,8 +16,6 @@ discipline as paddle_tpu.obs, enforced by the jax_import_fence pass):
                     mutation)
 - `lock_order`      named-lock instrumentation + inversion detection
                     (the faults shard runs with PADDLE_LOCK_CHECK=1)
-- `rows`            REQUIRED_ROWS — the single source of truth for
-                    the bench-record row lists the lints enforce
 
 Driver: `python tools/framework_lint.py --all`.
 """
@@ -26,7 +24,7 @@ from __future__ import annotations
 
 _SUBMODULES = (
     "ast_lint", "hlo_audit", "hlo_text", "lock_order",
-    "recompile_guard", "rows",
+    "recompile_guard",
 )
 
 __all__ = list(_SUBMODULES)

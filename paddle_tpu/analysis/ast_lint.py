@@ -1,9 +1,8 @@
 """Framework AST lint — registered source passes over paddle_tpu/
 (ISSUE 13 tentpole, part c).
 
-Generalizes `check_bench_record.py`'s one-off `obs` mode into a pass
-registry the `tools/framework_lint.py` driver runs over the whole
-tree. Each pass encodes a rule the repo learned the hard way:
+A pass registry the `tools/framework_lint.py` driver runs over the
+whole tree. Each pass encodes a rule the repo learned the hard way:
 
 - **jax_import_fence** — the module-scope jax-import allowlist,
   inverted into explicit jax-free zones: obs/ (serving front ends and
@@ -16,15 +15,14 @@ tree. Each pass encodes a rule the repo learned the hard way:
   falls over.
 - **duplicate_dict_keys** — a duplicate key in a dict literal is
   legal Python that silently keeps the LAST value; in the flag
-  registry (core/flags.py `_DEFAULTS`) or a bench row dict it is a
-  silently-dropped setting. Any dict literal with a repeated constant
-  key fails.
+  registry (core/flags.py `_DEFAULTS`) it is a silently-dropped
+  setting. Any dict literal with a repeated constant key fails.
 - **unfenced_timing** — a function that binds a jitted callable
   (`f = jax.jit(...)` / `...lower().compile()`), calls it between
   clock reads, and never fences (block_until_ready / float / asarray
   / device_get / tolist / item) measures DISPATCH, not execution —
   the async-dispatch timing bug the dispatch-floor campaign
-  (ROADMAP 5d) kept re-finding in bench code. Trainer-style
+  (ROADMAP 5d) kept re-finding in timing code. Trainer-style
   self-fencing APIs (run_step fetches the loss) are not flagged: the
   pass tracks only locally-bound jit objects.
 - **raw_collective_outside_shard_map** — `lax.psum` / `ppermute` /
@@ -65,6 +63,13 @@ JAX_FREE_DIRS = (
     "paddle_tpu/decoding",
 )
 JAX_FREE_FILES = (
+    # the telemetry modules every front end imports: the fence names
+    # them so that deleting one fails the lint, not only a box
+    "paddle_tpu/obs/metrics.py",
+    "paddle_tpu/obs/timeline.py",
+    "paddle_tpu/obs/tracing.py",
+    "paddle_tpu/obs/flight_recorder.py",
+    "paddle_tpu/obs/aggregate.py",
     "paddle_tpu/__init__.py",
     "paddle_tpu/__main__.py",
     "paddle_tpu/launch.py",
@@ -160,7 +165,7 @@ def check_jax_import_fence(repo_dir: str) -> list:
             )
             continue
         fenced.append(full)
-    for path in fenced:
+    for path in dict.fromkeys(fenced):  # obs modules are named twice
         rel = os.path.relpath(path, repo_dir)
         tree, _src = _parse(path)
         for node in _module_scope(tree):
@@ -202,7 +207,7 @@ def check_duplicate_dict_keys(repo_dir: str) -> list:
                             f"{key!r} in dict literal — Python "
                             f"silently keeps the LAST value; the "
                             f"first registration is dead (flag "
-                            f"registry / bench-row field shadowing)"
+                            f"registry shadowing)"
                         )
                     seen.add(key)
                 except TypeError:
@@ -226,8 +231,7 @@ def _is_jit_binding(node):
 
 def check_unfenced_timing(repo_dir: str) -> list:
     violations = []
-    subpaths = ("paddle_tpu", "bench.py", "bench_multichip.py",
-                "tools")
+    subpaths = ("paddle_tpu", "tools")
     for path in iter_py_files(repo_dir, subpaths):
         if os.sep + "traces" + os.sep in path:
             continue

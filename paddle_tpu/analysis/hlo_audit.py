@@ -2,7 +2,7 @@
 
 Static checks over captured HLO modules (`tools/traces/*.hlo.txt.gz`
 — REAL compiled programs dumped by tools/profile_longctx.py /
-bench.write_decode_hlo), turning the repo's hardest-won perf
+tools/profile_lm.py), turning the repo's hardest-won perf
 invariants into machine-checked tripwires:
 
 - **donation/aliasing** — a train-update program that donates its

@@ -240,7 +240,7 @@ class BeamSearchDecoder:
         # The int() fetches BLOCK on the whole jitted while-loop, so
         # they are the device-time window; only the submit window
         # before them is host dispatch work (`last_timeline` is what
-        # bench rows must read — timing around generate() itself
+        # a caller must read — timing around generate() itself
         # attributes the entire device run to dispatch and reports a
         # nonsense host_overhead_frac of ~1.0)
         self.last_steps = int(t_end)

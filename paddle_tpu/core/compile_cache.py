@@ -1,7 +1,7 @@
 """Where the persistent XLA compilation cache lives.
 
-One rule for every entry point (chip_smoke.py, bench.py,
-bench_multichip.py, `python -m paddle_tpu train|serve`, the tests): a
+One rule for every entry point (chip_smoke.py, benchmarks/run.py,
+`python -m paddle_tpu train|serve`, the capture tools, the tests): a
 directory that stays put. A cache that moves — a temporary directory, a
 path with a pid or a time in it — never hits, and a cold run on the
 chip is mostly compilation.

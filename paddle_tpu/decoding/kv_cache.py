@@ -62,7 +62,7 @@ class PoolExhausted(RuntimeError):
 class PagedKVCache:
     """Fixed-size-page KV pool for one LM: the device-side K/V arrays
     ([L, num_pages, page_size, H, hd] each), a host-side page free
-    list, and the measured counters the decode bench row reports.
+    list, and the counters of what was read from pages and recomputed.
 
     Slot addressing: absolute position p of a sequence lives in its
     `pages[p // page_size]` at offset `p % page_size`; a gathered

@@ -36,8 +36,8 @@ from paddle_tpu.ops import sequence_ops as sops
 def _use_fused(bsz=None, t_max=None, h=None, mult=4) -> bool:
     """Fused Pallas cell policy: explicit flag only.
 
-    Round-3 interleaved A/B measurement (bench.py
-    bench_lstm_fused_vs_scan: both arms compiled+warmed, alternating
+    Round-3 interleaved A/B measurement (the pre-chip harness's
+    fused-vs-scan LSTM row: both arms compiled+warmed, alternating
     timing windows, min per arm — immune to the host-stall bias that
     produced round 2's contradictory numbers) shows XLA's
     lax.scan lowering BEATS the fused Pallas kernels on v5e at every
@@ -53,7 +53,7 @@ def _use_fused(bsz=None, t_max=None, h=None, mult=4) -> bool:
 
     The shape parameters are intentionally retained (unused) so call
     sites keep passing them — if a future XLA/Mosaic shift flips the
-    A/B (the bench row watches it), the shape-dependent policy slots
+    A/B (no cell measures it: ROADMAP Design 5), the policy slots
     back in without touching callers.
 
     Round-6 verdict (ROADMAP 5a, PERF.md "fused-RNN family retired"):
@@ -85,8 +85,8 @@ def _use_fused(bsz=None, t_max=None, h=None, mult=4) -> bool:
     return False
 
 
-# once-per-process latch: the bench A/B flips the flag per timing
-# window and must not spam a warning per engaged forward
+# once-per-process latch: a caller that flips the flag per timing
+# window must not get a warning per engaged forward
 _WARNED_FUSED_OPTIN: list = []
 
 

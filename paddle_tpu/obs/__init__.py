@@ -12,7 +12,7 @@ The reference treated telemetry as a first-class subsystem
 TrainerInternal.cpp:177); `core/stat.py` is now a view over this
 registry, so there is exactly one timer substrate in the process.
 
-HARD CONSTRAINT (linted by `tools/check_bench_record.py obs`): no
+HARD CONSTRAINT (linted by `ast_lint.check_jax_import_fence`): no
 module in this package imports `jax` at module top level. The registry
 must stay importable in the serving TCP front end, the master client
 and data workers without dragging in the device runtime.

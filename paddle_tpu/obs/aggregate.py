@@ -36,8 +36,8 @@ threshold — the short window gives fast detection, the long window
 refuses to page on a blip that already ended (see DESIGN.md). The
 same two-window rule gates admitted-p99-over-SLO alerting.
 
-No jax imports anywhere (linted by `check_bench_record.py obs`, and
-this module is on the REQUIRED_OBS_MODULES list): fleet aggregation
+No jax imports anywhere (linted by `ast_lint.check_jax_import_fence`,
+whose JAX_FREE_FILES names this module): fleet aggregation
 runs in routers, CLIs and CI boxes with no device runtime.
 """
 
@@ -52,7 +52,7 @@ from paddle_tpu.analysis.lock_order import named_lock
 
 # the cross-process incident bundle schema (written by
 # serving/fleet.py's FleetMonitor, rendered by tools/fleet_view.py,
-# linted by tools/check_bench_record.py bundle)
+# linted by obs/flight_recorder.py check_bundle)
 INCIDENT_SCHEMA = "paddle-tpu-fleet-incident/v1"
 
 

@@ -11,8 +11,8 @@ taps the SAME `registry.event()` pipe the EventStream reads (spans,
 `timeline` samples, `watchdog` rungs, `serving` anomalies,
 `preempt_flush`), keeps the last `capacity` of them in a ring, and on
 `maybe_dump(reason)` writes everything — ring + registry snapshot +
-trigger context — as one bundle file `tools/trace_view.py` and the
-`check_bench_record.py bundle` lint understand.
+trigger context — as one bundle file `tools/trace_view.py` and
+`check_bundle` below (`tools/framework_lint.py bundle`) understand.
 
 Dump discipline (the "no dump storm" contract, pinned by test):
 
@@ -46,8 +46,23 @@ from typing import Optional
 from paddle_tpu.analysis.lock_order import named_lock
 from paddle_tpu.core import flags as _flags
 from paddle_tpu.obs import metrics as _metrics
+from paddle_tpu.obs.aggregate import INCIDENT_SCHEMA
 
 BUNDLE_SCHEMA = "paddle-tpu-flight-bundle/v1"
+BUNDLE_REQUIRED_FIELDS = (
+    "schema", "reason", "ts", "pid", "seq", "events", "metrics",
+)
+# fleet incident bundles (ISSUE 17): the router's cross-process
+# stitch — alerts + per-replica flightz rings + the merged fleet view
+# ride beside the router's own event ring
+INCIDENT_REQUIRED_FIELDS = (
+    "schema", "reason", "ts", "pid", "seq", "alerts", "events",
+    "replicas", "fleet",
+)
+SPAN_EVENT_FIELDS = (
+    "name", "trace_id", "span_id", "parent_id", "ts", "dur_s",
+    "status",
+)
 
 
 class BoundedBundleDir:
@@ -189,8 +204,7 @@ class FlightRecorder:
             return list(self._ring)
 
     def spans(self) -> list:
-        """Just the span events currently in the ring (the bench
-        rows' span-split source)."""
+        """Just the span events currently in the ring."""
         return [e for e in self.snapshot() if e.get("kind") == "span"]
 
     # ---- dumping ----
@@ -228,7 +242,7 @@ class FlightRecorder:
         }
         path = self._dir.path_for(seq, reason)
         if path is None:
-            # ring-only mode (bench rows, tests reading spans()):
+            # ring-only mode (tests reading spans(), a router's ring):
             # nothing to write, but the trigger is still counted and
             # the bundle is handed back in-memory via last_bundle
             self.last_bundle = bundle
@@ -339,3 +353,101 @@ def maybe_dump(reason: str, /, **context) -> Optional[str]:
     if rec is None:
         return None
     return rec.maybe_dump(reason, **context)
+
+
+# ---- bundle lint (`tools/framework_lint.py bundle FILE...`) ------
+def check_bundle(path: str) -> list:
+    """Static schema lint for one bundle file — flight-recorder
+    bundles AND fleet incident bundles (ISSUE 17), dispatched on the
+    schema tag. For an incident bundle the span-event check runs over
+    the STITCHED event set: the router's own ring plus every
+    replica's flightz ring."""
+    violations = []
+    try:
+        with open(path) as f:
+            doc = json.load(f)
+    except (OSError, ValueError) as e:
+        return [f"{path}: unreadable bundle ({e})"]
+    if not isinstance(doc, dict):
+        return [f"{path}: bundle is not a JSON object"]
+    if doc.get("schema") == INCIDENT_SCHEMA:
+        return _check_incident_bundle(path, doc)
+    if doc.get("schema") != BUNDLE_SCHEMA:
+        violations.append(
+            f"{path}: schema {doc.get('schema')!r} != "
+            f"{BUNDLE_SCHEMA!r}"
+        )
+    for field in BUNDLE_REQUIRED_FIELDS:
+        if field not in doc:
+            violations.append(f"{path}: missing field {field!r}")
+    violations.extend(_check_events(path, "events", doc.get("events")))
+    prof = doc.get("profile")
+    if prof is not None and (not isinstance(prof, dict)
+                             or "captured" not in prof):
+        violations.append(
+            f"{path}: 'profile' stanza malformed (needs 'captured')"
+        )
+    return violations
+
+
+def _check_events(path: str, where: str, events) -> list:
+    violations = []
+    if not isinstance(events, list):
+        return [f"{path}: '{where}' is not a list"]
+    for i, ev in enumerate(events):
+        if not isinstance(ev, dict) or "kind" not in ev:
+            violations.append(
+                f"{path}: {where}[{i}] has no 'kind'"
+            )
+            continue
+        if ev["kind"] == "span":
+            missing = [f for f in SPAN_EVENT_FIELDS if f not in ev]
+            if missing:
+                violations.append(
+                    f"{path}: {where}[{i}] span missing {missing}"
+                )
+            elif not (isinstance(ev["dur_s"], (int, float))
+                      and ev["dur_s"] >= 0):
+                violations.append(
+                    f"{path}: {where}[{i}] span dur_s "
+                    f"{ev['dur_s']!r} is not a non-negative number"
+                )
+    return violations
+
+
+def _check_incident_bundle(path: str, doc: dict) -> list:
+    violations = []
+    for field in INCIDENT_REQUIRED_FIELDS:
+        if field not in doc:
+            violations.append(f"{path}: missing field {field!r}")
+    alerts = doc.get("alerts")
+    if not isinstance(alerts, list):
+        violations.append(f"{path}: 'alerts' is not a list")
+    else:
+        for i, a in enumerate(alerts):
+            if not isinstance(a, dict) or "alert" not in a:
+                violations.append(
+                    f"{path}: alerts[{i}] has no 'alert' kind"
+                )
+    fleet = doc.get("fleet")
+    if fleet is not None and (not isinstance(fleet, dict)
+                              or "merged" not in fleet):
+        violations.append(
+            f"{path}: 'fleet' stanza malformed (needs 'merged')"
+        )
+    violations.extend(_check_events(path, "events", doc.get("events")))
+    replicas = doc.get("replicas")
+    if not isinstance(replicas, dict):
+        violations.append(f"{path}: 'replicas' is not a dict")
+        replicas = {}
+    for name, ring in replicas.items():
+        if not isinstance(ring, dict):
+            violations.append(
+                f"{path}: replicas[{name!r}] is not a dict"
+            )
+            continue
+        if "events" in ring:
+            violations.extend(_check_events(
+                path, f"replicas[{name!r}].events", ring["events"]
+            ))
+    return violations

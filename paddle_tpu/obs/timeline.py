@@ -29,8 +29,8 @@ The timeline is pure bookkeeping (no jax — the *trainer* owns the
 fencing; `fence_now()` only answers "is this a fenced step"). Totals
 are mirrored into the process registry as counters under `<prefix>.`;
 `fractions()` yields the `data_wait_frac` / `host_overhead_frac` /
-`device_frac` fields the bench drivers attach to every permanent
-north-star row, and `emit_pass()` writes one structured `timeline`
+`device_frac` split of a pass (`SGD.last_timeline`; they sum to
+about 1), and `emit_pass()` writes one structured `timeline`
 event per pass to the JSONL stream. `end_step` is the always-on
 reader of the spans: a step that took over `SLOW_FACTOR` times the
 median of the last `SLOW_WINDOW` is logged with its split, with or

@@ -45,8 +45,8 @@ every `trace_serve_period`-th anonymous one). Ids of a trace begun in
 this process come from a process counter; only spans that join a
 carrier's trace draw random ids, because other processes add to it.
 
-No jax import, at module scope or later (linted by
-`check_bench_record.py obs`): the profiler is taken from
+No jax import, at module scope or later (module scope is linted by
+`ast_lint.check_jax_import_fence`): the profiler is taken from
 `sys.modules` when the process has jax loaded already, so tracing
 works in the TCP front end, the master client and data workers
 without a device runtime.

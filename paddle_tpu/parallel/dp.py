@@ -181,7 +181,7 @@ class TrainStep:
         # multi-step pipelining (ROADMAP 5d): N consecutive steps as a
         # lax.scan over the SAME step body inside ONE jitted dispatch,
         # so short-step models amortize the per-program submission
-        # floor (bench.py dispatch_floor_probe) N-fold. Per-step RNG is
+        # floor (tests/test_steps_per_dispatch.py) N-fold. Per-step RNG is
         # fold_in(step_key, global_step) — bit-identical to what the
         # sequential loop derives, so N-step and 1-step training walk
         # the same trajectory. Returns stacked per-step losses (or

@@ -336,8 +336,8 @@ def flash_dense_attention(q, k, v, *, causal=False, kv_len=None,
 
 
 # analytic HBM-byte model for the attention CORE (scores + softmax +
-# P@V on one layer's forward), the accounting the longctx bench rows
-# carry so "flash removes bytes" is a stated, checkable expectation:
+# P@V on one layer's forward), the accounting the longctx captures'
+# reports carry so "flash removes bytes" is a checkable expectation:
 # dense round-trips the [B,H,Tq,Tk] scores ~4 times (QK^T write,
 # softmax read+write, P read for P@V); flash never writes them, so
 # only the q/k/v/o streams remain.

@@ -555,8 +555,8 @@ class ShardedEmbeddingTable:
     @property
     def rows_materialized(self) -> int:
         """Distinct rows this table has ever touched (resident +
-        spilled) — the numerator of the bench row's
-        `rows_touched_frac`."""
+        spilled): over `rows_total`, the share of the logical
+        table that was ever touched."""
         return sum(len(d) for d in self._slot_of) + sum(
             len(sp) for sp in self._spill
         )

@@ -465,8 +465,8 @@ class FleetRouter:
         replica = sp.labels.get("replica") or sp.labels.get("shed_by")
         if ok:
             # the router's OWN end-to-end timing of admitted requests
-            # — the independent cross-check the bench row compares
-            # against the fleet p99 merged from replica histograms
+            # — an independent reading beside the fleet p99 that is
+            # merged from the replicas' histograms
             _obs.get_registry().histogram(
                 "fleet.request_latency_s").observe(lat, model=model)
         if self.monitor is not None:

@@ -20,16 +20,16 @@ forces their lanes to eos and freezes their scores, so slot occupancy
 can change between dispatches without recompiling (the decode program
 is keyed only on slot count).
 
-The measured cache story the decode bench row reports:
+The cache story, in counters:
 `cached_prefix_tokens` counts prefix tokens READ from pages by decode
 dispatches (each one a full-prefix recompute the baseline would have
 paid — `prefix_recompute_bytes_saved` prices them via
 `models.lm.lm_prefix_token_recompute_bytes`); `reprefilled_tokens`
 counts tokens a readmission had to recompute because eviction threw
 its pages away. `cache_hit_frac = cached / (cached + reprefilled)`:
-1.0 when nothing is evicted, and decode throughput measurably falls
-with it as eviction pressure rises (the lm_decode bench row's
-scaling points).
+1.0 when nothing is evicted; it falls as eviction pressure rises
+(what that costs in decode throughput on the chip is not measured:
+no cell decodes).
 
 Module scope stays jax-free like every serving/ module: the device
 work happens inside `decoding.kv_cache.PagedLM`, and the blocking

@@ -904,8 +904,8 @@ class InferenceServer:
                 self._anomaly.latency(lat)
             # occupancy bookkeeping: one formed batch per group, its
             # real (un-padded) request count alongside — mean
-            # occupancy = batch_requests / batches, read by the
-            # serve_loadtest bench row instead of recomputed there
+            # occupancy = batch_requests / batches, for whoever reads
+            # the registry (tests/test_serving_robustness.py does)
             reg.counter("serving.batches").inc(model=name)
             reg.counter("serving.batch_requests").inc(
                 len(lats), model=name

@@ -16,8 +16,8 @@ place the tests get their violence from:
   shard to exercise manifest rejection and fallback.
 - `start_preemptible_trainer`: a REAL SGD trainer subprocess with
   checkpointing + auto-resume, the target for SIGTERM-preemption and
-  NaN-injection experiments (shared by tests/test_elastic_faults.py
-  and the `mc_preempt_recovery` bench row).
+  NaN-injection experiments (the preemption and nan-storm tests of
+  tests/test_elastic_faults.py).
 
 Test-support code, but shipped in the package (like the reference's
 paddle/cuda stubs) so downstream users can fault-test their own
@@ -265,9 +265,9 @@ def _read_jsonl(path: str) -> list:
 
 def read_worker_records(out_file: str) -> list:
     """Parse the preemptible worker's OUT_FILE (one JSON dict per
-    line; schema documented on PREEMPTIBLE_TRAINER_SRC). Shared by
-    the elastic-fault tests and the mc_preempt_recovery bench row so
-    a record-format change breaks in one place, loudly."""
+    line; schema documented on PREEMPTIBLE_TRAINER_SRC). One parser
+    for every elastic-fault test, so that a record-format change
+    breaks in one place, loudly."""
     return _read_jsonl(out_file)
 
 

@@ -50,8 +50,8 @@ Telemetry (ISSUE 10): every ladder event is simultaneously (a) kept
 on the structured `WatchdogReport`, (b) counted in the process
 registry (`watchdog.events{kind=...}`), and (c) emitted on the JSONL
 event stream with its `global_step` stamp — so NaN-detect latency and
-rollback cost are computed from the stream by the bench rows instead
-of grepped out of logs.
+rollback cost can be computed from the stream instead of grepped
+out of logs.
 """
 
 from __future__ import annotations

@@ -16,7 +16,7 @@ import jax  # noqa: E402
 jax.config.update("jax_platforms", "cpu")
 # One persistent XLA cache for the session, at the fixed place every
 # entry point uses (core/compile_cache.py). It is exported so that the
-# subprocesses tests spawn (bench smokes, distributed workers, the
+# subprocesses tests spawn (the CLI, distributed workers, the
 # inline replica/trainer sources of testing_faults, which never call
 # the helper) read and write the same directory.
 from paddle_tpu.core import compile_cache  # noqa: E402

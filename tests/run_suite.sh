@@ -14,10 +14,10 @@ N="${1:-3}"
 FAULTS_TIMEOUT="${FAULTS_TIMEOUT:-900}"
 mapfile -t FILES < <(ls tests/test_*.py)
 
-# static-analysis gate, tier 1 (ISSUE 13): the fast jax-free passes
-# (AST lint + bench-record static + obs import fence) run BEFORE the
-# shards — a tree that fails them is broken no matter what the tests
-# say, and they cost ~a second.
+# static-analysis gate, tier 1 (ISSUE 13): the fast jax-free AST
+# passes (the jax import fence among them) run BEFORE the shards — a
+# tree that fails them is broken no matter what the tests say, and
+# they cost ~a second.
 if ! python tools/framework_lint.py --fast; then
   echo "[framework_lint] fast passes FAILED — not running the suite"
   exit 1
@@ -52,9 +52,9 @@ done
 # instrumented and conftest's sessionfinish hook fails the shard on
 # any lock-order inversion observed during the fault tier. The tier
 # includes the ISSUE 20 elastic sparse-CTR kill/resume tests
-# (test_sparse_shard_elastic.py, test_online_learning.py,
-# test_bench_multichip.py::test_ctr_bigvocab_row_*): SIGKILLed
-# sharded-table workers and subprocess serving replicas run under
+# (test_sparse_shard_elastic.py::
+# test_sigkill_mid_epoch_zero_lost_zero_retrained,
+# test_online_learning.py): SIGKILLed sharded-table workers and subprocess serving replicas run under
 # the same lock-order instrumentation.
 JAX_PLATFORMS=cpu \
   XLA_FLAGS=--xla_force_host_platform_device_count=8 \
@@ -65,7 +65,7 @@ JAX_PLATFORMS=cpu \
 tail -2 /tmp/suite_shard_faults.log | sed "s/^/[shard faults] /"
 
 # static-analysis gate, tier 2 (ISSUE 13): the HLO program audit runs
-# AFTER the shards/bench smokes — donation/aliasing, host-transfer
+# AFTER the shards — donation/aliasing, host-transfer
 # and byte budgets, forbidden-op patterns over the committed captures
 # plus committed *.audit.json freshness.
 if ! python tools/framework_lint.py hlo-audit; then

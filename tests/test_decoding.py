@@ -406,21 +406,6 @@ class TestSpeculativeGreedy:
 
 
 class TestChainMetricPlumbing:
-    def test_decode_chain_row_constants(self):
-        """The gated row/fields live in analysis/rows.py (the single
-        source of truth) and the row is a timeline north star."""
-        from paddle_tpu.analysis.rows import (
-            DECODE_CHAIN_FIELDS,
-            DECODE_CHAIN_ROW,
-            DECODE_CHAIN_SPEEDUP_FLOOR,
-            TIMELINE_ROWS,
-        )
-
-        assert DECODE_CHAIN_ROW in TIMELINE_ROWS
-        assert "dispatch_chain_depth" in DECODE_CHAIN_FIELDS
-        assert "chain_speedup" in DECODE_CHAIN_FIELDS
-        assert DECODE_CHAIN_SPEEDUP_FLOOR >= 1.5
-
     def test_decoding_package_is_fenced(self):
         """paddle_tpu/decoding joined the jax-import fence: module
         scope must stay importable with jax blocked (serving reaches

@@ -759,7 +759,7 @@ class TestMasterClientRetries:
 
 
 def _worker_records(out_file):
-    # shared parser (also used by the mc_preempt_recovery bench row)
+    # the one parser of the worker's records
     from paddle_tpu.testing_faults import read_worker_records
 
     return read_worker_records(out_file)

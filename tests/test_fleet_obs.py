@@ -52,7 +52,6 @@ from paddle_tpu.serving.tcp import (  # noqa: E402
     ServingTCPServer,
 )
 
-import check_bench_record as cbr  # noqa: E402
 import fleet_view  # noqa: E402
 
 
@@ -296,7 +295,7 @@ class TestFleetIncidentE2E:
         """The acceptance headline: a 2-replica fleet where one
         replica breaches the p99 SLO. The burn monitor must fire,
         write EXACTLY ONE rate-limited incident bundle naming the
-        slow replica, the bundle must pass the record lint, and
+        slow replica, the bundle must pass the bundle lint, and
         `tools/fleet_view.py` must extract a critical path whose
         spans come from more than one process."""
         incident_dir = str(tmp_path / "incidents")
@@ -355,8 +354,8 @@ class TestFleetIncidentE2E:
             assert len(files) == 1, files
             path = os.path.join(incident_dir, files[0])
 
-            # the bundle validates against the record lint
-            assert cbr.check_bundle(path) == []
+            # the bundle validates against the bundle lint
+            assert fr.check_bundle(path) == []
 
             with open(path) as f:
                 doc = json.load(f)

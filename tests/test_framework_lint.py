@@ -2,10 +2,9 @@
 
 Pins: the driver runs green on THIS tree with jax blocked (the passes
 are pure stdlib), every AST pass actually bites on a seeded
-violation, the REQUIRED_ROWS row lists have exactly one source of
-truth consumed by check_bench_record, and run_suite.sh really wires
-the driver in (fast tier before the shards, HLO audit after, lock
-checking on the faults shard).
+violation, and run_suite.sh really wires the driver in (fast tier
+before the shards, HLO audit after, lock checking on the faults
+shard).
 """
 
 import json
@@ -17,7 +16,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "tools"))
 
 from paddle_tpu.analysis import ast_lint  # noqa: E402
-from paddle_tpu.analysis import rows  # noqa: E402
 
 
 def _run(args, **kw):
@@ -31,7 +29,8 @@ class TestDriver:
     def test_all_green_on_tree_with_jax_blocked(self):
         """The acceptance pin: `framework_lint.py --all` passes on
         the committed tree, in a process where importing jax dies —
-        every pass (AST, bench-static, obs, hlo-audit) is jax-free."""
+        every pass over the tree (AST, hlo-audit, spmd-audit) is
+        jax-free."""
         code = (
             "import sys\n"
             "sys.modules['jax'] = None\n"
@@ -57,9 +56,9 @@ class TestDriver:
     def test_list_and_usage(self):
         r = _run(["--list"])
         assert r.returncode == 0
-        for name in ("ast", "bench-static", "obs", "hlo-audit",
-                     "spmd-audit"):
-            assert name in r.stdout
+        assert r.stdout.split() == [
+            "ast", "hlo-audit", "spmd-audit", "bundle"
+        ]
         r = _run([])
         assert r.returncode == 2
         r = _run(["no-such-pass"])
@@ -100,6 +99,15 @@ class TestAstPasses:
         )
         v = ast_lint.check_jax_import_fence(str(tmp_path))
         assert len(v) == 1 and "bad.py:1" in v[0]
+        # module scope includes try/if blocks: whatever runs at
+        # import time; a function's own import is lazy and fine
+        (tmp_path / "paddle_tpu" / "serving" / "bad.py").write_text(
+            "try:\n    import jax.numpy as jnp\nexcept ImportError:\n"
+            "    jnp = None\n"
+            "def ok():\n    import jax\n"
+        )
+        v = ast_lint.check_jax_import_fence(str(tmp_path))
+        assert len(v) == 1 and "bad.py:2" in v[0]
 
     def test_jax_import_fence_flags_deleted_zone(self, tmp_path):
         self._scaffold(tmp_path)
@@ -108,6 +116,13 @@ class TestAstPasses:
         shutil.rmtree(tmp_path / "paddle_tpu" / "obs")
         v = ast_lint.check_jax_import_fence(str(tmp_path))
         assert any("paddle_tpu/obs" in x and "missing" in x for x in v)
+        # one module of it deleted is named too (the package's
+        # required modules are in JAX_FREE_FILES)
+        self._scaffold(tmp_path)
+        os.remove(tmp_path / "paddle_tpu" / "obs" / "tracing.py")
+        v = ast_lint.check_jax_import_fence(str(tmp_path))
+        assert len(v) == 1 and "missing" in v[0]
+        assert "paddle_tpu/obs/tracing.py" in v[0]
 
     def test_function_local_jax_import_ok(self, tmp_path):
         self._scaffold(tmp_path)
@@ -130,7 +145,7 @@ class TestAstPasses:
 
     def test_unfenced_timing_bites(self, tmp_path):
         self._scaffold(tmp_path)
-        (tmp_path / "paddle_tpu" / "badbench.py").write_text(
+        (tmp_path / "paddle_tpu" / "badtiming.py").write_text(
             "import time\n"
             "def measure(jax, x):\n"
             "    f = jax.jit(lambda v: v + 1)\n"
@@ -143,7 +158,7 @@ class TestAstPasses:
 
     def test_fenced_timing_clean(self, tmp_path):
         self._scaffold(tmp_path)
-        (tmp_path / "paddle_tpu" / "goodbench.py").write_text(
+        (tmp_path / "paddle_tpu" / "goodtiming.py").write_text(
             "import time\n"
             "def measure(jax, x):\n"
             "    f = jax.jit(lambda v: v + 1)\n"
@@ -225,50 +240,6 @@ class TestAstPasses:
         assert "R.bad()" in v[0] and "_d" in v[0]
 
 
-class TestRowsSingleSourceOfTruth:
-    def test_check_bench_record_consumes_rows(self):
-        """Satellite pin: the static AST pass and the compare pass no
-        longer hard-code their own row lists — both read
-        paddle_tpu/analysis/rows.py, object-identically."""
-        import check_bench_record as cbr
-
-        assert cbr.TIMELINE_ROWS is rows.TIMELINE_ROWS
-        assert cbr.REQUIRED_MC_ROWS is rows.REQUIRED_MC_ROWS
-        assert cbr.AB_ROWS is rows.AB_ROWS
-        assert cbr.TIMELINE_FIELDS is rows.TIMELINE_FIELDS
-        assert cbr.needs_timeline is rows.needs_timeline
-        src = open(
-            os.path.join(REPO, "tools", "check_bench_record.py")
-        ).read()
-        # no literal copy left behind to drift
-        assert "mc_checkpoint_overhead" not in src.split(
-            "from paddle_tpu.analysis.rows"
-        )[1].split("BENCH_FILES")[0]
-
-    def test_needs_timeline_prefixes(self):
-        assert rows.needs_timeline("serve_loadtest")
-        assert rows.needs_timeline("mc_longctx_ring_t32768_sp4")
-        assert rows.needs_timeline("mc_preempt_recovery_sp2")
-        assert not rows.needs_timeline("smallnet_fc_train_steps_per_s")
-
-    def test_rows_matches_bench_north_stars(self):
-        """rows.TIMELINE_ROWS still mirrors bench.py's literal
-        NORTH_STARS (the drift tripwire's other side)."""
-        import ast as ast_mod
-
-        tree = ast_mod.parse(
-            open(os.path.join(REPO, "bench.py")).read()
-        )
-        north = None
-        for node in tree.body:
-            if isinstance(node, ast_mod.Assign) and any(
-                isinstance(t, ast_mod.Name) and t.id == "NORTH_STARS"
-                for t in node.targets
-            ):
-                north = tuple(ast_mod.literal_eval(node.value))
-        assert north == rows.TIMELINE_ROWS
-
-
 class TestSuiteWiring:
     def test_run_suite_wires_framework_lint(self):
         """CI satellite pin: the fast tier gates the shards, the HLO
@@ -277,6 +248,12 @@ class TestSuiteWiring:
         sh = open(
             os.path.join(REPO, "tests", "run_suite.sh")
         ).read()
+        import framework_lint
+
+        # the fast tier is the AST passes alone, and the script runs
+        # no pass that the driver no longer has
+        assert framework_lint.FAST_PASSES == ("ast",)
+        assert "bench" not in sh
         assert "framework_lint.py --fast" in sh
         assert "framework_lint.py hlo-audit" in sh
         assert "framework_lint.py spmd-audit" in sh
@@ -305,29 +282,29 @@ class TestSuiteWiring:
                 REPO, "tools", "traces", stem + ".audit.json"
             )), f"{stem}.audit.json missing"
 
-    def test_mc_capture_without_audit_report_fails_static(
-        self, tmp_path
+    def test_capture_without_audit_report_fails_the_audit_pass(
+        self, tmp_path, capsys
     ):
-        """check_bench_record static mode: a committed mc_* capture
-        with no sibling audit.json is a violation (the cheap
-        existence gate the fast tier runs before the shards)."""
+        """A capture the budgets name with no sibling audit.json is a
+        violation of the audit pass that owns it; writing the report
+        clears it."""
         import shutil
 
-        import check_bench_record as cbr
+        import framework_lint
 
-        repo2 = tmp_path / "repo"
-        repo2.mkdir()
-        for f in ("bench.py", "bench_multichip.py", "serve_bench.py"):
-            src = os.path.join(REPO, f)
-            if os.path.exists(src):
-                shutil.copy(src, str(repo2 / f))
-        traces = repo2 / "tools" / "traces"
+        src = os.path.join(REPO, "tools", "traces")
+        traces = tmp_path / "tools" / "traces"
         traces.mkdir(parents=True)
-        (traces / "mc_orphan.hlo.txt.gz").write_bytes(b"\x1f\x8b")
-        v = [x for x in cbr.check_static(str(repo2))
-             if "mc_orphan" in x]
-        assert len(v) == 1 and "audit.json" in v[0]
-        # adding the report clears it
-        (traces / "mc_orphan.audit.json").write_text("{}")
-        assert not [x for x in cbr.check_static(str(repo2))
-                    if "mc_orphan" in x]
+        stem = "mc_sparse_lookup"
+        for ext in (".hlo.txt.gz", ".report.json"):
+            shutil.copy(os.path.join(src, stem + ext), str(traces))
+        budgets = json.load(open(
+            os.path.join(src, "audit_budgets.json")))
+        (traces / "audit_budgets.json").write_text(
+            json.dumps({stem: budgets[stem]}))
+        argv = ["spmd-audit", "--repo", str(tmp_path)]
+        assert framework_lint.main(argv) == 1
+        err = capsys.readouterr().err
+        assert f"{stem}: no committed audit report" in err
+        assert framework_lint.main(argv + ["--write-audit"]) == 0
+        assert framework_lint.main(argv) == 0

@@ -315,7 +315,7 @@ class TestSparseApply:
     def test_step_time_independent_of_vocab(self):
         """With buffer donation the scatter updates the table in place:
         wall time must NOT scale with V (the 'step time independent of
-        V' contract; measured on the TPU chip in bench.py's CTR bench).
+        V' contract).
         16x the vocab is allowed at most ~4x the time — an O(V) update
         would be ~16x."""
         import time

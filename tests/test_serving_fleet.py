@@ -21,8 +21,7 @@ The acceptance surface of the fleet PR, on CPU throughout:
   under load with zero admitted requests lost, breaker rotation
   within the reset window, and a restarted replica booting from the
   verified cache and rejoining rotation via the half-open probe; the
-  boot gate refusing corrupt/policy-violating cache entries; and the
-  `serve_fleet_loadtest` bench row passing its own record lint.
+  boot gate refusing corrupt/policy-violating cache entries.
 """
 
 import json
@@ -670,65 +669,3 @@ class TestFleetFaults:
                                  (np.zeros((1, 8), np.float32),))
         prog = inference.load_verified(cache, "k")
         assert prog.via == "exec"
-
-    def test_fleet_bench_row_passes_record_lint(self, tmp_path):
-        """CPU smoke of the permanent `serve_fleet_loadtest` row: it
-        lands in the full-row artifact, reports admitted_lost == 0,
-        carries the kill-phase dict, and passes its own
-        check_bench_record compare gate."""
-        record = str(tmp_path / "record.jsonl")
-        stdout_path = str(tmp_path / "stdout.txt")
-        env = dict(os.environ, JAX_PLATFORMS="cpu",
-                   BENCH_FULL_RECORD=record,
-                   BENCH_FLEET_SECONDS="0.6")
-        r = subprocess.run(
-            [sys.executable, "bench.py", "serve_fleet_loadtest"],
-            cwd=REPO, env=env, capture_output=True, text=True,
-            timeout=600,
-        )
-        assert r.returncode == 0, r.stderr[-3000:]
-        with open(stdout_path, "w") as f:
-            f.write(r.stdout)
-        rows = [json.loads(ln) for ln in r.stdout.splitlines()
-                if ln.startswith("{")]
-        row = next(x for x in rows
-                   if x["metric"] == "serve_fleet_loadtest")
-        assert row["admitted_lost"] == 0
-        assert row["kill"]["admitted_lost"] == 0
-        assert row["kill"]["goodput_rps"] > 0
-        assert row["kill"]["rotated_out"] is True
-        assert row["kill"]["rejoined"] is True
-        lint = subprocess.run(
-            [sys.executable, "tools/check_bench_record.py", "compare",
-             stdout_path, record],
-            cwd=REPO, capture_output=True, text=True)
-        assert lint.returncode == 0, lint.stderr
-
-    def test_coldstart_bench_row_cache_faster(self, tmp_path):
-        """CPU smoke of the permanent `serve_coldstart` row: the
-        verified-cache boot is measurably faster than the
-        compile-from-scratch boot, and the row passes its record
-        lint."""
-        record = str(tmp_path / "record.jsonl")
-        stdout_path = str(tmp_path / "stdout.txt")
-        env = dict(os.environ, JAX_PLATFORMS="cpu",
-                   BENCH_FULL_RECORD=record,
-                   BENCH_COLDSTART_LAYERS="48")
-        r = subprocess.run(
-            [sys.executable, "bench.py", "serve_coldstart"],
-            cwd=REPO, env=env, capture_output=True, text=True,
-            timeout=600,
-        )
-        assert r.returncode == 0, r.stderr[-3000:]
-        with open(stdout_path, "w") as f:
-            f.write(r.stdout)
-        rows = [json.loads(ln) for ln in r.stdout.splitlines()
-                if ln.startswith("{")]
-        row = next(x for x in rows if x["metric"] == "serve_coldstart")
-        assert row["cache_boot_s"] < row["compile_boot_s"]
-        assert row["value"] > 1.0
-        lint = subprocess.run(
-            [sys.executable, "tools/check_bench_record.py", "compare",
-             stdout_path, record],
-            cwd=REPO, capture_output=True, text=True)
-        assert lint.returncode == 0, lint.stderr

@@ -6,10 +6,9 @@ inside the deadline; FlakyProxy RST/delay/mid-response cuts on client
 connections neither wedge the server nor leak in-flight requests;
 SIGKILL of the serving worker mid-request fails the client fast;
 drain-on-shutdown terminates every admitted request; a hook-bearing
-generation request completes via the host-stepped fallback (replacing
-the bench record's `hooks_on: unavailable` — VERDICT Missing #1); and
-the `serve_loadtest` bench row lands in the full-row artifact with a
-≥3-point latency curve.
+generation request completes via the host-stepped fallback; and the
+registry carries queue depth, occupancy, the time split and the shed
+count at three offered loads.
 
 Everything runs on CPU — serving robustness is a correctness
 property, not a hardware property.
@@ -543,57 +542,84 @@ class TestServeCLI:
                 proc.wait()
 
 
-# ==================================================== bench + artifacts
-class TestServeLoadtestRow:
-    def test_row_has_curve_and_lands_in_full_record(self, tmp_path):
-        """CPU smoke of the permanent `serve_loadtest` bench row: ≥3
-        offered-load points, each with p50/p99 latency, and the row is
-        appended to the BENCH_full artifact (checked with the
-        check_bench_record lint)."""
-        record = str(tmp_path / "full.jsonl")
-        stdout_path = str(tmp_path / "stdout.txt")
-        env = dict(os.environ, JAX_PLATFORMS="cpu",
-                   BENCH_FULL_RECORD=record,
-                   BENCH_SERVE_SECONDS="0.5")
-        r = subprocess.run(
-            [sys.executable, "bench.py", "serve_loadtest"],
-            cwd=REPO, env=env, capture_output=True, text=True,
-            timeout=600,
-        )
-        assert r.returncode == 0, r.stderr[-3000:]
-        with open(stdout_path, "w") as f:
-            f.write(r.stdout)
-        rows = [json.loads(ln) for ln in r.stdout.splitlines()
-                if ln.startswith("{")]
-        row = next(x for x in rows if x["metric"] == "serve_loadtest")
-        assert row["value"] > 0
-        pts = row["points"]
-        assert len(pts) >= 3
-        for p in pts:
-            assert p["p50_ms"] is not None and p["p99_ms"] is not None
-            assert p["p50_ms"] <= p["p99_ms"]
-        # saturation tok/s present + summary carries the row
-        assert "goodput_tok_s" in pts[-1]
-        # registry-sourced telemetry (ISSUE 10): the timeline triple
-        # every north-star row carries, queue-depth HWM and mean
-        # occupancy read from the obs registry, not recomputed here
-        for f in ("data_wait_frac", "host_overhead_frac",
-                  "device_frac"):
-            assert 0.0 <= row[f] <= 1.0, (f, row[f])
-        assert row["max_queue_depth"] >= 1
-        occ = row["mean_batch_occupancy"]
-        assert occ is not None and occ >= 1.0
-        summary = next(x for x in rows if x["metric"] == "summary")
-        assert "serve_loadtest" in summary["north_stars"]
-        # the full-row artifact really holds every printed row
-        rec = [json.loads(ln) for ln in open(record)]
-        assert any(x["metric"] == "serve_loadtest" for x in rec)
-        lint = subprocess.run(
-            [sys.executable, "tools/check_bench_record.py", "compare",
-             stdout_path, record],
-            cwd=REPO, capture_output=True, text=True,
-        )
-        assert lint.returncode == 0, lint.stderr
+# ========================================================= telemetry
+class TestServeTelemetry:
+    @pytest.mark.parametrize("load", [
+        "one_at_a_time", "burst_of_max_batch", "burst_past_the_queue",
+    ])
+    def test_registry_under_load(self, load):
+        """What the server publishes into the obs registry while it
+        serves, read as an operator's dashboard reads it (deltas over
+        the load): queue-depth high-water mark, mean batch occupancy,
+        the admitted requests' time split, the latency histogram, and
+        under overload the shed count, with no admitted request
+        lost."""
+        from paddle_tpu.obs import aggregate as agg
+        from paddle_tpu.obs import metrics as om
+
+        reg = om.get_registry()
+        name = f"toy-{load}"  # series of this case alone
+
+        def read():
+            return {
+                "latency": reg.counter(
+                    "serving.request_latency_s").get(),
+                "queue_wait": reg.counter(
+                    "serving.request_queue_wait_s").get(),
+                "dispatch": reg.counter(
+                    "serving.request_dispatch_s").get(),
+                "shed": reg.counter("serving.shed").get(
+                    reason="overloaded"),
+            }
+
+        reg.gauge("serving.queue_depth_hwm").reset()
+        base = read()
+        cfg = ServeConfig(max_queue=8, max_batch=4,
+                          default_deadline_s=30.0)
+        srv = InferenceServer(cfg)
+        srv.add_model(name, ToyModel(delay_s=0.02))
+        admitted, shed = [], 0
+        try:
+            if load == "one_at_a_time":
+                for _ in range(4):
+                    admitted.append(srv.submit(name, [1, 2]))
+                    admitted[-1].result(timeout=30)
+            else:
+                n = (cfg.max_batch if load == "burst_of_max_batch"
+                     else 5 * cfg.max_queue)
+                for _ in range(n):
+                    try:
+                        admitted.append(srv.submit(name, [1, 2]))
+                    except ServeRejected as e:
+                        assert e.reason == "overloaded"
+                        shed += 1
+                for r in admitted:
+                    r.result(timeout=30)
+        finally:
+            srv.shutdown(drain=True)
+        now = read()
+        d = {k: now[k] - base[k] for k in base}
+        assert reg.gauge("serving.queue_depth_hwm").get(default=0) >= 1
+        batches = reg.counter("serving.batches").get(model=name)
+        served = reg.counter("serving.batch_requests").get(model=name)
+        assert batches >= 1 and served / batches >= 1.0
+        lat, wait, disp = d["latency"], d["queue_wait"], d["dispatch"]
+        assert lat > 0
+        for share in (wait / lat, disp / lat,
+                      max(1.0 - (wait + disp) / lat, 0.0)):
+            assert 0.0 <= share <= 1.0, (wait, disp, lat)
+        hist = reg.snapshot()["histograms"][
+            f"serving.admitted_latency_s{{model={name}}}"]
+        assert agg.quantile(hist, 0.5) <= agg.quantile(hist, 0.99)
+        # nothing admitted is lost: every admitted request was served
+        assert all(r.state == "done" for r in admitted)
+        assert served == len(admitted) == hist["count"]
+        assert d["shed"] == shed
+        if load == "burst_past_the_queue":
+            assert shed > 0
+            assert len(admitted) + shed == 5 * cfg.max_queue
+        else:
+            assert shed == 0
 
 
 class TestLoadCompiledFaults:

@@ -18,6 +18,7 @@ Pins the tentpole contracts:
   CLI mode.
 """
 
+import copy
 import json
 import os
 import signal
@@ -158,9 +159,7 @@ class TestFlightRecorder:
         assert any(e["kind"] == "watchdog" for e in doc["events"])
         assert doc["profile"] == {"captured": False}
         # the static bundle lint accepts the real artifact
-        import check_bench_record as cbr
-
-        assert cbr.check_bundle(p1) == []
+        assert fr.check_bundle(p1) == []
 
     def test_dump_dir_is_bounded(self, tmp_path):
         reg = om.MetricsRegistry()
@@ -174,23 +173,6 @@ class TestFlightRecorder:
                        if f.endswith(".json"))
         assert len(files) == 3
         assert files[-1].startswith("flight-00007")
-
-    def test_bundle_lint_catches_malformed(self, tmp_path):
-        import check_bench_record as cbr
-
-        p = tmp_path / "bad.json"
-        p.write_text(json.dumps({
-            "schema": "wrong/v0", "reason": "x", "ts": 1, "pid": 2,
-            "seq": 1, "metrics": {},
-            "events": [{"kind": "span", "name": "a"}, {"no": "kind"}],
-        }))
-        v = cbr.check_bundle(str(p))
-        assert any("schema" in x for x in v)
-        assert any("span missing" in x for x in v)
-        assert any("no 'kind'" in x for x in v)
-        p2 = tmp_path / "garbage.json"
-        p2.write_text("not json")
-        assert cbr.check_bundle(str(p2))
 
 
 # ============================================== serving end-to-end
@@ -221,6 +203,154 @@ def _serve_pair(delay_s=0.0, **cfg_kw):
     server.add_model("echo", _EchoModel(delay_s=delay_s))
     tcp = ServingTCPServer(server)
     return server, tcp
+
+
+# ======================================================= bundle lint
+_SPAN = {"kind": "span", "name": "a", "trace_id": "t", "span_id": "s",
+         "parent_id": "", "ts": 1.0, "dur_s": 0.1, "status": "ok"}
+
+
+@pytest.fixture(scope="module")
+def written_bundles(tmp_path_factory):
+    """One bundle of each kind as the program writes it: a flight
+    bundle dumped by a FlightRecorder whose ring holds real spans,
+    and an incident bundle stitched by a FleetRouter's monitor over a
+    live replica's flightz frame. Both pass the lint as written."""
+    from paddle_tpu.serving.fleet import FleetConfig, FleetRouter
+
+    tmp = tmp_path_factory.mktemp("bundles")
+    rec = fr.enable_flight_recorder(dump_dir=str(tmp / "flight"),
+                                    min_interval_s=0.0)
+    server, tcp = _serve_pair()
+    router = FleetRouter(
+        {"r0": f"127.0.0.1:{tcp.port}"},
+        FleetConfig(poll_interval_s=0.05, monitor=True,
+                    incident_dir=str(tmp / "incidents"),
+                    incident_min_interval_s=0.0),
+    )
+    try:
+        router.call("echo", [1, 2], deadline_ms=20000, trace=True)
+        _wait_spans(rec, "serve.request")
+        flight = rec.maybe_dump("test", why="lint")
+        incident = router.monitor._maybe_incident(
+            router, [{"alert": "p99_slo", "replica": "r0"}])
+    finally:
+        router.close()
+        tcp.stop()
+        server.shutdown(drain=False)
+        fr.disable_flight_recorder()
+    docs = {}
+    for kind, path in (("flight", flight), ("incident", incident)):
+        assert path and fr.check_bundle(path) == [], (kind, path)
+        with open(path) as f:
+            docs[kind] = json.load(f)
+    assert any(e.get("kind") == "span"
+               for e in docs["incident"]["replicas"]["r0"]["events"])
+    return docs
+
+
+def _first_span(doc):
+    return next(e for e in doc["events"] if e.get("kind") == "span")
+
+
+class TestBundleLint:
+    """`flight_recorder.check_bundle` (the `bundle` pass of
+    tools/framework_lint.py): every field the two schemas require is
+    required, by name."""
+
+    @pytest.mark.parametrize("kind,field", [
+        *(("flight", f) for f in fr.BUNDLE_REQUIRED_FIELDS),
+        *(("incident", f) for f in fr.INCIDENT_REQUIRED_FIELDS),
+        *(("span", f) for f in fr.SPAN_EVENT_FIELDS),
+    ])
+    def test_each_missing_field_is_named(self, written_bundles,
+                                         tmp_path, kind, field):
+        doc = copy.deepcopy(
+            written_bundles["flight" if kind == "span" else kind])
+        del (_first_span(doc) if kind == "span" else doc)[field]
+        p = tmp_path / "b.json"
+        p.write_text(json.dumps(doc))
+        v = fr.check_bundle(str(p))
+        assert any(repr(field) in x for x in v), v
+
+    _SPOILED = {  # case: (bundle kind, what is done to it, violation)
+        "flight_schema": (
+            "flight", lambda d: d.update(schema="wrong/v0"), "schema"),
+        "incident_schema": (
+            "incident",
+            lambda d: d.update(schema=d["schema"][:-1] + "0"), "schema"),
+        "event_without_kind": (
+            "flight", lambda d: d["events"].append({"no": "kind"}),
+            "has no 'kind'"),
+    }
+
+    @pytest.mark.parametrize("case", [*_SPOILED, "not_json"])
+    def test_malformed_is_refused(self, written_bundles, tmp_path,
+                                  case):
+        p = tmp_path / "b.json"
+        if case == "not_json":
+            p.write_text("not json")
+            want = "unreadable bundle"
+        else:
+            kind, spoil, want = self._SPOILED[case]
+            doc = copy.deepcopy(written_bundles[kind])
+            spoil(doc)
+            p.write_text(json.dumps(doc))
+        v = fr.check_bundle(str(p))
+        assert any(want in x for x in v), v
+
+    def test_bundle_lint_cli(self, tmp_path):
+        """`framework_lint.py bundle F...` exits 0 on well-formed
+        bundles, 1 with the violation printed otherwise."""
+        ok = tmp_path / "ok.json"
+        ok.write_text(json.dumps({
+            "schema": "paddle-tpu-flight-bundle/v1", "reason": "t",
+            "ts": 1.0, "pid": 1, "seq": 1, "events": [_SPAN],
+            "metrics": {}, "profile": {"captured": False},
+        }))
+        cmd = [sys.executable, "tools/framework_lint.py", "bundle"]
+        r = subprocess.run(cmd + [str(ok)], cwd=REPO,
+                           capture_output=True, text=True)
+        assert r.returncode == 0, r.stderr
+        bad = tmp_path / "bad.json"
+        bad.write_text(json.dumps({"schema": "nope"}))
+        r = subprocess.run(cmd + [str(ok), str(bad)], cwd=REPO,
+                           capture_output=True, text=True)
+        assert r.returncode == 1 and "schema" in r.stderr
+
+    def test_bundle_lint_incident(self, tmp_path):
+        """`check_bundle` dispatches on the incident schema tag and
+        validates the cross-process stitch: required fields, typed
+        alerts, the fleet stanza, and span events in EVERY ring (the
+        router's own plus each replica's flightz dump)."""
+        good = {
+            "schema": "paddle-tpu-fleet-incident/v1",
+            "reason": "burn_rate", "ts": 1.0, "pid": 1, "seq": 1,
+            "alerts": [{"alert": "p99_slo", "p99_short_ms": 9.0}],
+            "offending": "r1",
+            "states": {}, "events": [_SPAN],
+            "replicas": {"r1": {"pid": 2, "enabled": True,
+                                "events": [_SPAN]}},
+            "fleet": {"merged": {"counters": {}}, "delta": None,
+                      "rates": None},
+        }
+        p = tmp_path / "incident-00001-burn_rate.json"
+        p.write_text(json.dumps(good))
+        assert fr.check_bundle(str(p)) == []
+        # missing required field
+        bad = dict(good)
+        del bad["fleet"]
+        p.write_text(json.dumps(bad))
+        assert any("'fleet'" in x for x in fr.check_bundle(str(p)))
+        # untyped alert entries
+        p.write_text(json.dumps(dict(good, alerts=[{"oops": 1}])))
+        assert any("alert" in x for x in fr.check_bundle(str(p)))
+        # a replica ring with a malformed span event is caught too
+        torn = dict(_SPAN)
+        del torn["dur_s"]
+        p.write_text(json.dumps(dict(
+            good, replicas={"r1": {"events": [torn]}})))
+        assert any("dur_s" in x for x in fr.check_bundle(str(p)))
 
 
 class TestServeTraceEndToEnd:
@@ -617,9 +747,7 @@ class TestTracePropagationUnderFaults:
             assert "serve.queued" in seg_names
             assert "serve.dispatch" in seg_names
             # the bundle lint accepts it
-            import check_bench_record as cbr
-
-            assert cbr.check_bundle(path) == []
+            assert fr.check_bundle(path) == []
         finally:
             fr.disable_flight_recorder()
 

@@ -9,12 +9,6 @@ Registered passes (run one by name, `--fast`, or `--all`):
                timing around async dispatch, unlocked container
                mutation. Pure AST, jax-free, fast — run BEFORE the
                test shards.
-  bench-static tools/check_bench_record.py `static` mode (bench rows
-               must flow through emit(); permanent rows registered;
-               NORTH_STARS/TIMELINE_ROWS drift tripwire), subsumed
-               here as a registered pass.
-  obs          check_bench_record `obs` mode (no module-scope jax in
-               paddle_tpu/obs/; required modules present), subsumed.
   hlo-audit    paddle_tpu/analysis/hlo_audit.py over every capture
                named in tools/traces/audit_budgets.json: donation/
                aliasing, host-transfer budget, byte budgets vs the
@@ -36,6 +30,10 @@ Registered passes (run one by name, `--fast`, or `--all`):
                Same freshness discipline and --write-audit flow as
                hlo-audit; the two passes split the budgets file by
                policy kind so `--all` audits every stem exactly once.
+  bundle       `bundle FILE...`: schema lint of flight-recorder and
+               fleet incident bundles an operator hands it
+               (paddle_tpu/obs/flight_recorder.py `check_bundle`);
+               not a pass over the tree, so `--all` leaves it out.
 
 Runtime tripwires live next door and are driven elsewhere: the
 recompile guard (analysis/recompile_guard.py) arms inside the trainer
@@ -46,7 +44,8 @@ PADDLE_LOCK_CHECK=1 (tests/run_suite.sh).
 Usage:
     python tools/framework_lint.py --all
     python tools/framework_lint.py --fast          # jax-free AST tier
-    python tools/framework_lint.py ast obs ...     # specific passes
+    python tools/framework_lint.py ast hlo-audit   # specific passes
+    python tools/framework_lint.py bundle B.json [...]
     python tools/framework_lint.py hlo-audit --write-audit
     python tools/framework_lint.py --list
 
@@ -64,11 +63,7 @@ import sys
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if _REPO not in sys.path:
     sys.path.insert(0, _REPO)
-_TOOLS = os.path.dirname(os.path.abspath(__file__))
-if _TOOLS not in sys.path:
-    sys.path.insert(0, _TOOLS)
-
-TRACES_DIR = os.path.join(_TOOLS, "traces")
+TRACES_DIR = os.path.join(_REPO, "tools", "traces")
 
 
 # ---- passes -------------------------------------------------------
@@ -76,18 +71,6 @@ def pass_ast(repo: str, _args) -> list:
     from paddle_tpu.analysis import ast_lint
 
     return ast_lint.run_passes(repo)
-
-
-def pass_bench_static(repo: str, _args) -> list:
-    import check_bench_record as cbr
-
-    return [f"[bench-static] {v}" for v in cbr.check_static(repo)]
-
-
-def pass_obs(repo: str, _args) -> list:
-    import check_bench_record as cbr
-
-    return [f"[obs] {v}" for v in cbr.check_obs_imports(repo)]
 
 
 def _audit_pass(repo: str, args, tag: str, only=None) -> list:
@@ -161,15 +144,24 @@ def pass_spmd_audit(repo: str, args) -> list:
     )
 
 
+def pass_bundle(_repo: str, args) -> list:
+    from paddle_tpu.obs import flight_recorder
+
+    return [
+        f"[bundle] {v}"
+        for path in args.bundle_files
+        for v in flight_recorder.check_bundle(path)
+    ]
+
+
 PASSES = {
     "ast": pass_ast,
-    "bench-static": pass_bench_static,
-    "obs": pass_obs,
     "hlo-audit": pass_hlo_audit,
     "spmd-audit": pass_spmd_audit,
+    "bundle": pass_bundle,
 }
 # the jax-free tier cheap enough to gate every suite run up front
-FAST_PASSES = ("ast", "bench-static", "obs")
+FAST_PASSES = ("ast",)
 
 
 def main(argv=None) -> int:
@@ -178,9 +170,10 @@ def main(argv=None) -> int:
         description=__doc__.splitlines()[0],
     )
     ap.add_argument("passes", nargs="*",
-                    help=f"pass names ({', '.join(PASSES)})")
+                    help=f"pass names ({', '.join(PASSES)}); "
+                         f"after `bundle`, the bundle files")
     ap.add_argument("--all", action="store_true",
-                    help="run every registered pass")
+                    help="run every pass over the tree")
     ap.add_argument("--fast", action="store_true",
                     help=f"run the fast jax-free tier "
                          f"({', '.join(FAST_PASSES)})")
@@ -198,8 +191,15 @@ def main(argv=None) -> int:
             print(name)
         return 0
     names = list(args.passes)
+    if "bundle" in names:  # what follows it are its files
+        i = names.index("bundle")
+        names, args.bundle_files = names[:i + 1], names[i + 1:]
+        if not args.bundle_files:
+            print("framework_lint: bundle needs at least one file",
+                  file=sys.stderr)
+            return 2
     if args.all:
-        names = list(PASSES)
+        names = [n for n in PASSES if n != "bundle"]
     elif args.fast:
         names = list(FAST_PASSES)
     if not names:

@@ -1,7 +1,7 @@
 """Long-context attention capture tool (ISSUE 12 / PERF.md round 8):
-compile the longctx train step (the exact model bench.bench_longctx
-measures) with `attn_impl` dense AND flash, and write per-arm
-captures next to the committed traces:
+compile the longctx train step (`longctx_conf` below) with
+`attn_impl` dense AND flash, and write per-arm captures next to the
+committed traces:
 
   tools/traces/longctx_t{T}_{impl}.hlo.txt.gz   compiled HLO module
   tools/traces/longctx_t{T}_{impl}.report.json  shape + XLA cost
@@ -13,12 +13,11 @@ captures next to the committed traces:
 committed `*.attrib.json` byte attribution whose `attention` category
 proves the flash byte removal on the real compiled program — the
 no-TPU-needed half of the proof. On a TPU host, add `--trace-dir` to
-also capture an XPlane profile of the same step (the time half, same
-as tools/profile_resnet.py), and `--run` to measure step wall time on
-whatever backend this runs on.
+also capture an XPlane profile of the same step (the time half), and
+`--run` to measure step wall time on whatever backend this runs on.
 
 Compilation allocates no tensors, so the dense arm compiles at the
-full bench shape (B=4, T=4096) even on a laptop; `--run` at that
+full shape (B=4, T=4096) even on a laptop; `--run` at that
 shape needs the memory for the real [B,H,T,T] scores — that being
 prohibitive is the point.
 
@@ -39,6 +38,48 @@ import numpy as np
 sys.path.insert(
     0, os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 )
+
+
+def longctx_conf(t, d=512, heads=8, layers=2, classes=512,
+                 attn_impl="dense", seq_parallel="none",
+                 vocab=32000):
+    """The long-context self-attention model every longctx row (single
+    chip AND the T>=32k ring/Ulysses multichip rows) measures:
+    embedding -> N causal MHA blocks with residual fc -> per-token
+    classification. One builder so the A/B arms differ ONLY in
+    attn_impl / seq_parallel."""
+    from paddle_tpu import dsl
+
+    with dsl.model() as m:
+        ids = dsl.data("ids", dim=(), is_ids=True, is_seq=True)
+        lbl = dsl.data("label", dim=(), is_ids=True, is_seq=True)
+        x = dsl.embedding(ids, size=d, vocab_size=vocab)
+        for _ in range(layers):
+            att = dsl._add(
+                "multi_head_attention", [x], size=d,
+                num_heads=heads, causal=True,
+                seq_parallel=seq_parallel, attn_impl=attn_impl,
+            )
+            x = dsl.addto(att, dsl.fc(att, size=d, act="relu"))
+        out = dsl.fc(x, size=classes, act="")
+        dsl.classification_cost(out, lbl)
+    return m.conf
+
+
+def longctx_feed(bs, t, classes=512, vocab=32000, seed=0):
+    from paddle_tpu.core.arg import id_arg
+
+    rng = np.random.default_rng(seed)
+    lens = np.full((bs,), t, np.int32)
+    return {
+        "ids": id_arg(
+            rng.integers(0, vocab, (bs, t)).astype(np.int32), lens
+        ),
+        "label": id_arg(
+            rng.integers(0, classes, (bs, t)).astype(np.int32), lens
+        ),
+    }
+
 
 
 def build_step(conf, feed, seed=0):
@@ -130,7 +171,6 @@ def main():
     _flags.set_flag("matmul_precision", "bfloat16")
     jax.config.update("jax_default_prng_impl", "rbg")
 
-    from bench import longctx_conf, longctx_feed
     from paddle_tpu.parallel.ring import attention_hbm_bytes
 
     os.makedirs(args.out_dir, exist_ok=True)
@@ -151,7 +191,7 @@ def main():
             with gzip.open(stem + ".hlo.txt.gz", "wt") as f:
                 f.write(compiled.as_text())
             report = {
-                "model": "bench.longctx_conf full update step "
+                "model": "longctx_conf full update step "
                          "(donated params+opt buffers)",
                 "attn_impl": impl,
                 "batch_size": args.bs,
@@ -184,7 +224,7 @@ def main():
             f.write(compiled.as_text())
         hd = args.d // args.heads
         report = {
-            "model": "bench.longctx_conf (the longctx bench rows)",
+            "model": "longctx_conf grad step",
             "attn_impl": impl,
             "batch_size": args.bs,
             "seq_len": args.t,

@@ -1,6 +1,5 @@
 """Multi-chip HLO capture tool (ISSUE 15): compile the sharded
-programs the CPU-mesh smokes measure (bench_multichip) and write
-per-row captures next to the committed traces:
+programs and write per-row captures next to the committed traces:
 
   tools/traces/<row>.hlo.txt.gz     compiled partitioned HLO module
   tools/traces/<row>.report.json    mesh/shape context + the parsed
@@ -51,8 +50,7 @@ ROWS = (
 
 def _ensure_cpu_mesh(n: int) -> None:
     """Force an n-virtual-device CPU backend BEFORE jax initializes
-    (same trick as bench_multichip's re-exec, minus the re-exec: this
-    tool owns its process from main())."""
+    (this tool owns its process from main())."""
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
@@ -84,13 +82,12 @@ def _write(out_dir, row, text, report):
 
 
 def capture_longctx(mode, t, n_dev, out_dir, synthetic):
-    """The mc_longctx ring/ulysses rows: the SAME model
-    bench_multichip._bench_longctx_sharded measures (bench.py
-    longctx_conf with seq_parallel=mode), time dim sharded over the
+    """The mc_longctx ring/ulysses rows: profile_longctx.py's
+    longctx_conf with seq_parallel=mode, time dim sharded over the
     mesh `seq` axis, fwd+bwd grad step."""
     import jax
 
-    from bench import longctx_conf, longctx_feed
+    from profile_longctx import longctx_conf, longctx_feed
     from paddle_tpu.core.config import OptimizationConf
     from paddle_tpu.core.mesh import (
         DATA_AXIS, SEQ_AXIS, make_mesh, set_mesh,
@@ -132,8 +129,8 @@ def capture_longctx(mode, t, n_dev, out_dir, synthetic):
         set_mesh(make_mesh())
     row = f"mc_longctx_{mode}_t{t_run}"
     _write(out_dir, row, text, {
-        "model": "bench.longctx_conf full train step "
-                 "(the bench_multichip mc_longctx rows)",
+        "model": "longctx_conf full train step "
+                 "(the mc_longctx rows)",
         "seq_parallel": mode,
         "attn_impl": "flash",
         "batch_size": bs,

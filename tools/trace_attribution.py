@@ -3,7 +3,7 @@
 
 The tool behind ROADMAP item 2's attribution requirement: given a
 profiler capture (the Chrome-trace `trace.json.gz` that
-`jax.profiler`/`tools/profile_resnet.py` writes from the XPlane — the
+`jax.profiler` writes from the XPlane — the
 committed `tools/traces/*.trace.json.gz` files), name where the
 device's wall time goes:
 
@@ -28,7 +28,7 @@ plugin or `tensorflow.python.profiler` does this); the committed
 captures are already trace.json.gz.
 
 **HLO-module captures** (`*.hlo.txt[.gz]`, written by
-tools/profile_longctx.py or bench.write_decode_hlo): when no device
+tools/profile_longctx.py or tools/profile_lm.py): when no device
 profiler is reachable (this container has no TPU and the CPU profiler
 emits no per-op plane), the same classifier attributes the REAL
 compiled program's **bytes** statically — every top-level instruction
