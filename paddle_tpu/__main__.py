@@ -484,10 +484,11 @@ def cmd_metrics(args):
                 t = timelines[-1]
                 print(
                     "last timeline: pass %s step %s  "
-                    "data_wait=%.1f%% host=%.1f%% device=%.1f%% "
-                    "ckpt=%.1f%%" % (
+                    "data_wait=%.1f%% h2d=%.1f%% host=%.1f%% "
+                    "device=%.1f%% ckpt=%.1f%%" % (
                         t.get("pass_id"), t.get("global_step"),
                         100 * t.get("data_wait_frac", 0),
+                        100 * t.get("h2d_frac", 0),
                         100 * t.get("host_overhead_frac", 0),
                         100 * t.get("device_frac", 0),
                         100 * t.get("checkpoint_stall_frac", 0),

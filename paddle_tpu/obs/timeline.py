@@ -11,10 +11,16 @@ of the very durations the spans carry. Which span feeds which part:
                          batch (a worker thread reads and feeds a step
                          ahead; its own time is `<prefix>
                          .feed_worker_s`, no part of a step's wall)
+- **h2d**              — `train.h2d`: the training thread placing a
+                         fed batch on the device (`dp.shard_batch`),
+                         a step ahead between the dispatch and the
+                         fetch of the step before, or late, before
+                         its own dispatch: the staging copy and the
+                         enqueue of the transfer
 - **host_dispatch**    — `train.dispatch`: Python + runtime time to
-                         *submit* the jitted step, argument transfer
-                         included (async dispatch: this returns before
-                         the device finishes)
+                         *submit* the jitted step, whose arguments are
+                         on the device (async dispatch: this returns
+                         before the device finishes)
 - **device_step**      — `train.fetch`, the host blocked on the loss,
                          plus `train.fence`, a full `block_until_ready`
                          every `sample_period` steps so the
@@ -45,10 +51,11 @@ import statistics
 
 from paddle_tpu.obs import metrics as _metrics
 
-PARTS = ("data_wait", "host_dispatch", "device_step", "handlers",
+PARTS = ("data_wait", "h2d", "host_dispatch", "device_step", "handlers",
          "checkpoint_stall")
 SPAN_PART = {
     "train.input_wait.feeder": "data_wait",
+    "train.h2d": "h2d",
     "train.dispatch": "host_dispatch",
     "train.fetch": "device_step",
     "train.fence": "device_step",
@@ -102,6 +109,7 @@ class StepTimeline:
             median_s = median * 1e-9
             parts = {
                 "input_wait_s": split.get("train.input_wait.feeder", 0),
+                "h2d_s": split.get("train.h2d", 0),
                 "dispatch_s": split.get("train.dispatch", 0),
                 "fetch_s": split.get("train.fetch", 0),
                 "fence_s": split.get("train.fence", 0),
@@ -144,6 +152,7 @@ class StepTimeline:
         the BeginIteration handler. All zero before the first step."""
         wall = sum(self._totals_ns.values())
         names = {"data_wait": "data_wait_frac",
+                 "h2d": "h2d_frac",
                  "host_dispatch": "host_overhead_frac",
                  "device_step": "device_frac",
                  "handlers": "handlers_frac",

@@ -48,18 +48,34 @@ def param_sharding(mesh: Mesh, pc=None) -> NamedSharding:
     return NamedSharding(mesh, auto_param_spec(pc, mesh))
 
 
-def shard_batch(feed: dict, mesh: Mesh, sharding=None) -> dict:
-    """Device-put a host feed with batch-dim sharding (or `sharding`),
-    under the span `train.h2d`: on a mesh the transfer is a call of
-    its own, and the span says what it took."""
-    sh = sharding or batch_sharding(mesh)
-
-    def put(x):
-        return jax.device_put(x, sh) if x is not None else None
-
-    nbytes = sum(x.nbytes for x in jax.tree_util.tree_leaves(feed))
-    with _tracing.span("train.h2d", bytes=nbytes):
-        return jax.tree_util.tree_map(put, feed)
+def shard_batch(feed: dict, mesh: Optional[Mesh], sharding=None,
+                timeline=None) -> dict:
+    """The one function that places a fed batch on the device: on a
+    mesh with batch-dim sharding (or `sharding`), with a mesh of None
+    as uncommitted arrays on the default device (`jax.device_put(x)`
+    with no device and no sharding: the step lowers, compiles and
+    caches as it does for a numpy feed). The transfer is a call of its
+    own under the span `train.h2d`, which says what it took and is
+    handed to `timeline` (an obs.StepTimeline) where one is given. A
+    feed that is placed already (every leaf a device array, on a mesh
+    with this sharding) is returned as it is, under no span: the
+    training loop places a batch a step ahead, an external loop may
+    hand `TrainStep` numpy."""
+    sh = None
+    if mesh is not None:
+        sh = sharding or batch_sharding(mesh)
+    leaves = jax.tree_util.tree_leaves(feed)
+    if all(isinstance(x, jax.Array) and (
+            sh is None or x.sharding.is_equivalent_to(sh, x.ndim))
+            for x in leaves):
+        return feed
+    nbytes = sum(getattr(x, "nbytes", 0) for x in leaves)
+    with _tracing.span("train.h2d", bytes=nbytes) as moved:
+        placed = jax.tree_util.tree_map(
+            lambda x: jax.device_put(x, sh), feed)
+    if timeline is not None:
+        timeline.add(moved)
+    return placed
 
 
 class TrainStep:
@@ -240,10 +256,10 @@ class TrainStep:
         [n] (or [n, 2] health vectors in watchdog mode) and outs
         leaves stacked [n, ...]. jax.jit retraces per distinct n —
         use one or two stable chunk sizes."""
+        stacked = None  # the steps' axis first, then the batch's
         if self.mesh is not None:
-            feeds = shard_batch(
-                feeds, self.mesh,
-                NamedSharding(self.mesh, P(None, DATA_AXIS)))
+            stacked = NamedSharding(self.mesh, P(None, DATA_AXIS))
+        feeds = shard_batch(feeds, self.mesh, stacked)
         if self.watchdog:
             return self._multi(
                 params, opt_state, state, feeds, step_i, step_key,
@@ -254,8 +270,10 @@ class TrainStep:
 
     def __call__(self, params, opt_state, state, feed, step_i, rng,
                  lr_scale=None):
-        if self.mesh is not None:
-            feed = shard_batch(feed, self.mesh)
+        """`feed`: placed already by `shard_batch` (the training loop
+        does that a step ahead) or not (an external loop's numpy):
+        placed here then, inside the caller's `train.dispatch`."""
+        feed = shard_batch(feed, self.mesh)
         if self.watchdog:
             # always pass the scale so the traced signature is stable;
             # a changed float re-dispatches, never recompiles
@@ -275,8 +293,7 @@ class TrainStep:
         running replicated. AOT compilation does NOT populate the jit
         dispatch cache — run() reuses the compiled executable so the
         step is compiled once."""
-        if self.mesh is not None:
-            feed = shard_batch(feed, self.mesh)
+        feed = shard_batch(feed, self.mesh)
         args = (params, opt_state, state, feed, step_i, rng)
         if self.watchdog:
             args += (1.0,)

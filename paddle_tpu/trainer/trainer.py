@@ -9,7 +9,6 @@ forwardBackward + updater; the whole mesh runs it SPMD.
 
 from __future__ import annotations
 
-import contextlib
 import itertools
 import logging
 from typing import Callable, Optional
@@ -29,7 +28,7 @@ from paddle_tpu.obs.timeline import StepTimeline
 from paddle_tpu.evaluators import create_evaluator
 from paddle_tpu.network import Network
 from paddle_tpu.optimizers import create_optimizer
-from paddle_tpu.parallel.dp import TrainStep
+from paddle_tpu.parallel.dp import TrainStep, shard_batch
 from paddle_tpu.trainer import async_checkpoint as actp
 from paddle_tpu.trainer import checkpoint as ckpt
 from paddle_tpu.trainer import watchdog as wdg
@@ -64,8 +63,9 @@ _PASS_OVER = object()  # what next() gives when the reader has ended
 # Fed batches that may wait in the queue between the pass's worker and
 # the training thread: one to be taken at once and one behind it, so
 # that a feeder a little slower than the step for a batch or two does
-# not reach the chip. Beside them one is in the step and one may be in
-# the worker's hand. A constant: nothing is better served by another.
+# not reach the chip. Beside them one is in the step, one may be on the
+# device for the next step and one in the worker's hand. A constant:
+# nothing is better served by another.
 FEED_AHEAD = 2
 
 
@@ -75,6 +75,105 @@ def _feed_size(feed) -> tuple:
     leaves = jax.tree_util.tree_leaves(feed)
     rows = leaves[0].shape[0] if leaves and leaves[0].ndim else 0
     return rows, sum(x.nbytes for x in leaves)
+
+
+class _FedBatches:
+    """The one source of a pass's fed batches, for both loops:
+    `(batch_id, feed, placed)` in the reader's order from batch
+    `first` on. `feed` is what the feeder made, on the host (numpy:
+    what evaluators, handlers and the counters read); `placed` is the
+    same batch on the device (`dp.shard_batch`), the step's argument.
+
+    A worker thread runs `SGD._feed_ahead` up to FEED_AHEAD batches
+    ahead; everything here runs on the training thread. `next()` takes
+    the next fed batch under `train.input_wait.feeder` (counted
+    `trainer.feed_ahead_ready` where it was there already, else
+    `trainer.feed_ahead_waited`), places it unless `place_next` has
+    (`trainer.feed_placed_late`, else `trainer.feed_placed_ahead`),
+    fires its BeginIteration and counts its rows and bytes.
+
+    `place_next()` is the loop's between a step's dispatch and its
+    fetch: where the queue holds something it takes it and places it
+    (`train.h2d`), so that the transfer runs while the device is busy
+    with the step before and the next dispatch is an enqueue alone.
+    It never waits for the worker: a device that is busy must not find
+    the thread that fetches its loss blocked on the queue. The pass's
+    end or the worker's exception taken there is kept for the next
+    `next()`, after the step's EndIteration, where it arrives without
+    this. One batch ahead at most: the device can use no more.
+
+    Closing it, on any way out of the pass, stops and joins the worker
+    and drops what was fed ahead, the placed batch with the queue's."""
+
+    def __init__(self, trainer, reader, feeder, first, pass_id,
+                 event_handler, tl, context):
+        self._trainer, self._pass_id = trainer, pass_id
+        self._event_handler, self._tl = event_handler, tl
+        self._taken = None  # place_next's: (item, placed, raised)
+        self._ahead = Buffered(
+            lambda: trainer._feed_ahead(reader, feeder, first, context),
+            FEED_AHEAD, name="feed-ahead",
+        )
+
+    def __iter__(self):
+        return self
+
+    def _place(self, feed):
+        return shard_batch(feed, self._trainer.mesh, timeline=self._tl)
+
+    def place_next(self) -> None:
+        if self._taken is not None or not self._ahead.ready():
+            return
+        try:
+            item = next(self._ahead, _PASS_OVER)
+        except Exception as exc:  # the worker's: the next next() raises it
+            self._taken = (None, None, exc)
+            return
+        placed = None if item is _PASS_OVER else self._place(item[1])
+        self._taken = (item, placed, None)
+
+    def __next__(self):
+        taken, self._taken = self._taken, None
+        with _tracing.span("train.input_wait.feeder") as waited:
+            if taken is None:
+                ready = self._ahead.ready()
+                item, placed = next(self._ahead, _PASS_OVER), None
+            else:
+                ready = True
+                item, placed, raised = taken
+                if raised is not None:
+                    raise raised
+            if item is _PASS_OVER:
+                waited.discard()
+        if item is _PASS_OVER:
+            raise StopIteration
+        self._tl.add(waited)
+        reg = _obs.get_registry()
+        reg.counter(
+            "trainer.feed_ahead_ready" if ready
+            else "trainer.feed_ahead_waited").inc()
+        batch_id, feed = item
+        reg.counter(
+            "trainer.feed_placed_late" if placed is None
+            else "trainer.feed_placed_ahead").inc()
+        if placed is None:
+            placed = self._place(feed)
+        self._event_handler(BeginIteration(self._pass_id, batch_id))
+        rows, nbytes = _feed_size(feed)  # a fed batch goes to the step
+        reg.counter("trainer.rows").inc(rows)
+        reg.counter("trainer.feed_bytes").inc(nbytes)
+        return batch_id, feed, placed
+
+    def close(self) -> None:
+        self._taken = None
+        self._ahead.close()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        self.close()
+        return False
 
 
 class SGD:
@@ -235,23 +334,33 @@ class SGD:
         return cost
 
     def run_step(self, feed, lr_scale: float = 1.0,
-                 timeline=None) -> tuple:
+                 timeline=None, fed=None) -> tuple:
         """One step on an already-fed Arg dict; returns
         (cost, finite, outs). The public stepping unit for external
-        loops (paddle.v2's trainer drives this). In watchdog mode the
-        step returns the 2-float health vector [loss, all_finite] —
-        ONE device->host fetch carries both, so the finiteness verdict
-        costs no extra transfer over the loss fetch the loop always
-        made — and a non-finite batch's update was already skipped on
-        device.
+        loops (paddle.v2's trainer drives this): their feed may be
+        numpy, and is then placed on the device inside
+        `train.dispatch` (`TrainStep.__call__`). `SGD.train` hands the
+        feed as `dp.shard_batch` placed it and, as `fed`, its source
+        of batches (`_FedBatches`): between this step's dispatch and
+        its fetch, while the device is busy with it, the next batch
+        is placed, if the queue has it (`train.h2d`). The placed
+        batch is the caller's: nothing here keeps it past the call.
 
-        Spans: `train.dispatch` (argument transfer and enqueue: the
-        jitted call returns before the device finishes) and
-        `train.fetch` (the host blocked on the loss). `timeline`: an
-        obs.StepTimeline that is handed both; on its fenced steps the
-        params are fenced with block_until_ready under `train.fence`,
-        so the update tail is measured too; every other step stays
-        async beyond the loss fetch."""
+        In watchdog mode the step returns the 2-float health vector
+        [loss, all_finite] — ONE device->host fetch carries both, so
+        the finiteness verdict costs no extra transfer over the loss
+        fetch the loop always made — and a non-finite batch's update
+        was already skipped on device.
+
+        Spans: `train.dispatch` (the enqueue: the jitted call returns
+        before the device finishes; an external loop's numpy feed is
+        transferred in it), `train.h2d` (the next batch's placement,
+        with `fed`) and `train.fetch` (the host blocked on the loss).
+        `timeline`: an obs.StepTimeline that is handed dispatch and
+        fetch; on its fenced steps the params are fenced with
+        block_until_ready under `train.fence`, so the update tail is
+        measured too; every other step stays async beyond the loss
+        fetch."""
         with _tracing.span("train.dispatch") as dispatched:
             rng = _rng.split_for_step(self.step_key, self.global_step)
             (
@@ -265,6 +374,8 @@ class SGD:
                 self.global_step, rng, lr_scale=lr_scale,
             )
         self.global_step += 1
+        if fed is not None:
+            fed.place_next()
         with _tracing.span("train.fetch") as fetched:
             if self.step_fn.watchdog:
                 health = np.asarray(loss)  # the single host fetch
@@ -305,7 +416,7 @@ class SGD:
             layer.publish_stats(values, reg)
 
     def run_steps(self, feeds, lr_scale: float = 1.0,
-                  timeline=None) -> tuple:
+                  timeline=None, fed=None) -> tuple:
         """Run len(feeds) consecutive steps in ONE jitted dispatch
         (lax.scan over the train step — multi-step pipelining,
         ROADMAP 5d). Returns (costs, finites, outs): per-batch cost
@@ -314,8 +425,9 @@ class SGD:
         stacked [n, ...] (slice leaf[i] for batch i's evaluator view).
         The per-step RNG/optimizer trajectory is identical to calling
         run_step n times. All feeds in one call must share one shape
-        signature (they compile per distinct stacked shape). Spans
-        and `timeline` as in run_step; stacking the feeds is part of
+        signature (they compile per distinct stacked shape). Spans,
+        `timeline` and `fed` as in run_step; stacking the feeds (on
+        the device, where they are placed already) is part of
         `train.dispatch`."""
         n = len(feeds)
         with _tracing.span("train.dispatch", steps=n) as dispatched:
@@ -333,6 +445,8 @@ class SGD:
                 self.global_step, self.step_key, lr_scale=lr_scale,
             )
         self.global_step += n
+        if fed is not None:
+            fed.place_next()
         with _tracing.span("train.fetch") as fetched:
             health = np.asarray(losses)  # the single host fetch
         if self.step_fn.watchdog:
@@ -367,41 +481,60 @@ class SGD:
         (`data.reader.Buffered`, the reference's DoubleBuffer,
         DataProvider.h:249). This thread takes them out in the same
         order. So for every batch k: reader -> feeder (worker) ->
-        BeginIteration(k) -> step -> EndIteration(k) (this thread), the
-        batches, their order and every loss being what an inline
-        feeder gives; what is new is that the reader and the feeder of
-        the batches after k, as far as the queue lets the worker run,
-        may run BEFORE BeginIteration(k+1) and while batch k is in its
-        step. A reader or feeder may assume: it is called from one
-        thread at a time, in order, once a batch; it may not assume
-        that thread to be the main one, nor read what a BeginIteration
-        handler of its own batch writes, and should keep to numpy (the
-        transfer to the device is the step's). A fed batch is this
+        placement on the device -> BeginIteration(k) -> step ->
+        EndIteration(k) (this thread), the batches, their order and
+        every loss being what an inline feeder gives; what is new is
+        that the reader and the feeder of the batches after k, as far
+        as the queue lets the worker run, may run BEFORE
+        BeginIteration(k+1) and while batch k is in its step. A reader
+        or feeder may assume: it is called from one thread at a time,
+        in order, once a batch; it may not assume that thread to be
+        the main one, nor read what a BeginIteration handler of its
+        own batch writes, and should keep to numpy: the worker makes
+        no JAX array.
+
+        The transfer to the device runs a step ahead too, on this
+        thread (`_FedBatches`, `dp.shard_batch`): between step k's
+        dispatch and its fetch, while the device is busy with k, batch
+        k+1 is taken from the queue IF it is there (this never waits
+        for the worker) and placed, under `train.h2d`; step k+1's
+        dispatch is then an enqueue alone. A batch that was not there
+        (a pass's first, and whenever the worker is behind) is placed
+        before its own dispatch. The step gets the placed batch;
+        evaluators, handlers and counters get the feeder's own, on
+        the host, which the loop keeps beside it until EndIteration:
+        no evaluator gains a device-to-host copy. A fed batch is this
         call's for as long as anything here refers to it (the queue,
-        the step and its transfer, a chunk of `steps_per_dispatch`
-        feeds, the evaluators, a handler that keeps it) and is let go
-        after its EndIteration: a feeder may hand out memory again
-        once nothing refers to it, as `data.feeder.DataFeeder` does,
-        and never before. A reader's or feeder's exception is raised
-        here, by the wait for the batch it belonged to, after every
-        batch before it has been trained. Handlers, evaluators, the
-        watchdog, checkpoints and every JAX call stay on this thread.
+        the placed batch and its transfer, the step, a chunk of
+        `steps_per_dispatch` feeds, the evaluators, a handler that
+        keeps it) and is let go after its EndIteration: a feeder may
+        hand out memory again once nothing refers to it, as
+        `data.feeder.DataFeeder` does, and never before. A reader's or
+        feeder's exception is raised here, by the wait for the batch
+        it belonged to, after every batch before it has been trained
+        (also where the placement took it from the queue: it is kept
+        until then). Handlers, evaluators, the watchdog, checkpoints
+        and every JAX call stay on this thread.
 
         However the call ends (the last pass over, a handler's
         exception, `Preempted`, `WatchdogAbort`) the worker is stopped
-        and joined first. Batches fed ahead and not trained are
-        dropped: a preemption's checkpoint counts trained batches
-        only, and the deterministic reader replays the rest after the
-        resume. A watchdog rollback keeps the queue: the data stream
-        does not roll back.
+        and joined first. Batches fed ahead and not trained, the placed
+        one among them, are dropped: a preemption's checkpoint counts
+        trained batches only, and the deterministic reader replays the
+        rest after the resume. A watchdog rollback keeps the queue and
+        the placed batch: the data stream does not roll back.
 
         Spans: `train.input_wait.feeder` is this thread blocked on the
         queue for the next fed batch (nothing of the reader runs here,
-        so `train.input_wait.reader` is not opened); the worker's own
-        work is `feed_ahead.reader` and `feed_ahead.feeder`, in the
-        call's trace and under no step. Counters: `trainer
-        .feed_ahead_ready` / `.feed_ahead_waited` (steps whose fed
-        batch was, or was not, in the queue when asked for) and
+        so `train.input_wait.reader` is not opened); `train.h2d` is a
+        batch's placement, a sibling of `train.dispatch` under
+        `train.step`; the worker's own work is `feed_ahead.reader` and
+        `feed_ahead.feeder`, in the call's trace and under no step.
+        Counters: `trainer.feed_ahead_ready` / `.feed_ahead_waited`
+        (steps whose fed batch was, or was not, in the queue when
+        asked for), `trainer.feed_placed_ahead` / `.feed_placed_late`
+        (steps whose batch was on the device when the step was
+        dispatched, and steps that placed it themselves) and
         `trainer.feed_worker_s` (the worker's seconds in the reader
         and the feeder).
 
@@ -463,10 +596,10 @@ class SGD:
                 evals = self._make_evaluators()
                 costs = []
                 first = skip_batches if pass_id == start_pass else 0
-                with contextlib.closing(self._fed_batches(
-                    reader, feeder, first, pass_id, event_handler, tl,
-                    context,
-                )) as fed:
+                with _FedBatches(
+                    self, reader, feeder, first, pass_id,
+                    event_handler, tl, context,
+                ) as fed:
                     run_pass = (
                         self._run_pass_pipelined
                         if self.steps_per_dispatch > 1
@@ -580,47 +713,6 @@ class SGD:
                 busy.inc(fed.dur_s)
             yield batch_id, feed
 
-    def _fed_batches(self, reader, feeder, first, pass_id,
-                     event_handler, tl, context):
-        """The one source of a pass's fed batches, for both loops:
-        `(batch_id, feed)` in the reader's order from batch `first`
-        on. A worker thread runs `_feed_ahead` up to FEED_AHEAD batches
-        ahead; here, on the training thread, each `next()` blocks for
-        the next fed batch under `train.input_wait.feeder` (counted
-        `trainer.feed_ahead_ready` where it was waiting already, else
-        `trainer.feed_ahead_waited`), fires its BeginIteration and
-        counts its rows and bytes. Closing it, on any way out of the
-        pass, stops and joins the worker and drops what was fed ahead."""
-        reg = _obs.get_registry()
-        with Buffered(
-            lambda: self._feed_ahead(reader, feeder, first, context),
-            FEED_AHEAD, name="feed-ahead",
-        ) as ahead:
-            while True:
-                ready = ahead.ready()
-                with _tracing.span(
-                        "train.input_wait.feeder") as waited:
-                    item = next(ahead, _PASS_OVER)
-                    if item is _PASS_OVER:
-                        waited.discard()
-                if item is _PASS_OVER:
-                    return
-                tl.add(waited)
-                reg.counter(
-                    "trainer.feed_ahead_ready" if ready
-                    else "trainer.feed_ahead_waited").inc()
-                batch_id, feed = item
-                event_handler(BeginIteration(pass_id, batch_id))
-                self._count_feed(feed)
-                yield item
-
-    def _count_feed(self, feed) -> None:
-        """A fed batch goes to the step: count its rows and bytes."""
-        rows, nbytes = _feed_size(feed)
-        reg = _obs.get_registry()
-        reg.counter("trainer.rows").inc(rows)
-        reg.counter("trainer.feed_bytes").inc(nbytes)
-
     def _after_batch(self, pass_id, batch_id, cost, finite, outs, feed,
                      evals, costs, wd, save_dir, ckpt_mode,
                      event_handler, log_period, observe=True):
@@ -657,8 +749,10 @@ class SGD:
     def _run_pass(self, pass_id, first, fed, event_handler, evals,
                   costs, tl, wd, guard, save_dir, ckpt_mode, log_period):
         """One pass, a step a batch: each `train.step` span covers the
-        wait for the batch's feed, its dispatch and fetch, and its
-        handlers."""
+        wait for the batch's feed (and its placement, where the step
+        before could not place it), its dispatch, the next batch's
+        placement, its fetch, and its handlers. The step gets the
+        placed batch, evaluators and handlers the host's."""
         batch_id = first - 1
         while True:
             with _tracing.span(
@@ -669,9 +763,10 @@ class SGD:
                 if item is None:
                     step.discard()
                     return
-                batch_id, feed = item
+                batch_id, feed, placed = item
                 cost, finite, outs = self.run_step(
-                    feed, wd.lr_scale() if wd else 1.0, timeline=tl,
+                    placed, wd.lr_scale() if wd else 1.0, timeline=tl,
+                    fed=fed,
                 )
                 with _tracing.span("train.handlers") as handled:
                     self._after_batch(
@@ -703,8 +798,11 @@ class SGD:
         shape-signature change (e.g. a ragged final reader batch)
         closes the chunk early and opens the next with the odd batch,
         so mixed shapes cost one extra compile, never an error. One
-        `train.step` span covers a chunk: its batches' input waits,
-        one dispatch, one fetch, the handlers of all its batches."""
+        `train.step` span covers a chunk: its batches' input waits
+        and placements (each batch is placed as it is collected, the
+        chunk stacked on the device), one dispatch, the next chunk's
+        first batch placed if the queue has it, one fetch, the
+        handlers of all its batches."""
         spd = self.steps_per_dispatch
         done_upto = first  # batches of this pass fully trained
 
@@ -729,12 +827,12 @@ class SGD:
 
         def flush(buf):
             cs, fs, outs = self.run_steps(
-                [f for _, f in buf],
-                wd.lr_scale() if wd else 1.0, timeline=tl,
+                [placed for _, _, placed in buf],
+                wd.lr_scale() if wd else 1.0, timeline=tl, fed=fed,
             )
             with _tracing.span("train.handlers") as handled:
                 observe = True
-                for j, (bid, feed) in enumerate(buf):
+                for j, (bid, feed, _) in enumerate(buf):
                     action = self._after_batch(
                         pass_id, bid, cs[j], fs[j],
                         jax.tree_util.tree_map(lambda x: x[j], outs)
@@ -749,10 +847,10 @@ class SGD:
                         observe = False
             tl.add(handled)
 
-        held = None  # (batch_id, feed, sig) whose signature closed a chunk
+        held = None  # (item, sig) whose signature closed a chunk
         pass_over = False
         while not pass_over:
-            buf, sig = ([held[:2]], held[2]) if held else ([], None)
+            buf, sig = ([held[0]], held[1]) if held else ([], None)
             held = None
             with _tracing.span(
                 "train.step", step_num=self.global_step, pass_id=pass_id,
@@ -763,12 +861,11 @@ class SGD:
                     if item is None:
                         pass_over = True
                         break
-                    batch_id, feed = item
-                    fsig = _sig(feed)
+                    fsig = _sig(item[1])
                     if buf and fsig != sig:
-                        held = (batch_id, feed, fsig)
+                        held = (item, fsig)
                         break
-                    buf.append((batch_id, feed))
+                    buf.append(item)
                     sig = fsig
                 if buf:
                     step.set_label("batch_id", buf[0][0])

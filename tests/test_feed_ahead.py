@@ -6,9 +6,18 @@ what feeding the same batches inline gives, bit for bit; the feeder of
 batch k+1 runs while batch k is still in its step; nothing runs further
 ahead than the queue allows; a skipped batch is not fed; the reader's
 and the feeder's exceptions arrive on the training thread as they are;
-and no thread outlives the call, however it ends."""
+and no thread outlives the call, however it ends.
 
+The transfer to the device runs a step ahead too, on the training thread
+(ISSUE 32): batch k+1 is placed between step k's dispatch and its fetch
+where the queue has it by then, and never waited for there; what the
+placement takes from the queue (a batch, the pass's end, the worker's
+exception) arrives where it did; the step gets the placed batch, the
+evaluators the feeder's own."""
+
+import gc
 import threading
+import weakref
 
 import jax
 import numpy as np
@@ -19,6 +28,7 @@ from paddle_tpu.core.arg import Arg
 from paddle_tpu.core.config import OptimizationConf
 from paddle_tpu.data import reader as rd
 from paddle_tpu.data.feeder import DataFeeder, dense_vector, integer_value
+from paddle_tpu.obs import flight_recorder as fr
 from paddle_tpu.obs import metrics as om
 from paddle_tpu.trainer import trainer as trainer_mod
 from paddle_tpu.trainer import watchdog as wdg
@@ -28,6 +38,7 @@ from paddle_tpu.trainer.trainer import FEED_AHEAD, SGD
 
 WAIT_S = 60          # an Event that is not set by then fails the test
 LOOPS = pytest.mark.parametrize("spd", [1, 3], ids=["plain", "chunks"])
+PLACING = pytest.mark.parametrize("spd", [1, 2], ids=["plain", "chunks"])
 OPT = OptimizationConf(learning_method="adam", learning_rate=1e-2)
 
 
@@ -215,7 +226,8 @@ def test_the_next_batch_is_fed_while_this_one_is_in_its_step(spd):
 @LOOPS
 def test_the_worker_runs_no_further_ahead_than_the_queue_allows(spd):
     """When batch k's step ends, batches 0..k have been taken: beside
-    them the queue holds FEED_AHEAD at most and the worker one."""
+    them the queue holds FEED_AHEAD at most, the worker one, and one
+    is placed on the device for the next step."""
     fed, worst = [], []
 
     def feeder(raw):
@@ -228,7 +240,7 @@ def test_the_worker_runs_no_further_ahead_than_the_queue_allows(spd):
             # then a little longer: room for one that would overrun
             for _ in range(20000):
                 if len(fed) >= 1 + FEED_AHEAD + 1:
-                    break
+                    break               # nothing is placed before a step
                 threading.Event().wait(0.0005)
             for _ in range(100):
                 threading.Event().wait(0.0005)
@@ -240,7 +252,8 @@ def test_the_worker_runs_no_further_ahead_than_the_queue_allows(spd):
                     feeder=feeder, event_handler=handler)
     assert fed == list(range(12))
     # a chunk takes its batches before any of them ends
-    assert max(worst) <= FEED_AHEAD + 1 + (spd - 1)
+    assert worst[0] <= FEED_AHEAD + 1
+    assert max(worst) <= FEED_AHEAD + 1 + 1 + (spd - 1)
     assert FEED_AHEAD == 2
 
 
@@ -418,20 +431,432 @@ def test_a_resume_trains_every_batch_once(spd, tmp_path, monkeypatch):
 
 
 def test_the_feed_reaches_the_step_as_numpy():
-    """The worker hands over what the feeder made: no transfer, no JAX
-    array, is made on its thread."""
-    got = []
+    """The worker hands over what the feeder made, numpy: no transfer,
+    no JAX array, is made on its thread. The training thread places
+    it, and the step receives device arrays."""
+    handed, stepped = [], []
     t = _sgd(1)
-    step = t.run_step
+    place, step = trainer_mod.shard_batch, t.step_fn._step
 
-    def run_step(feed, *a, **kw):
-        got.extend(jax.tree_util.tree_leaves(feed))
-        return step(feed, *a, **kw)
+    def shard_batch(feed, *a, **kw):
+        handed.append((threading.current_thread(),
+                       jax.tree_util.tree_leaves(feed)))
+        return place(feed, *a, **kw)
 
-    t.run_step = run_step
-    t.train(reader=lambda: iter(_batches(3)), feeder=_feeder)
-    assert len(got) == 6
-    assert all(type(x) is np.ndarray for x in got)
+    def stepped_with(params, opt_state, state, feed, *a):
+        stepped.extend(jax.tree_util.tree_leaves(feed))
+        return step(params, opt_state, state, feed, *a)
+
+    t.step_fn._step = stepped_with
+    try:
+        trainer_mod.shard_batch = shard_batch
+        t.train(reader=lambda: iter(_batches(3)), feeder=_feeder)
+    finally:
+        trainer_mod.shard_batch = place
+    assert len(handed) == 3
+    assert all(th is threading.current_thread() for th, _ in handed)
+    assert all(type(x) is np.ndarray for _, xs in handed for x in xs)
+    assert len(stepped) == 6
+    assert all(isinstance(x, jax.Array) for x in stepped)
+
+
+# ---- the placement, a step ahead (ISSUE 32) ---------------------------
+
+
+def _until(cond):
+    for _ in range(int(WAIT_S / 0.0005)):
+        if cond():
+            return
+        threading.Event().wait(0.0005)
+    raise AssertionError("waited %d s in vain" % WAIT_S)
+
+
+class Paced(Recorder):
+    """A reader, a feeder and a handler under which batch k+1 is in the
+    queue when step k is dispatched: BeginIteration(k) waits until the
+    pass's worker has entered the feeder for batch k+2 (one worker, in
+    order: it has put k+1 by then) or has ended."""
+
+    def __init__(self, batches, feeder=_feeder, then=None):
+        super().__init__(feeder)
+        self.batches, self.then, self.entered = batches, then, -1
+
+    def reader(self):
+        self.entered = -1
+        return iter(_numbered(self.batches))
+
+    def feed(self, raw):
+        self.entered = raw[2]
+        return super().feed(raw)
+
+    def handle(self, e):
+        super().handle(e)
+        if isinstance(e, BeginIteration):
+            _until(lambda: self.entered >= e.batch_id + 2
+                   or not _workers())
+        if self.then is not None:
+            self.then(e)
+
+
+@pytest.fixture
+def recorder():
+    rec = fr.enable_flight_recorder()
+    try:
+        yield rec
+    finally:
+        fr.disable_flight_recorder()
+
+
+def _steps(rec, t):
+    """[{name: [spans]}] of a call's `train.step` roots, in order."""
+    spans = [s for s in rec.spans() if s["trace_id"] == t.last_trace_id]
+    roots = sorted((s for s in spans if s["name"] == "train.step"),
+                   key=lambda s: s["t0_ns"])
+    out = []
+    for r in roots:
+        kids = {}
+        for s in spans:
+            if s["parent_id"] == r["span_id"]:
+                kids.setdefault(s["name"], []).append(s)
+        out.append(kids)
+    return out
+
+
+def _placed_counts():
+    reg = om.get_registry()
+    return [reg.counter("trainer.feed_placed_" + k).get()
+            for k in ("ahead", "late")]
+
+
+@PLACING
+def test_the_next_batch_is_placed_between_dispatch_and_fetch(
+        spd, recorder):
+    """Step k's dispatch has returned, its loss has not been asked for:
+    there batch k+1 goes to the device. Only a pass's first batch (and
+    a chunk's later ones) is placed by its own step, before its
+    dispatch."""
+    before = _placed_counts()
+    paced = Paced(_batches(8))
+    t = _sgd(spd)
+    t.train(reader=paced.reader, feeder=paced.feed,
+            event_handler=paced.handle)
+    ahead, late = (a - b for a, b in zip(_placed_counts(), before))
+    steps = _steps(recorder, t)
+    assert len(steps) == 8 // spd
+    for i, kids in enumerate(steps):
+        (dispatch,), (fetch,) = kids["train.dispatch"], kids["train.fetch"]
+        placed = kids.get("train.h2d", [])
+        early = [s for s in placed if s["t1_ns"] <= dispatch["t0_ns"]]
+        between = [s for s in placed if s["t0_ns"] >= dispatch["t1_ns"]
+                   and s["t1_ns"] <= fetch["t0_ns"]]
+        assert len(early) + len(between) == len(placed)
+        # the chunk's first batch was placed by the step before
+        assert len(early) == (spd if i == 0 else spd - 1)
+        assert len(between) == (1 if i < len(steps) - 1 else 0)
+    assert (ahead, late) == (len(steps) - 1, 8 - (len(steps) - 1))
+
+
+@PLACING
+def test_placement_never_waits_for_the_worker(spd, recorder):
+    """The feeder of batch 2 returns only after EndIteration(1): a
+    placement that waited for it after step 1's dispatch would keep
+    that step's loss from being fetched, for ever. The batch is placed
+    late, by its own step, and counted so."""
+    released = threading.Event()
+    held = []
+
+    def feeder(raw):
+        if raw[2] == 2:
+            held.append(released.wait(WAIT_S))
+        return _feeder(raw)
+
+    def handler(e):
+        if isinstance(e, EndIteration) and e.batch_id == 1:
+            released.set()
+
+    before = _placed_counts()
+    t = _sgd(spd)
+    t.train(reader=lambda: iter(_numbered(_batches(4))), feeder=feeder,
+            event_handler=handler)
+    assert held == [True] and t.global_step == 4
+    ahead, late = (a - b for a, b in zip(_placed_counts(), before))
+    assert ahead + late == 4 and late >= 2
+    step = _steps(recorder, t)[2 // spd]
+    first = min(step["train.h2d"], key=lambda s: s["t0_ns"])
+    assert first["t1_ns"] <= step["train.dispatch"][0]["t0_ns"]
+    assert first["t0_ns"] >= step["train.input_wait.feeder"][0]["t1_ns"]
+
+
+@PLACING
+def test_events_come_in_the_order_they_came_in(spd):
+    """BeginIteration(k) -> step -> EndIteration(k) -> BeginIteration
+    (k+1), a chunk's Begins before its Ends: a batch placed ahead
+    begins no earlier for it."""
+    paced = Paced(_batches(7))
+    _sgd(spd).train(reader=paced.reader, feeder=paced.feed,
+                    num_passes=2, event_handler=paced.handle)
+    got = [(kind[0], p, b) for kind, p, b in paced.log if kind != "feed"]
+    want = []
+    for p in (0, 1):
+        for i in range(0, 7, spd):
+            chunk = range(i, min(i + spd, 7))
+            want += [("B", p, b) for b in chunk]
+            want += [("E", p, b) for b in chunk]
+    assert got == want
+
+
+@PLACING
+@pytest.mark.parametrize("where", ["reader", "feeder"])
+def test_an_exception_taken_at_placement_arrives_after_the_step(
+        spd, where):
+    """The worker has died of batch 4 before step 3 is dispatched, so
+    the placement after that dispatch finds its exception in the
+    queue: EndIteration(3) still comes first, and then the exception,
+    as it is."""
+    reader, feeder = _raises_in_worker(where)
+    rec = Recorder(feeder)
+
+    def handler(e):
+        rec.handle(e)
+        if isinstance(e, BeginIteration) and e.batch_id == 3:
+            _until(lambda: not _workers())
+
+    with pytest.raises(DiskDied, match="batch 4"):
+        _sgd(spd).train(reader=reader, feeder=rec.feed,
+                        event_handler=handler)
+    events = [e for e in rec.log if e[0] != "feed"]
+    assert events[-1] == ("EndIteration", 0, 3)
+    assert [e[2] for e in events if e[0] == "BeginIteration"] == [
+        0, 1, 2, 3]
+    assert [e[2] for e in events if e[0] == "EndIteration"] == [
+        0, 1, 2, 3]
+    assert not _workers()
+
+
+@PLACING
+def test_the_end_of_a_pass_taken_at_placement_ends_it_after_the_step(spd):
+    """The worker has ended before the pass's last step is dispatched:
+    the placement takes the end from the queue, the step ends as any
+    other, then the pass, and the next pass trains every batch."""
+    rec = Recorder()
+    ends = []
+
+    def handler(e):
+        rec.handle(e)
+        if isinstance(e, EndPass):
+            ends.append(len(rec.log))
+        if isinstance(e, BeginIteration) and e.batch_id == 3:
+            _until(lambda: not _workers())
+
+    t = _sgd(spd)
+    t.train(reader=lambda: iter(_numbered(_batches(4))), feeder=rec.feed,
+            num_passes=2, event_handler=handler)
+    ended = [e[1:] for e in rec.log if e[0] == "EndIteration"]
+    assert ended == [(p, b) for p in (0, 1) for b in range(4)]
+    assert t.global_step == 8 and len(ends) == 2
+    assert rec.log[ends[0] - 1] == ("EndIteration", 0, 3)
+    assert not _workers()
+
+
+def _preempted(t, paced, tmp_path, monkeypatch):
+    guard = trainer_mod._NullPreemptionGuard()
+    monkeypatch.setattr(wdg, "PreemptionGuard", lambda: guard)
+
+    def then(e):
+        if isinstance(e, EndIteration) and e.batch_id == 3:
+            guard.preempted = True
+    paced.then = then
+    with pytest.raises(wdg.Preempted) as ei:
+        t.train(reader=paced.reader, feeder=paced.feed,
+                event_handler=paced.handle,
+                save_dir=str(tmp_path / "ckpt"))
+    return ei
+
+
+def _handler_raised(t, paced, tmp_path, monkeypatch):
+    def then(e):
+        if isinstance(e, EndIteration) and e.batch_id == 3:
+            raise Stop
+    paced.then = then
+    with pytest.raises(Stop) as ei:
+        t.train(reader=paced.reader, feeder=paced.feed,
+                event_handler=paced.handle)
+    return ei
+
+
+def _watchdog_aborted(t, paced, tmp_path, monkeypatch):
+    with pytest.raises(wdg.WatchdogAbort) as ei:
+        t.train(reader=paced.reader, feeder=paced.feed,
+                event_handler=paced.handle)
+    return ei
+
+
+@PLACING
+@pytest.mark.parametrize(
+    "end", [_preempted, _handler_raised, _watchdog_aborted],
+    ids=lambda f: f.__name__.lstrip("_"))
+def test_an_early_end_drops_the_placed_batch_with_the_queues(
+        spd, end, tmp_path, monkeypatch):
+    """When the call ends a batch is on the device for a step that
+    will not come. Nothing keeps it once the call has returned, not
+    even for one who keeps the exception and so the call's frames."""
+    placed = []
+    place = trainer_mod.shard_batch
+
+    def shard_batch(feed, *a, **kw):
+        out = place(feed, *a, **kw)
+        placed.append([weakref.ref(x)
+                       for x in jax.tree_util.tree_leaves(out)])
+        return out
+
+    def feeder(raw):
+        feed = _feeder(raw)
+        if end is _watchdog_aborted and raw[2] >= 2:
+            feed["x"] = Arg(value=np.full_like(raw[0], np.nan))
+        return feed
+
+    monkeypatch.setattr(trainer_mod, "shard_batch", shard_batch)
+    kw = {}
+    if end is _watchdog_aborted:
+        kw["watchdog"] = wdg.WatchdogConfig(skip_budget=1)
+    t = _sgd(spd, **kw)
+    paced = Paced(_batches(12), feeder)
+    raised = end(t, paced, tmp_path, monkeypatch)
+    begun = len([e for e in paced.log if e[0] == "BeginIteration"])
+    assert begun == t.global_step == 4
+    assert len(placed) == begun + 1     # one was placed ahead
+    gc.collect()
+    assert [r() for r in placed[-1]] == [None, None]
+    assert raised.traceback and not _workers()
+
+
+@PLACING
+def test_a_resume_after_a_placement_trains_every_batch_once(
+        spd, tmp_path, monkeypatch):
+    """Preempted with batch 4 on the device: it is dropped with the
+    queue's, the checkpoint counts the four trained, and the resumed
+    call feeds and trains 4..9."""
+    batches = _batches(10)
+    save_dir = str(tmp_path / "ckpt")
+    paced = Paced(batches)
+    t = _sgd(spd)
+    _preempted(t, paced, tmp_path, monkeypatch)
+    monkeypatch.setattr(wdg, "PreemptionGuard",
+                        trainer_mod._NullPreemptionGuard)
+    t2, rec2 = _sgd(spd), Recorder()
+    assert t2.resume(save_dir) == 0
+    t2.train(reader=lambda: iter(_numbered(batches)), feeder=rec2.feed,
+             event_handler=rec2.handle, save_dir=save_dir)
+    trained = [e[2] for r in (paced, rec2) for e in r.log
+               if e[0] == "EndIteration"]
+    assert trained == list(range(10))
+    assert [e[1] for e in rec2.log if e[0] == "feed"] == list(range(4, 10))
+    straight = _sgd(spd)
+    straight.train(reader=lambda: iter(batches), feeder=_feeder)
+    for name, value in straight.params.items():
+        np.testing.assert_array_equal(np.asarray(t2.params[name]),
+                                      np.asarray(value))
+
+
+@PLACING
+def test_a_watchdog_rollback_keeps_the_placed_batch(spd, tmp_path):
+    """Two batches of NaN in pass 1 spend the skip budget and roll the
+    parameters back to pass 0's checkpoint, with the next batch on the
+    device already: the data stream does not roll back, every batch
+    begins and ends once, in order."""
+    def feeder(raw):
+        feed = _feeder(raw)
+        if raw[2] in (3, 4) and fed.count(raw[2]) == 1:
+            feed["x"] = Arg(value=np.full_like(raw[0], np.nan))
+        fed.append(raw[2])
+        return feed
+
+    fed = []
+    paced = Paced(_batches(6), feeder)
+    t = _sgd(spd, watchdog=wdg.WatchdogConfig(skip_budget=1,
+                                              good_batches=3))
+    t.train(reader=paced.reader, feeder=paced.feed, num_passes=3,
+            event_handler=paced.handle, save_dir=str(tmp_path / "ckpt"))
+    report = t.last_watchdog_report
+    assert report.rollbacks == 1 and not report.aborted
+    ids = [(p, b) for p in range(3) for b in range(6)]
+    assert [e[1:] for e in paced.log if e[0] == "BeginIteration"] == ids
+    assert [e[1:] for e in paced.log if e[0] == "EndIteration"] == ids
+
+
+class Kept:
+    """An evaluator that keeps the feeds it is handed."""
+
+    name = "kept"
+
+    def __init__(self):
+        self.feeds = []
+
+    def add_batch(self, outs, feed):
+        self.feeds.append(feed)
+
+    def result(self):
+        return len(self.feeds)
+
+
+@PLACING
+def test_evaluators_get_the_feeders_own_batch(spd, monkeypatch):
+    """The step trains the placed batch; an evaluator reads labels and
+    inputs from the batch the feeder made, on the host, the same
+    objects: it gains no copy back from the device."""
+    made, kept = [], Kept()
+
+    def feeder(raw):
+        made.append(_feeder(raw))
+        return made[-1]
+
+    t = _sgd(spd)
+    monkeypatch.setattr(t, "_make_evaluators", lambda: [kept])
+    t.train(reader=lambda: iter(_batches(5)), feeder=feeder)
+    assert len(kept.feeds) == 5
+    assert all(got is fed for got, fed in zip(kept.feeds, made))
+    assert all(type(x) is np.ndarray for fed in kept.feeds
+               for x in jax.tree_util.tree_leaves(fed))
+
+
+@pytest.mark.parametrize("mesh", [False, True], ids=["one", "mesh"])
+def test_run_step_from_an_external_loop_takes_numpy_or_placed(mesh):
+    """`run_step(feed)` with the feeder's numpy batch, as paddle.v2's
+    trainer and `train_batch` call it, trains what the same batch
+    placed beforehand trains, bit for bit."""
+    from paddle_tpu.core.mesh import make_mesh
+    from paddle_tpu.parallel.dp import shard_batch
+
+    kw = {}
+    if mesh:
+        kw["mesh"] = make_mesh({"data": 4}, devices=jax.devices()[:4])
+    a, b = _sgd(1, **kw), _sgd(1, **kw)
+    for raw in _batches(4):
+        got = a.run_step(_feeder(raw))
+        want = b.run_step(shard_batch(_feeder(raw), b.mesh))
+        assert got[:2] == want[:2]
+    assert a.train_batch(_feeder(raw)) == b.train_batch(_feeder(raw))
+    for name, value in b.params.items():
+        np.testing.assert_array_equal(np.asarray(a.params[name]),
+                                      np.asarray(value))
+
+
+def test_memory_taken_again_stops_growing_with_a_batch_placed_ahead():
+    """Over 40 steps `DataFeeder` makes fresh memory only for what is
+    alive at once: the queue's two, the worker's, the step's, the one
+    placed ahead. Were a placed batch or its host copy held past its
+    step, `fresh` would rise with every batch."""
+    rng = np.random.default_rng(32)
+    rows = [(rng.standard_normal(8).astype(np.float32), int(rng.integers(4)))
+            for _ in range(8 * 40)]
+    feeder = DataFeeder({"x": 0, "label": 1},
+                        {"x": dense_vector(8), "label": integer_value(4)})
+    fresh = om.get_registry().counter("feeder.buffers_fresh")
+    before = fresh.get()
+    t = _sgd(1)
+    t.train(reader=rd.batched(lambda: iter(rows), 8), feeder=feeder)
+    assert t.global_step == 40
+    assert fresh.get() - before <= 6
 
 
 # ---- data.reader.Buffered itself -------------------------------------

@@ -917,11 +917,14 @@ class TestTrainerStepSpans:
         assert {s["trace_id"] for s in steps} == {t.last_trace_id}
         kids = [s for s in global_recorder.spans()
                 if s["parent_id"] == steps[3]["span_id"]]
-        assert {k["name"] for k in kids} == {
+        # a batch's placement (`train.h2d`) is this step's child or
+        # the step's before: tests/test_train_spans.py holds where
+        assert {k["name"] for k in kids} - {"train.h2d"} == {
             "train.input_wait.feeder",
             "train.dispatch", "train.fetch", "train.fence",
             "train.handlers",
         }
+        assert len(by["train.h2d"]) == 12
         assert len(by["train.fence"]) == 3  # 12 steps / period 4
         assert steps[-1]["labels"]["step_num"] == 11
         assert steps[-1]["labels"]["batch_id"] == 5

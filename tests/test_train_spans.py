@@ -1,8 +1,11 @@
 """The training step is spanned from inside `SGD.train`: every step one
 `train.step` root whose children are where the training thread's time
 goes, read here from a ring-only flight recorder, and the same durations
-feed `StepTimeline`. The pass's worker, which reads and feeds a step
-ahead, spans its own work as `feed_ahead.*`, outside the steps."""
+feed `StepTimeline`. A batch's placement on the device is `train.h2d`,
+beside `train.dispatch`: after the dispatch of the step before where the
+queue had the batch by then, else before its own. The pass's worker,
+which reads and feeds a step ahead, spans its own work as `feed_ahead.*`,
+outside the steps."""
 
 import logging
 import os
@@ -15,6 +18,7 @@ import pytest
 
 from paddle_tpu import dsl
 from paddle_tpu.core import flags as _flags
+from paddle_tpu.core.arg import Arg
 from paddle_tpu.core.config import OptimizationConf
 from paddle_tpu.core.mesh import make_mesh
 from paddle_tpu.data.feeder import DataFeeder, dense_vector, integer_value
@@ -120,6 +124,18 @@ def _assert_trees(trees, share=0.05):
     assert walls - covered <= share * walls, (walls, covered)
 
 
+def _but_placements(kids):
+    """The children's names with every `train.h2d` taken out, each of
+    which follows the wait for its own batch (placed late) or the
+    dispatch (the next batch, placed ahead)."""
+    names = [k["name"] for k in kids]
+    for i, name in enumerate(names):
+        if name == "train.h2d":
+            assert i and names[i - 1] in ("train.input_wait.feeder",
+                                          "train.dispatch")
+    return [n for n in names if n != "train.h2d"]
+
+
 def _assert_timeline_is_the_spans(t, trees):
     got = {p: 0 for p in set(SPAN_PART.values())}
     for _, kids in trees:
@@ -138,10 +154,14 @@ def test_every_step_has_one_root_with_the_tables_children(
             for r, _ in trees] == [(p, b) for p in (0, 1) for b in range(6)]
     for i, (root, kids) in enumerate(trees):
         fenced = (i + 1) % 4 == 0
-        assert [k["name"] for k in kids] == [
+        assert _but_placements(kids) == [
             "train.input_wait.feeder", "train.dispatch", "train.fetch",
             *(["train.fence"] if fenced else []), "train.handlers"]
         assert root["status"] == "ok" and root["parent_id"] == ""
+    # a pass's first batch is placed by its own step
+    for first in (trees[0], trees[6]):
+        assert [k["name"] for k in first[1]][:2] == [
+            "train.input_wait.feeder", "train.h2d"]
     _assert_trees(trees)
     # nothing for the wait of a pass that only found it over, and the
     # reader no longer runs on the training thread
@@ -155,27 +175,49 @@ def test_every_step_has_one_root_with_the_tables_children(
 
 
 @pytest.mark.parametrize("spd", [1, 4])
-def test_a_mesh_puts_the_transfer_under_dispatch(recorder, spd):
-    t = _train(mesh=make_mesh({"data": 4}, devices=jax.devices()[:4]),
-               steps_per_dispatch=spd)
+@pytest.mark.parametrize("mesh", [False, True], ids=["one", "mesh"])
+def test_the_transfer_is_a_span_beside_dispatch(recorder, spd, mesh):
+    """With a mesh or without: every batch is placed once, under a
+    `train.h2d` that is its step's child and not the dispatch's, and
+    what the step is handed needs no second transfer."""
+    kw = {}
+    if mesh:
+        kw["mesh"] = make_mesh({"data": 4}, devices=jax.devices()[:4])
+    t = _train(steps_per_dispatch=spd, **kw)
     spans = recorder.spans()
     trees = _trees(recorder, t.last_trace_id)
     assert sum(r["labels"].get("steps", 1) for r, _ in trees) == 12
+    placed = [s for s in spans if s["name"] == "train.h2d"]
+    assert len(placed) == 12
+    roots = {r["span_id"] for r, _ in trees}
+    assert {s["parent_id"] for s in placed} <= roots
+    # a batch: 4 rows of 4 float32 and 4 int32 ids
+    assert {s["labels"]["bytes"] for s in placed} == {4 * 4 * 4 + 4 * 4}
     for root, kids in trees:
         assert {k["name"] for k in kids} >= CHILDREN
-        dispatch = next(k for k in kids if k["name"] == "train.dispatch")
-        h2d = [s for s in spans if s["parent_id"] == dispatch["span_id"]]
-        assert [s["name"] for s in h2d] == ["train.h2d"]
-        # a batch: 4 rows of 4 float32 and 4 int32 ids
-        assert h2d[0]["labels"]["bytes"] == (
-            root["labels"].get("steps", 1) * (4 * 4 * 4 + 4 * 4))
-        assert dispatch["t0_ns"] <= h2d[0]["t0_ns"] \
-            and h2d[0]["t1_ns"] <= dispatch["t1_ns"]
+        _but_placements(kids)
 
 
-def test_without_a_mesh_there_is_no_transfer_span(recorder):
-    _train()
-    assert not [s for s in recorder.spans() if s["name"] == "train.h2d"]
+def test_an_external_loops_numpy_feed_is_placed_under_dispatch(recorder):
+    """`run_step` from a loop that is not `SGD.train`'s: a numpy feed
+    is placed by the step itself, inside `train.dispatch`; one that is
+    on the device already is not placed again; both train the same."""
+    t = _train()
+    rng = np.random.default_rng(1)
+    feed = {"x": Arg(value=rng.standard_normal((4, 4)).astype(np.float32)),
+            "y": Arg(ids=np.arange(4, dtype=np.int32) % 3)}
+    before = len(recorder.spans())
+    cost = t.run_step(feed)[0]
+    mid = len(recorder.spans())
+    t.run_step(jax.tree_util.tree_map(jax.numpy.asarray, feed))
+    spans = recorder.spans()
+    first, second = spans[before:mid], spans[mid:]
+    dispatch = next(s for s in first if s["name"] == "train.dispatch")
+    h2d = [s for s in first if s["name"] == "train.h2d"]
+    assert len(h2d) == 1 and h2d[0]["parent_id"] == dispatch["span_id"]
+    assert h2d[0]["labels"]["bytes"] == 4 * 4 * 4 + 4 * 4
+    assert "train.h2d" not in [s["name"] for s in second]
+    assert np.isfinite(cost)
 
 
 def test_a_chunk_of_steps_is_one_root(recorder, fence_every_4):
@@ -187,7 +229,7 @@ def test_a_chunk_of_steps_is_one_root(recorder, fence_every_4):
     assert [r["labels"]["step_num"] for r, _ in trees] == [0, 4, 6, 10]
     for root, kids in trees:
         n = root["labels"]["steps"]
-        names = [k["name"] for k in kids]
+        names = _but_placements(kids)
         assert names[:n] == ["train.input_wait.feeder"] * n
         rest = names[n:]
         assert rest[:2] == ["train.dispatch", "train.fetch"]
@@ -252,8 +294,8 @@ def test_a_stalled_reader_is_one_slow_step_with_its_split(
     assert e["step_num"] == 8 and (e["pass_id"], e["batch_id"]) == (1, 2)
     assert e["input_wait_s"] >= 6 * STEP_S
     assert e["wall_s"] > 3 * e["median_s"]
-    parts = sum(e[k] for k in ("input_wait_s", "dispatch_s", "fetch_s",
-                               "fence_s", "handlers_s"))
+    parts = sum(e[k] for k in ("input_wait_s", "h2d_s", "dispatch_s",
+                               "fetch_s", "fence_s", "handlers_s"))
     assert parts == pytest.approx(e["wall_s"], rel=0.05)
     lines = [r.getMessage() for r in caplog.records
              if "slow step" in r.getMessage()]
@@ -298,7 +340,7 @@ def test_spans_lie_in_the_profilers_trace(tmp_path):
     stats = [dict(ev.stats) for ev in roots]
     assert sorted(s["step_num"] for s in stats)[-1] == 12
     assert {s["pass_id"] for s in stats} == {0, 1}
-    for name in CHILDREN:
+    for name in CHILDREN | {"train.h2d"}:
         inside = [ev for ev in events if ev.name == name]
         assert len(inside) >= 12, name
         for ev in inside:
@@ -353,6 +395,28 @@ def test_the_feed_ahead_counters_add_up_to_the_steps(recorder, spd):
     assert worker_s == pytest.approx(
         sum(s["t1_ns"] - s["t0_ns"] for s in spans) * 1e-9, rel=1e-6)
     assert worker_s >= 12 * STEP_S / 4
+
+
+@pytest.mark.parametrize("spd", [1, 4])
+def test_the_placement_counters_add_up_to_the_steps(recorder, spd):
+    """Every step's batch was on the device when the step was
+    dispatched (`ahead`) or was placed by the step itself (`late`: a
+    pass's first at least); `trainer.h2d_s` is the `train.h2d` spans'
+    own seconds."""
+    reg = om.get_registry()
+    names = ("trainer.feed_placed_ahead", "trainer.feed_placed_late",
+             "trainer.h2d_s")
+    before = [reg.counter(n).get() for n in names]
+    _train(steps_per_dispatch=spd)
+    ahead, late, h2d_s = (reg.counter(n).get() - b
+                          for n, b in zip(names, before))
+    assert ahead + late == 12
+    assert late >= 2            # each pass's first batch
+    assert ahead >= 2           # the handler's sleep lets the worker lead
+    spans = [s for s in recorder.spans() if s["name"] == "train.h2d"]
+    assert len(spans) == 12
+    assert h2d_s == pytest.approx(
+        sum(s["t1_ns"] - s["t0_ns"] for s in spans) * 1e-9, rel=1e-6)
 
 
 def test_no_worker_outlives_the_call():
