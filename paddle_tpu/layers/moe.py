@@ -1,9 +1,13 @@
 """MoE layer: sparsely-activated expert FFN. One layer type, two routings.
 
 With `top_k` in its attrs it is the expert layer of a present-day decoder
-block (models/mellum.py): softmax-then-top-k routing over ALL
-`num_experts`, of which the layer HOLDS a contiguous share (`held`: first
-index and count, as one chip of an expert-parallel layer does), gated
+block (models/mellum.py, models/kimi.py): top-k routing over ALL
+`num_experts` (`scoring_func` "softmax", or "sigmoid" with
+`routed_scaling_factor`; `topk_method` "noaux_tc" chooses by score plus the
+selection bias `e_score_correction_bias`, a float32 constant of the job that
+no gradient reaches and the optimizer leaves alone), of which the layer
+HOLDS a contiguous share (`held`: first index and count, as one chip of
+an expert-parallel layer does), gated
 (SiLU) experts, renormalised weights, and no dropped token whatever the
 imbalance (ops/moe.py `dropless_moe`: a sort, grouped matrix products, a
 weighted gather). It computes its own experts' part of the result; what
@@ -42,8 +46,9 @@ class MoELayer(Layer):
     expert_act. size = output dim (== input dim). Params: router w0
     [D, E]; experts w_in [E, D, H], w_out [E, H, D]."""
 
-    # the dropless path's router (the capacity path's is `w0`)
-    float32_params = ("router",)
+    # the dropless path's router (the capacity path's is `w0`) and its
+    # selection bias
+    float32_params = ("router", "e_score_correction_bias")
 
     @property
     def dropless(self) -> bool:
@@ -56,8 +61,9 @@ class MoELayer(Layer):
         return int(first), int(count)
 
     def _build_dropless(self, s):
-        """Router [D, E] over all the experts; w_gate, w_up [Eh, D, H] and
-        w_down [Eh, H, D] of the Eh held."""
+        """Router [D, E] over all the experts (with `topk_method`
+        "noaux_tc" its selection bias [E] beside it); w_gate, w_up
+        [Eh, D, H] and w_down [Eh, H, D] of the Eh held."""
         d = s.size
         a = self.conf.attrs
         _, eh = self._held()
@@ -73,6 +79,11 @@ class MoELayer(Layer):
                 if pc.initial_std is None:     # one expert's fan-in
                     pc.initial_std = 1.0 / (dims[1] ** 0.5)
             pcs[slot] = pc
+        if a.get("topk_method") == "noaux_tc":
+            pc = self.weight_conf(0, (a["num_experts"],))
+            pc.name = f"_{self.name}.e_score_correction_bias"
+            pc.is_static = True
+            pcs["e_score_correction_bias"] = pc
         self._spec = s
         return s, pcs
 
@@ -129,6 +140,9 @@ class MoELayer(Layer):
             flat, params["router"], params["w_gate"], params["w_up"],
             params["w_down"], top_k=a["top_k"], held_first=self._held()[0],
             norm_topk=a.get("norm_topk", True),
+            scoring=a.get("scoring_func", "softmax"),
+            select_bias=params.get("e_score_correction_bias"),
+            routed_scale=a.get("routed_scaling_factor", 1.0),
             activation=activations.get(a.get("expert_act", "silu")),
             token_mask=x.mask(jnp.float32).reshape(-1) if x.is_seq else None,
         )

@@ -19,3 +19,4 @@ from paddle_tpu.models.ctr import ctr_linear, ctr_wide_deep  # noqa: F401
 from paddle_tpu.models.gan import GAN, gan_conf  # noqa: F401
 from paddle_tpu.models.vae import vae_conf  # noqa: F401
 from paddle_tpu.models.mellum import mellum  # noqa: F401
+from paddle_tpu.models.kimi import kimi  # noqa: F401

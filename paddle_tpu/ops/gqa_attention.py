@@ -1,10 +1,12 @@
 """Causal grouped-query attention with an optional sliding window, never
 materialising the [T, T] scores or the KV heads repeated per query head.
 
-    q [B, T, H, D], k and v [B, T, KV, D]  ->  [B, T, H, D]
+    q [B, T, H, D], k [B, T, KV, D], v [B, T, KV, Dv]  ->  [B, T, H, Dv]
 
 Query head h attends KV head h // (H / KV); position i sees j <= i and,
-with `window`, also i - j < window. Two lowerings (`impl`):
+with `window`, also i - j < window. The value head may differ in width
+from the query/key head (latent attention: 192-wide keys, 128-wide
+values); scores are scaled by 1/sqrt(D). Two lowerings (`impl`):
 
 - "pallas": the TPU's splash-attention kernel (jax.experimental.pallas
   .ops.tpu.splash_attention), as multi-query attention over the query heads
@@ -35,10 +37,14 @@ KERNEL_BLOCK_Q = KERNEL_BLOCK_KV = 1024
 BLOCKED_BLOCK_Q = 512
 
 
-def pallas_fits(t: int, d: int) -> bool:
+def pallas_fits(t: int, d: int, dv: int = None) -> bool:
     """The kernel's tiles: the sequence in blocks of a lane multiple, the
-    head a lane multiple."""
-    return t % LANES == 0 and d % LANES == 0
+    value head a lane multiple, the query/key head a lane multiple or a
+    half lane over one (192: Mosaic pads the block's last tile itself;
+    compiled for a v5e in tests/test_tpu_compile.py)."""
+    dv = d if dv is None else dv
+    return (t % LANES == 0 and dv % LANES == 0 and d >= LANES
+            and d % (LANES // 2) == 0)
 
 
 def _block(t: int, cap: int) -> int:
@@ -82,8 +88,8 @@ def _pallas(q, k, v, window, block_q, block_kv, interpret):
     qg = (q * (1.0 / math.sqrt(d))).astype(q.dtype)
     qg = qg.transpose(0, 2, 1, 3).reshape(b, kv, g, t, d)
     kt, vt = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
-    o = jax.vmap(jax.vmap(kernel))(qg, kt, vt)        # [B, KV, G, T, D]
-    return o.reshape(b, h, t, d).transpose(0, 2, 1, 3)
+    o = jax.vmap(jax.vmap(kernel))(qg, kt, vt)        # [B, KV, G, T, Dv]
+    return o.reshape(b, h, t, v.shape[-1]).transpose(0, 2, 1, 3)
 
 
 def _blocked(q, k, v, window, block_q):
@@ -113,7 +119,7 @@ def _blocked(q, k, v, window, block_q):
         q1 = min(q0 + block_q, t)
         k0 = 0 if window is None else max(0, q0 - (window - 1))
         outs.append(one(qg[:, q0:q1], k[:, k0:q1], v[:, k0:q1], q0, k0))
-    return jnp.concatenate(outs, axis=1).reshape(b, t, h, d)
+    return jnp.concatenate(outs, axis=1).reshape(b, t, h, v.shape[-1])
 
 
 def gqa_attention(q, k, v, *, window=None, impl=None, block_q=None,
@@ -121,6 +127,7 @@ def gqa_attention(q, k, v, *, window=None, impl=None, block_q=None,
     """See the module's docstring. `block_q`/`block_kv`: tile sizes
     (defaults 1024/1024 for the kernel, 512 for the portable loop)."""
     b, t, h, d = q.shape
+    dv = v.shape[-1]
     if h % k.shape[2]:
         raise ValueError(f"{h} query heads do not divide over "
                          f"{k.shape[2]} KV heads")
@@ -128,12 +135,13 @@ def gqa_attention(q, k, v, *, window=None, impl=None, block_q=None,
         window = None
     if impl is None:
         on_tpu = jax.default_backend() == "tpu"
-        impl = "pallas" if on_tpu and pallas_fits(t, d) else "blocked"
+        impl = "pallas" if on_tpu and pallas_fits(t, d, dv) else "blocked"
     if impl == "pallas":
-        if not pallas_fits(t, d):
+        if not pallas_fits(t, d, dv):
             raise ValueError(
-                f"the attention kernel needs T and the head size in "
-                f"multiples of {LANES}; got T={t}, D={d}")
+                f"the attention kernel needs T and the value head in "
+                f"multiples of {LANES}, the query/key head in multiples of "
+                f"{LANES // 2} from {LANES}; got T={t}, D={d}, Dv={dv}")
         return _pallas(q, k, v, window, block_q or KERNEL_BLOCK_Q,
                        block_kv or KERNEL_BLOCK_KV,
                        _ops.pallas_interpret(interpret))

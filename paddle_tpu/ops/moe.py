@@ -9,8 +9,9 @@ Tokens route within fixed-size GROUPS (GShard's [G, S, ...] layout) so
 dispatch tensors stay O(N * group_size) instead of O(N^2). Aux
 load-balancing loss per GShard/Switch eq. 4.
 
-Beside it, the present-day path (`dropless_moe`): softmax-then-top-k
-routing over ALL the experts, for a layer that holds a contiguous share of
+Beside it, the present-day path (`dropless_moe`): top-k routing (softmax
+over the experts, or a sigmoid a logit with a selection bias and a scale)
+over ALL the experts, for a layer that holds a contiguous share of
 them (`held_first`, as many as its weights have), with no capacity and no
 dropped token. The (token, expert) slots are sorted by expert, held experts
 first; one grouped matrix product a projection runs over the held experts'
@@ -151,19 +152,37 @@ def moe_ffn(
 
 # ---- dropless top-k routing over a held share of the experts ----
 
-def route_topk(x, router_w, top_k: int, norm_topk: bool = True):
+def route_topk(x, router_w, top_k: int, norm_topk: bool = True, *,
+               scoring: str = "softmax", bias=None, scale: float = 1.0):
     """x [N, D], router_w [D, E] -> (weights [N, k] float32, experts
-    [N, k] int32). Logits, softmax and top-k are float32 whatever the
+    [N, k] int32). Logits, scores and top-k are float32 whatever the
     operands' dtypes: the product is taken of their float32 values at the
     highest precision (a TPU's default rounds a float32 product's operands
     to bfloat16), since a logit's last bits decide which experts a token
-    gets."""
+    gets. `scoring`: "softmax" over the experts, or "sigmoid" of each
+    logit alone. `bias` [E] is added to the scores for the CHOICE only (a
+    selection bias, which no gradient reaches): an expert's weight is its
+    score without it. `scale` multiplies the weights after `norm_topk`
+    has made them sum to 1."""
     logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    prob = jax.nn.softmax(logits, axis=-1)
-    top, idx = jax.lax.top_k(prob, top_k)
+    if scoring == "softmax":
+        score, eps = jax.nn.softmax(logits, axis=-1), None
+    elif scoring == "sigmoid":
+        # the family's own guard: k sigmoids can all be 0 in float32
+        score, eps = jax.nn.sigmoid(logits), 1e-20
+    else:
+        raise ValueError(f"unknown router scoring {scoring!r}")
+    if bias is None:
+        top, idx = jax.lax.top_k(score, top_k)
+    else:
+        _, idx = jax.lax.top_k(score + bias.astype(jnp.float32), top_k)
+        top = jnp.take_along_axis(score, idx, axis=-1)
     if norm_topk:
-        top = top / jnp.sum(top, axis=-1, keepdims=True)
+        total = jnp.sum(top, axis=-1, keepdims=True)
+        top = top / (total if eps is None else total + eps)
+    if scale != 1.0:
+        top = top * scale
     return top, idx.astype(jnp.int32)
 
 
@@ -297,6 +316,8 @@ _unsort.defvjp(_unsort_fwd, _unsort_bwd)
 
 def dropless_moe(x, router_w, w_gate, w_up, w_down, *, top_k: int,
                  held_first: int = 0, norm_topk: bool = True,
+                 scoring: str = "softmax", select_bias=None,
+                 routed_scale: float = 1.0,
                  activation=jax.nn.silu, token_mask=None, impl=None):
     """The held experts' part of a top-k mixture's result, no token dropped.
 
@@ -311,7 +332,9 @@ def dropless_moe(x, router_w, w_gate, w_up, w_down, *, top_k: int,
     n, d = x.shape
     e, eh = router_w.shape[1], w_up.shape[0]
     with jax.named_scope("moe.route"):
-        weight, expert = route_topk(x, router_w, top_k, norm_topk)
+        weight, expert = route_topk(x, router_w, top_k, norm_topk,
+                                    scoring=scoring, bias=select_bias,
+                                    scale=routed_scale)
         # held experts first: key 0 .. Eh-1; absent ones after; padding last
         key = jnp.mod(expert - held_first, e).reshape(-1)
         if token_mask is not None:

@@ -1,8 +1,9 @@
 """The benchmark's cheap tests in tier-1: `benchmarks/tests/test_harness.py`
 (files and names, the last line's keys, the no-TPU refusal, `trace_reduce`,
 the readers, a cell added as new files), `test_program_spans.py` (the
-readers of the program's spans and scopes) and `test_mellum_cell.py` (the
-cell PR 28 added, at a tiny size). They run here as they stand there, but
+readers of the program's spans and scopes), `test_mellum_cell.py` (the
+cell PR 28 added, at a tiny size) and `test_kimi_cell.py` (the cell PR 33
+added, likewise). They run here as they stand there, but
 for the two that `REPLACED` names with the reason: each fails as it stands
 since PR 28 appended the entries that ISSUE 28 named, no PR but a `benchmark`
 PR may edit those files, and so each is taken out of this module BY NAME (a
@@ -19,6 +20,8 @@ from benchmarks.tests.test_harness import *  # noqa: F401,F403
 from benchmarks.tests.test_program_spans import *  # noqa: F401,F403
 from benchmarks.tests.test_program_spans import NEW
 from benchmarks.tests.test_mellum_cell import *  # noqa: F401,F403,E402
+from benchmarks.tests.test_kimi_cell import *  # noqa: F401,F403,E402
+from benchmarks.tests.test_kimi_cell import KIMI_CELL, PR33
 
 REPLACED = {
     "test_a_token_counted_training_cell_is_new_files_and_appended_entries":
@@ -54,16 +57,22 @@ PR28 = ["mfu.tokens", "device_idle_share.tokens",
 
 
 def test_pr25s_and_pr28s_metrics_resolve_in_their_order():
+    """PR 25's metrics in their order, PR 28's twelve as one run in theirs,
+    followed by PR 33's thirteen in theirs (appended entries move nothing
+    that was there)."""
     import json
 
     bench = harness.load_benchmark()
     names = [m["name"] for m in bench["per_layer"]]
     entries = {m["name"]: m for m in bench["per_layer"]}
     assert [n for n in names if n in NEW] == NEW
-    assert names[-len(PR28):] == PR28
+    at = names.index(PR28[0])
+    assert names[at: at + len(PR28)] == PR28
+    assert names[at + len(PR28): at + len(PR28) + len(PR33)] == PR33
     for name, cell in ([(n, "resnet50.train_bs256") for n in NEW]
                        + [(n, "mellum2_12b_ep4.train_seq8192")
-                          for n in PR28]):
+                          for n in PR28]
+                       + [(n, KIMI_CELL) for n in PR33]):
         m = entries[name]
         assert m["workloads"] == [cell]
         assert (m["unit"], m["moves"]) == ("%", "train_units_per_s")
@@ -74,5 +83,7 @@ def test_pr25s_and_pr28s_metrics_resolve_in_their_order():
                             "window_s": 0.0}, data) is None
     assert {entries[n]["layer"] for n in NEW} == {
         "entry points", "device", "step program and model graph"}
-    assert {entries[n]["layer"] for n in PR28} == {
-        "entry points", "device", "step program and model graph", "kernels"}
+    for mine in (PR28, PR33):
+        assert {entries[n]["layer"] for n in mine} == {
+            "entry points", "device", "step program and model graph",
+            "kernels"}

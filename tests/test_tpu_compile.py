@@ -260,3 +260,53 @@ def test_grouped_matmul_forward_and_gradient(one_chip, monkeypatch):
     assert _has_kernel(_compile(fwd, rows, w, sizes))
     text = _compile(jax.grad(loss, argnums=(0, 1)), rows, w, sizes)
     assert text.count("tpu_custom_call") >= 2      # the rows', the weights'
+
+
+# ---- the latent-attention decoder's kernels at the widths of the cell
+# `kimi_vl_a3b_ep8.train_seq8192`: 2 rows of 8,192 positions, 16 heads with
+# 192-wide queries and keys and 128-wide values; 98,304 slots of 2,048
+# through 8 held experts of 1,408
+
+def test_latent_attention_forward_and_gradient_at_192_128(one_chip,
+                                                          monkeypatch):
+    from paddle_tpu import ops
+    from paddle_tpu.ops.gqa_attention import gqa_attention, pallas_fits
+
+    monkeypatch.setattr(ops, "pallas_interpret",
+                        lambda requested=None: False)
+    assert pallas_fits(8192, 192, 128)
+    qk = jax.ShapeDtypeStruct((2, 8192, 16, 192), jnp.bfloat16,
+                              sharding=one_chip)
+    v = jax.ShapeDtypeStruct((2, 8192, 16, 128), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def fwd(q, k, v):
+        return gqa_attention(q, k, v, impl="pallas")
+
+    def loss(q, k, v):
+        return jnp.sum(fwd(q, k, v).astype(jnp.float32))
+
+    assert _has_kernel(_compile(fwd, qk, qk, v))
+    assert jax.eval_shape(fwd, qk, qk, v).shape == (2, 8192, 16, 128)
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), qk, qk, v)
+    assert text.count("tpu_custom_call") >= 3      # forward, dq, dk and dv
+
+
+def test_grouped_matmul_at_the_latent_decoders_widths(one_chip, monkeypatch):
+    from paddle_tpu import ops
+    from paddle_tpu.ops.moe import grouped_matmul
+
+    monkeypatch.setattr(ops, "pallas_interpret",
+                        lambda requested=None: False)
+    sizes = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip)
+    for k, n in ((2048, 1408), (1408, 2048)):      # up and gate; down
+        rows = jax.ShapeDtypeStruct((98304, k), jnp.bfloat16,
+                                    sharding=one_chip)
+        w = jax.ShapeDtypeStruct((8, k, n), jnp.bfloat16, sharding=one_chip)
+
+        def loss(rows, w, sizes):
+            return jnp.sum(grouped_matmul(rows, w, sizes, impl="pallas")
+                           .astype(jnp.float32))
+
+        text = _compile(jax.grad(loss, argnums=(0, 1)), rows, w, sizes)
+        assert text.count("tpu_custom_call") >= 2  # the rows', the weights'
