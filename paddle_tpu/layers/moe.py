@@ -10,14 +10,17 @@ HOLDS a contiguous share (`held`: first index and count, as one chip of
 an expert-parallel layer does), gated
 (SiLU) experts, renormalised weights, and no dropped token whatever the
 imbalance (ops/moe.py `dropless_moe`: a sort, grouped matrix products, a
-weighted gather). It computes its own experts' part of the result; what
-absent experts would add is left out, and no code stands in for the
-exchange. The router's weights stay the float32 masters under the bfloat16
+weighted sum a token, each pass no longer than the slots held). It computes
+its own experts' part of the result; what absent experts would add is left
+out, and no code stands in for the exchange. The router's weights stay the float32 masters under the bfloat16
 policy (`float32_params`): its logits are a float32 product. Its extra
 output `<name>@stats` carries the routing counts the trainer publishes at
-its fence (`moe.slots`, `moe.slots_here`, `moe.load_max_over_mean`); there
-is no count of dropped slots, because the row buffer is all the slots and
-nothing on this path can drop one.
+its fence (`moe.slots`, `moe.slots_here`, `moe.load_max_over_mean`, and
+`moe.rows_moved`: the rows the dispatch moved, the held slots rounded up to
+a chunk; over `moe.slots` it is the part of the buffer that was worked, and
+1.0 means the held count saved nothing); there is no count of dropped slots,
+because the row buffer is all the slots and nothing on this path can drop
+one.
 
 Without `top_k` it is the older Switch-style layer that
 tests/test_pipeline_moe.py drives: top-1, a fixed capacity with tokens
@@ -31,6 +34,7 @@ place on the mesh model axis for EP.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from paddle_tpu.core.arg import Arg
@@ -117,7 +121,7 @@ class MoELayer(Layer):
 
     def extra_output_specs(self):
         if self.dropless:
-            return {f"{self.name}@stats": Spec(dim=(3,))}
+            return {f"{self.name}@stats": Spec(dim=(4,))}
         return {f"{self.name}@aux": Spec(dim=(1,))}
 
     @property
@@ -127,16 +131,21 @@ class MoELayer(Layer):
         return f"{self.name}@stats" if self.dropless else None
 
     def publish_stats(self, values, registry) -> None:
-        slots, here, load = (float(v) for v in values)
+        slots, here, load, moved = (float(v) for v in values)
         registry.counter("moe.slots").inc(slots, layer=self.name)
         registry.counter("moe.slots_here").inc(here, layer=self.name)
+        registry.counter("moe.rows_moved").inc(moved, layer=self.name)
         registry.gauge("moe.load_max_over_mean").set(load, layer=self.name)
 
     def _forward_dropless(self, params, x):
         a = self.conf.attrs
         v = x.value
         flat = v.reshape(-1, v.shape[-1])
-        y, stats = moe_ops.dropless_moe(
+        # one trace serves the layers of one shape (a decoder's are alike):
+        # jit keeps it by the function and these static arguments
+        y, stats = jax.jit(moe_ops.dropless_moe, static_argnames=(
+            "top_k", "held_first", "norm_topk", "scoring", "routed_scale",
+            "activation"))(
             flat, params["router"], params["w_gate"], params["w_up"],
             params["w_down"], top_k=a["top_k"], held_first=self._held()[0],
             norm_topk=a.get("norm_topk", True),
@@ -147,7 +156,7 @@ class MoELayer(Layer):
             token_mask=x.mask(jnp.float32).reshape(-1) if x.is_seq else None,
         )
         self._extra_outs = {
-            f"{self.name}@stats": Arg(value=stats.reshape(1, 3))
+            f"{self.name}@stats": Arg(value=stats.reshape(1, 4))
         }
         return Arg(value=y.reshape(v.shape), seq_lens=x.seq_lens)
 

@@ -16,14 +16,20 @@ them (`held_first`, as many as its weights have), with no capacity and no
 dropped token. The (token, expert) slots are sorted by expert, held experts
 first; one grouped matrix product a projection runs over the held experts'
 row groups (`grouped_matmul`: the TPU's megablox kernel, `lax.ragged_dot`
-elsewhere); a weighted gather brings the rows back. No tensor grows with
-experts x capacity: the row buffer is tokens x top-k, the worst case, and
-the kernel works only through the real group sizes.
+elsewhere); each token then sums its held slots' rows, weighted. No tensor
+grows with experts x capacity: the row buffer is tokens x top-k, the worst
+case, and NOTHING works through more of it than the slots held: the kernel
+goes by the real group sizes, and every other pass (the dispatch's gather,
+the gated activation, the combine, and the backward of each) runs chunk by
+chunk of `CHUNK` rows under a trip count taken from the held count, which
+is data: shapes are fixed and nothing retraces. Rows past the held ones are
+never written and never read.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -271,47 +277,232 @@ def grouped_matmul(lhs, rhs, group_sizes, impl=None, interpret=None):
     raise ValueError(f"unknown grouped matmul impl {impl!r}")
 
 
+# Rows a pass over the row buffer, or over the tokens, takes at a time
+# (fitted on the chip on both decoder cells: PERF.md section 6, PR 34). A
+# pass costs at most a chunk more than the held rows on the expert-major
+# side, and top_k chunks more on the token-major side.
+CHUNK = 512
+
+
+def _chunk_of(total: int) -> int:
+    """The rows a trip takes of `total`: the largest divisor of it that is
+    not over CHUNK, so that a buffer is whole chunks and a trip reads and
+    writes one by its index (an update at a row offset the compiler cannot
+    see to be aligned is a slower copy)."""
+    return next(c for c in range(min(CHUNK, total), 0, -1) if total % c == 0)
+
+
+def _trips(upto, total: int):
+    chunk = _chunk_of(total)
+    return (upto + chunk - 1) // chunk
+
+
+def _chunked(a, axis=0):
+    """a [.., total, ..] -> [.., total / chunk, chunk, ..] along `axis`."""
+    chunk = _chunk_of(a.shape[axis])
+    return a.reshape(a.shape[:axis] + (a.shape[axis] // chunk, chunk)
+                     + a.shape[axis + 1:])
+
+
+def _at(a, i, axis=0):
+    """Chunk `i` of a chunked array."""
+    return jax.lax.dynamic_index_in_dim(a, i, axis, keepdims=False)
+
+
+def _put(a, chunk, i):
+    return jax.lax.dynamic_update_index_in_dim(a, chunk, i, 0)
+
+
+def _chunks(upto, total: int, body, carry):
+    """carry = body(i, carry) for the chunks i that cover rows 0 .. `upto`
+    of `total`; `upto` is a device scalar: the trip count is data, and no
+    shape. The loop has no reverse-mode rule: every caller is one side of a
+    `custom_vjp`."""
+    return jax.lax.fori_loop(0, _trips(upto, total), body, carry)
+
+
+class _ByToken(NamedTuple):
+    """The held slots from the tokens' side, tokens ordered by how many of
+    their slots are held, most first (position p), a token's held slots in
+    slot order (column c): the tokens with more than c held slots are the
+    first of that order, so a pass over column c is a pass over a prefix."""
+    row: jax.Array     # [k, N] sorted row of p's c-th held slot
+    col: jax.Array     # [k, N] which of the token's k choices that slot is
+    held: jax.Array    # [N] how many p holds, descending
+    tokens: jax.Array  # [N] p -> token
+    rank: jax.Array    # [N] token -> p
+
+
+def _by_token(inverse, here, n: int) -> _ByToken:
+    """Sorts and one-hots over a token's k slots, no gather or scatter of
+    N * k scalars: one such costs the chip what nine sorts of them do."""
+    k = inverse.shape[0] // n
+    rows = inverse.reshape(n, k)
+    on = rows < here
+    held = jnp.sum(on, axis=1, dtype=jnp.int32)
+    nth = jnp.cumsum(on, axis=1, dtype=jnp.int32) - 1
+    choice = jnp.arange(k, dtype=jnp.int32)
+    pick = on[:, :, None] & (nth[:, :, None] == choice)     # [N, slot, c]
+    col = jnp.sum(jnp.where(pick, choice[None, :, None], 0), axis=1)
+    row = jnp.sum(jnp.where(pick, rows[:, :, None], 0), axis=1)
+    tokens = jnp.argsort(-held, stable=True).astype(jnp.int32)
+    return _ByToken(row[tokens].T, col[tokens].T, held[tokens], tokens,
+                    jnp.argsort(tokens).astype(jnp.int32))
+
+
+def _sum_by_token(sources, weight, plan: _ByToken):
+    """y[t] = the sum over token t's held slots, in slot order and float32,
+    of weight[slot] (1 without `weight`) times the sources' rows of that
+    slot; `sources`: row buffers [N * k, D] in sorted order. Column by
+    column over the prefix of tokens that have such a slot: the rows
+    gathered are the held slots', plus at most a chunk a column."""
+    n, (k, _) = plan.held.shape[0], plan.row.shape
+    d, dtype = sources[0].shape[1], sources[0].dtype
+    w = None
+    if weight is not None:                   # [k, N]: weight[token, col]
+        w = jnp.sum(jnp.where(
+            plan.col[:, :, None] == jnp.arange(k, dtype=jnp.int32),
+            weight[plan.tokens][None], 0), axis=2)
+
+    held_of, row_of = _chunked(plan.held), _chunked(plan.row, 1)
+    scale_of = None if w is None else _chunked(w, 1)
+
+    def tokens(i, y):
+        held, row = _at(held_of, i), _at(row_of, i, 1)
+        scale = None if w is None else _at(scale_of, i, 1)
+
+        def column(c, acc):
+            part = sum(s[row[c]].astype(jnp.float32) for s in sources)
+            if scale is not None:
+                part = part * scale[c][:, None]
+            # a row of a slot not held is not defined: chosen, not scaled
+            return acc + jnp.where((c < held)[:, None], part, 0)
+
+        acc = jax.lax.fori_loop(0, held[0], column,
+                                jnp.zeros((held.shape[0], d), jnp.float32))
+        return _put(y, acc.astype(dtype), i)
+
+    y = _chunks(jnp.sum(plan.held > 0), n, tokens,
+                _chunked(jnp.zeros((n, d), dtype)))
+    return y.reshape(n, d)[plan.rank]
+
+
 @jax.custom_vjp
-def _take_tokens(x, order, inverse, here):
-    """x [N, D] -> the row of each sorted slot's token [N * k, D]. `here`:
-    how many of the sorted slots (the first) are on experts held."""
-    return x[order // (order.shape[0] // x.shape[0])]
+def _take_tokens(x, order, here, plan):
+    """x [N, D] -> the row of each sorted slot's token [N * k, D], for the
+    first `here` sorted slots (those on experts held) and the rest of their
+    last chunk; rows past that are not defined. Twice over, for the two
+    products that read it: their cotangents then come back apart, and
+    nothing adds them over the whole buffer."""
+    k = order.shape[0] // x.shape[0]
+    token_of = _chunked(order // k)
+
+    def body(i, rows):
+        return _put(rows, x[_at(token_of, i)], i)
+
+    rows = _chunks(here, order.shape[0], body, _chunked(
+        jax.lax.empty((order.shape[0], x.shape[1]), x.dtype)))
+    rows = rows.reshape(order.shape[0], x.shape[1])
+    return rows, rows
 
 
-def _take_tokens_fwd(x, order, inverse, here):
-    return _take_tokens(x, order, inverse, here), (inverse, here, x.shape[0])
+def _take_tokens_fwd(x, order, here, plan):
+    return _take_tokens(x, order, here, plan), plan
 
 
-def _take_tokens_bwd(res, g):
-    inverse, here, n = res
+def _take_tokens_bwd(plan, gs):
     # a gather and a sum, not a scatter-add; the rows of slots no held
     # expert owns were never defined (grouped_matmul) and are left out
-    g = jnp.where((inverse < here)[:, None], g[inverse], 0)
-    return g.reshape(n, -1, g.shape[-1]).sum(axis=1), None, None, None
+    return _sum_by_token(gs, None, plan), None, None, None
 
 
 _take_tokens.defvjp(_take_tokens_fwd, _take_tokens_bwd)
 
 
 @jax.custom_vjp
-def _unsort(y, order, inverse, here):
-    """y [N * k, D] in sorted order -> slot order, the undefined rows of
-    slots no held expert owns as zeros."""
-    return jnp.where((inverse < here)[:, None], y[inverse], 0)
+def _combine(out, weight, order, inverse, here, plan):
+    """out [N * k, D] in sorted order, weight [N, k] -> y [N, D]: a token's
+    held slots' rows, weighted and summed. No [N, k, D] array is made on
+    the way out or back, and the residuals are arguments: a recomputed
+    forward of this op feeds nothing."""
+    return _sum_by_token((out,), weight, plan)
 
 
-def _unsort_fwd(y, order, inverse, here):
-    return _unsort(y, order, inverse, here), (order, here)
+def _combine_fwd(out, weight, order, inverse, here, plan):
+    return (_combine(out, weight, order, inverse, here, plan),
+            (out, weight, order, inverse, here))
 
 
-def _unsort_bwd(res, g):
-    order, here = res
-    # rows past `here` get what their slots were handed: zeros times the
-    # weights' cotangent, defined, and never read by a kernel
-    return g[order], None, None, None
+def _combine_bwd(res, g):
+    out, weight, order, inverse, here = res
+    n, k = weight.shape
+    # the weights in sorted order, and below the cotangents back in slot
+    # order: a permutation is applied by a sort on its inverse
+    _, sorted_w = jax.lax.sort((inverse, weight.reshape(-1)), num_keys=1)
+    token_of, weight_of = _chunked(order // k), _chunked(sorted_w)
+
+    def body(i, carry):
+        d_out, d_w = carry
+        got = g[_at(token_of, i)].astype(jnp.float32)
+        mine = jnp.sum(got * _at(d_out, i).astype(jnp.float32), axis=1)
+        # the chunk is read before it is written: said, or the compiler
+        # may order the write first and copy the whole buffer to read from
+        mine, d_out = jax.lax.optimization_barrier((mine, d_out))
+        d_out = _put(d_out, (_at(weight_of, i)[:, None] * got).astype(
+            out.dtype), i)
+        return d_out, _put(d_w, mine, i)
+
+    # a chunk of `out` is read, then its cotangent takes its place: nothing
+    # else reads `out` after this, and the step holds a row buffer fewer
+    d_out, d_w = _chunks(here, n * k, body, (
+        _chunked(out), _chunked(jax.lax.empty((n * k,), jnp.float32))))
+    d_out, d_w = d_out.reshape(out.shape), d_w.reshape(n * k)
+    # rows past `here` hold what lay there: chosen away, slot by slot
+    _, d_w = jax.lax.sort((order, d_w), num_keys=1)
+    d_weight = jnp.where(inverse < here, d_w, 0).reshape(n, k)
+    return d_out, d_weight.astype(weight.dtype), None, None, None, None
 
 
-_unsort.defvjp(_unsort_fwd, _unsort_bwd)
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def _gate(activation, gate, up):
+    return activation(gate) * up
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _gated(activation, gate, up, here):
+    """activation(gate) * up over the first `here` rows of [N * k, H]."""
+    gate_of, up_of = _chunked(gate), _chunked(up)
+
+    def body(i, hidden):
+        return _put(hidden, _gate(activation, _at(gate_of, i), _at(up_of, i)),
+                    i)
+
+    return _chunks(here, gate.shape[0], body, _chunked(
+        jax.lax.empty(gate.shape, gate.dtype))).reshape(gate.shape)
+
+
+def _gated_fwd(activation, gate, up, here):
+    return _gated(activation, gate, up, here), (gate, up, here)
+
+
+def _gated_bwd(activation, res, g):
+    gate, up, here = res
+    g_of = _chunked(g)
+
+    def body(i, carry):
+        _, pull = jax.vjp(functools.partial(_gate, activation),
+                          *(_at(a, i) for a in carry))
+        return tuple(_put(a, d, i) for a, d in zip(carry, pull(_at(g_of, i))))
+
+    # each cotangent takes its operand's place, chunk by chunk
+    d_gate, d_up = _chunks(here, gate.shape[0], body,
+                           (_chunked(gate), _chunked(up)))
+    return d_gate.reshape(gate.shape), d_up.reshape(up.shape), None
+
+
+_gated.defvjp(_gated_fwd, _gated_bwd)
 
 
 def dropless_moe(x, router_w, w_gate, w_up, w_down, *, top_k: int,
@@ -325,10 +516,13 @@ def dropless_moe(x, router_w, w_gate, w_up, w_down, *, top_k: int,
     and w_down [Eh, H, D] of the Eh experts held here, experts
     `held_first` .. `held_first + Eh`; an expert is `(activation(x w_gate)
     * (x w_up)) w_down`. `token_mask` [N] (1 = real) keeps padding out of
-    every expert. -> (y [N, D], stats [3] float32: slots routed, slots on
-    experts held here, the fullest held expert's load over the mean). The
-    row buffer is all N * k slots, so there is nothing to drop and no
-    count of it."""
+    every expert. -> (y [N, D], stats [4] float32: slots routed, slots on
+    experts held here, the fullest held expert's load over the mean, the
+    rows the dispatch moved). The row buffer is all N * k slots, so there
+    is nothing to drop and no count of it; every pass over it (dispatch,
+    the gated activation, combine, and their backward passes) stops a chunk
+    past the `here` rows of slots held, as the kernels do: when every slot
+    is held every chunk runs."""
     n, d = x.shape
     e, eh = router_w.shape[1], w_up.shape[0]
     with jax.named_scope("moe.route"):
@@ -341,25 +535,28 @@ def dropless_moe(x, router_w, w_gate, w_up, w_down, *, top_k: int,
             real = jnp.repeat(token_mask > 0, top_k)
             key = jnp.where(real, key, e)
         order = jnp.argsort(key, stable=True).astype(jnp.int32)
-        inverse = jnp.zeros_like(order).at[order].set(
-            jnp.arange(order.shape[0], dtype=jnp.int32))
-        counts = jnp.bincount(key, length=e + 1).astype(jnp.int32)
+        # by a sort and a one-hot: a scatter of N * k scalars (into the
+        # inverse, or into the counts) costs the chip nine such sorts
+        inverse = jnp.argsort(order).astype(jnp.int32)
+        counts = jnp.sum(key[:, None] == jnp.arange(e + 1, dtype=key.dtype),
+                         axis=0, dtype=jnp.int32)
         group_sizes = counts[:eh]
         here = jnp.sum(group_sizes)
+        plan = _by_token(inverse, here, n)
     with jax.named_scope("moe.dispatch"):
-        rows = _take_tokens(x, order, inverse, here)       # [N * k, D]
+        rows, rows_again = _take_tokens(x, order, here, plan)  # [N * k, D]
     with jax.named_scope("moe.experts"):
         up = grouped_matmul(rows, w_up, group_sizes, impl)
-        hidden = activation(
-            grouped_matmul(rows, w_gate, group_sizes, impl)) * up
+        hidden = _gated(activation, grouped_matmul(
+            rows_again, w_gate, group_sizes, impl), up, here)
         out = grouped_matmul(hidden.astype(x.dtype), w_down, group_sizes,
                              impl)
     with jax.named_scope("moe.combine"):
-        slots = _unsort(out, order, inverse, here).reshape(n, top_k, d)
-        y = jnp.sum(slots.astype(jnp.float32) * weight[..., None], axis=1)
+        y = _combine(out, weight, order, inverse, here, plan)
     real_slots = jnp.sum(counts[:e])
     stats = jnp.stack([
         real_slots, here,
         jnp.max(group_sizes) * eh / jnp.maximum(here, 1),
+        _trips(here, n * top_k) * _chunk_of(n * top_k),
     ]).astype(jnp.float32)
     return y.astype(x.dtype), stats

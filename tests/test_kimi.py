@@ -156,7 +156,7 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
         if once is None:
             once = same
         np.testing.assert_array_equal(same, once)   # every chip alike
-        slots, on_chip, _ = (float(s) for s in outs["l0_moe@stats"].value[0])
+        slots, on_chip = (float(s) for s in outs["l0_moe@stats"].value[0, :2])
         assert slots == 128.0
         here += on_chip
         # what the share's own reference gives is the share's whole layer

@@ -253,8 +253,8 @@ def test_dropless_routing_under_a_planted_imbalance():
     x = jnp.abs(x)                       # so that one column can win always
     wr = wr.at[:, 3].set(2.0)
     y, stats = M.dropless_moe(x, wr, wg, wu, wd, top_k=2)
-    slots, here, load = (float(s) for s in stats)
-    assert (slots, here) == (256.0, 256.0)
+    slots, here, load, moved = (float(s) for s in stats)
+    assert (slots, here, moved) == (256.0, 256.0, 256.0)
     _, chosen = M.route_topk(x, wr, 2)
     counts = np.bincount(np.asarray(chosen).reshape(-1), minlength=8)
     assert counts[3] == 128 and load == pytest.approx(128 * 8 / 256)
@@ -263,7 +263,7 @@ def test_dropless_routing_under_a_planted_imbalance():
     # a layer that holds expert 3 alone still computes all its 128 slots
     y3, stats3 = M.dropless_moe(x, wr, wg[3:4], wu[3:4], wd[3:4], top_k=2,
                                 held_first=3)
-    assert [float(s) for s in stats3] == [256.0, 128.0, 1.0]
+    assert [float(s) for s in stats3] == [256.0, 128.0, 1.0, 256.0]
     cfg3 = dict(cfg, num_experts=1, experts_held_first=3)
     np.testing.assert_allclose(
         y3, _ref_moe(cfg3, x, wr, wg[3:4], wu[3:4], wd[3:4]), atol=2e-6)
@@ -301,7 +301,7 @@ def test_the_shares_parts_add_up_to_the_uncut_layer():
          "_m.w_down": wd[2:4]}
     outs, _ = net.forward(p, {"x": Arg(value=x)})
     np.testing.assert_allclose(outs["m"].value, parts[1], atol=1e-6)
-    assert outs["m@stats"].value.shape == (1, 3)
+    assert outs["m@stats"].value.shape == (1, 4)
     assert list(net.stat_outputs) == ["m@stats"]
     assert net.stat_outputs["m@stats"] is net.layers["m"]
 
@@ -378,6 +378,211 @@ def test_undefined_rows_of_the_row_buffer_never_reach_a_result():
     for a, b in zip(g, clean_g):
         assert bool(jnp.all(jnp.isfinite(a)))
         np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-7)
+
+
+# ---- the row buffer's passes stop at the held rows ----
+
+SMALL_CHUNK = 32
+# sorted slots on experts held, of 64 tokens x 4: none, a few, whole chunks
+# to the row, a row over, and every slot
+HELD_ROWS = [0, 5, SMALL_CHUNK, SMALL_CHUNK + 1, 3 * SMALL_CHUNK, 256]
+
+
+def _sorted_slots(n=64, k=4, d=16, seed=12):
+    """A row buffer's index vectors as `dropless_moe` makes them, from a
+    random order of the slots, and values to move."""
+    ks = jax.random.split(jax.random.key(seed), 5)
+    order = jax.random.permutation(ks[0], n * k).astype(jnp.int32)
+    inverse = jnp.zeros_like(order).at[order].set(
+        jnp.arange(n * k, dtype=jnp.int32))
+    return (order, inverse, jax.random.normal(ks[1], (n, d)),
+            jax.random.normal(ks[2], (n * k, d)),
+            jax.random.normal(ks[3], (n * k, d)),
+            jax.random.uniform(ks[4], (n, k)))
+
+
+def _plain_unsort(rows, inverse, here):
+    """The masked gather: a sorted buffer's rows in slot order, the rows
+    of slots not held as zeros."""
+    return jnp.where((inverse < here)[:, None], rows[inverse], 0)
+
+
+def _plain_combine(out, weight, inverse, here):
+    n, k = weight.shape
+    slots = _plain_unsort(out, inverse, here).reshape(n, k, -1)
+    return jnp.sum(slots * weight[..., None], axis=1)
+
+
+@pytest.mark.parametrize("here", HELD_ROWS)
+def test_dispatch_moves_the_held_rows_out_and_sums_them_back(
+        monkeypatch, here):
+    monkeypatch.setattr(M, "CHUNK", SMALL_CHUNK)
+    order, inverse, x, g1, g2, _ = _sorted_slots()
+    n, k = x.shape[0], order.shape[0] // x.shape[0]
+    plan = M._by_token(inverse, here, n)
+    (rows, again), pull = jax.vjp(
+        lambda x: M._take_tokens(x, order, here, plan), x)
+    assert again is rows or bool(jnp.all(again == rows))
+    np.testing.assert_array_equal(rows[:here], x[order // k][:here])
+    # back: each token's held slots' rows of both cotangents, and no other
+    want = _plain_unsort(g1 + g2, inverse, here).reshape(n, k, -1).sum(1)
+    np.testing.assert_allclose(pull((g1, g2))[0], want, atol=2e-6)
+
+
+@pytest.mark.parametrize("here", HELD_ROWS)
+def test_combine_is_the_masked_gather_and_weighted_sum(monkeypatch, here):
+    monkeypatch.setattr(M, "CHUNK", SMALL_CHUNK)
+    order, inverse, _, out, _, weight = _sorted_slots()
+    n = weight.shape[0]
+    plan = M._by_token(inverse, here, n)
+    # rows past `here` are not defined: NaN there must reach nothing
+    out = out.at[here:].set(jnp.nan)
+
+    def mine(out, weight):
+        return M._combine(out, weight, order, inverse, here, plan)
+
+    def plain(out, weight):
+        return _plain_combine(out, weight, inverse, here)
+
+    y = mine(out, weight)
+    np.testing.assert_allclose(y, plain(out, weight), atol=2e-6)
+    g = jax.random.normal(jax.random.key(13), y.shape)
+    (d_out, d_w), (p_out, p_w) = (
+        jax.vjp(f, out, weight)[1](g) for f in (mine, plain))
+    np.testing.assert_allclose(d_out[:here], p_out[:here], atol=2e-6)
+    assert bool(jnp.all(jnp.isfinite(d_w)))
+    np.testing.assert_allclose(d_w, p_w, atol=1e-5)
+
+
+@pytest.mark.parametrize("here", HELD_ROWS)
+def test_the_gated_activation_over_the_held_rows(monkeypatch, here):
+    monkeypatch.setattr(M, "CHUNK", SMALL_CHUNK)
+    _, _, _, gate, up, _ = _sorted_slots()
+    g = jax.random.normal(jax.random.key(14), gate.shape)
+    hidden, pull = jax.vjp(
+        lambda gate, up: M._gated(jax.nn.silu, gate, up, here), gate, up)
+    want, plain_pull = jax.vjp(lambda gate, up: jax.nn.silu(gate) * up,
+                               gate, up)
+    np.testing.assert_allclose(hidden[:here], want[:here], atol=1e-6)
+    for a, b in zip(pull(g), plain_pull(g)):
+        np.testing.assert_allclose(a[:here], b[:here], atol=1e-6)
+
+
+def test_the_tokens_side_index_puts_held_slots_first_and_full_tokens_first():
+    order, inverse, *_ = _sorted_slots()
+    here, n, k = 70, 64, 4
+    plan = M._by_token(inverse, here, n)
+    on = np.asarray(inverse < here).reshape(n, k)
+    held = on.sum(1)
+    assert list(plan.held) == sorted(held, reverse=True)
+    assert sorted(np.asarray(plan.rank)) == list(range(n))
+    for t in range(n):
+        p = int(plan.rank[t])
+        assert int(plan.held[p]) == held[t]
+        assert int(plan.tokens[p]) == t
+        mine = [int(j) for j in plan.col[:held[t], p]]
+        assert mine == [j for j in range(k) if on[t, j]]
+        assert [int(r) for r in plan.row[:held[t], p]] == [
+            int(inverse[t * k + j]) for j in mine]
+    # tokens that hold as many keep their order: the sort is stable
+    for c in range(k + 1):
+        ranks = [int(plan.rank[t]) for t in range(n) if held[t] == c]
+        assert ranks == sorted(ranks)
+
+
+def _plain_dropless(x, wr, wg, wu, wd, *, top_k, held_first=0,
+                    token_mask=None):
+    """The layer by the formulas it had before its passes were cut to the
+    held rows (ISSUE 34): gathers and sums over all N * k slots, plain
+    autodiff, `ragged_dot`."""
+    n, e, eh = x.shape[0], wr.shape[1], wu.shape[0]
+    weight, expert = M.route_topk(x, wr, top_k)
+    key = jnp.mod(expert - held_first, e).reshape(-1)
+    if token_mask is not None:
+        key = jnp.where(jnp.repeat(token_mask > 0, top_k), key, e)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    inverse = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.shape[0], dtype=jnp.int32))
+    sizes = jnp.bincount(key, length=e + 1).astype(jnp.int32)[:eh]
+    rows = x[order // top_k]
+    hidden = jax.nn.silu(jax.lax.ragged_dot(rows, wg, sizes)) * (
+        jax.lax.ragged_dot(rows, wu, sizes))
+    out = jax.lax.ragged_dot(hidden, wd, sizes)
+    return _plain_combine(out, weight, inverse, jnp.sum(sizes)), None
+
+
+@pytest.mark.parametrize("first,held,masked,rows,tokens", [
+    (5, 1, False, 0, 128), (2, 2, False, None, 128), (0, 3, True, None, 128),
+    (0, 8, False, 256, 128), (0, 8, True, None, 128),
+    (2, 2, False, None, 100)],
+    ids=["none-held", "a-few", "a-few-masked", "all-held", "all-masked",
+         "chunks-of-25"])
+def test_the_layer_at_every_held_share_agrees_with_the_plain_formulas(
+        monkeypatch, first, held, masked, rows, tokens):
+    """`tokens` 100: 200 slots are no multiple of 32, and a trip takes the
+    largest divisor under it, 25 rows."""
+    monkeypatch.setattr(M, "CHUNK", SMALL_CHUNK)
+    x, wr, wg, wu, wd = _moe_weights()
+    x = x[:tokens]
+    if rows == 0:                        # expert 5 is nobody's choice
+        x, wr = jnp.abs(x) + 0.1, wr.at[:, 5].set(-10.0)
+    wg, wu, wd = (w[first:first + held] for w in (wg, wu, wd))
+    mask = (jnp.arange(128) % 5 != 0).astype(jnp.float32) if masked else None
+    chunk = M._chunk_of(2 * tokens)
+    assert chunk == (SMALL_CHUNK if tokens == 128 else 25)
+
+    def run(layer):
+        def f(*a):
+            return layer(*a, top_k=2, held_first=first, token_mask=mask)
+        return f(x, wr, wg, wu, wd), jax.grad(
+            lambda *a: jnp.sum(jnp.sin(f(*a)[0])), (0, 1, 2, 3, 4))(
+                x, wr, wg, wu, wd)
+
+    ((y, stats), g), ((want, _), want_g) = (
+        run(M.dropless_moe), run(_plain_dropless))
+    slots, here, _, moved = (float(s) for s in stats)
+    # 26 of 128 tokens padded
+    assert slots == (204.0 if masked else 2.0 * tokens)
+    if rows is not None:
+        assert here == rows
+    assert here <= moved <= min(here + (2 + 1) * chunk, 2 * tokens)
+    assert moved % chunk == 0 and (here > 0 or moved == 0)
+    np.testing.assert_allclose(y, want, atol=2e-6)
+    for a, b in zip(g, want_g):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=2e-6)
+
+
+def test_no_pass_of_the_step_is_as_long_as_the_row_buffer():
+    """What keeps the buffer-long passes from coming back unnoticed: in the
+    whole step (loss and gradients, recomputation on) no float32 [N, k, D]
+    array, and outside the chunk loops no gather that gives N * k rows."""
+    cfg = tiny_cfg(experts_held_first=2, num_experts=4)
+    net = Network(mellum(cfg))
+    p = RT.init_params(R.param_spec(cfg), 7)
+    feed, _ = batch(cfg)
+    n, k, d = 2 * 32, cfg["num_experts_per_tok"], cfg["hidden_size"]
+    traced = jax.make_jaxpr(jax.grad(
+        lambda p: net.loss_fn(p, feed, train=True)[0]))(p)
+    found = {"loops": 0, "row gathers in loops": 0}
+
+    def walk(jaxpr, in_loop):
+        for eq in jaxpr.eqns:
+            for v in eq.outvars:
+                shape, dtype = v.aval.shape, v.aval.dtype
+                assert not (shape == (n, k, d) and dtype == jnp.float32), eq
+                if (eq.primitive.name == "gather" and len(shape) == 2
+                        and shape[1] == d):
+                    assert in_loop or shape[0] < n * k, eq
+                    found["row gathers in loops"] += in_loop
+            loop = eq.primitive.name == "while"
+            found["loops"] += loop
+            for sub in jax.core.jaxprs_in_params(eq.params):
+                walk(sub, in_loop or loop)
+
+    walk(traced.jaxpr, False)
+    # 4 layers: the gathers of the dispatch (out, recomputed, back twice)
+    # and of the combine (out, back) are all in loops
+    assert found["row gathers in loops"] >= 4 * 5 and found["loops"] >= 4 * 9
 
 
 def test_under_the_bfloat16_policy_the_router_and_the_cost_stay_float32(
@@ -513,8 +718,11 @@ def test_trains_through_sgd_train_and_publishes_its_counters():
         assert reg.counter("moe.slots").get(layer=layer) == fenced * 128
         here = reg.counter("moe.slots_here").get(layer=layer)
         assert 0 < here < fenced * 128
+        moved = reg.counter("moe.rows_moved").get(layer=layer)
+        assert here <= moved <= fenced * 128     # a chunk (of 128) a fence
         assert reg.gauge("moe.load_max_over_mean").get(layer=layer) >= 1.0
     # what `python -m paddle_tpu metrics` prints
     text = reg.render_text()
-    for name in ("moe.slots", "moe.slots_here", "moe.load_max_over_mean"):
+    for name in ("moe.slots", "moe.slots_here", "moe.rows_moved",
+                 "moe.load_max_over_mean"):
         assert name in text

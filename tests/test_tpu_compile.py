@@ -262,6 +262,46 @@ def test_grouped_matmul_forward_and_gradient(one_chip, monkeypatch):
     assert text.count("tpu_custom_call") >= 2      # the rows', the weights'
 
 
+def test_the_expert_layers_passes_run_in_place_chunk_by_chunk(one_chip,
+                                                              monkeypatch):
+    """The whole layer, out and back under recomputation, at the widths of
+    `mellum2_12b_ep4.train_seq8192`: every pass over the row buffer is a
+    loop over chunks that writes where it stands. A chunk loop that the
+    compiler cannot run in place copies its whole buffer a trip (seen once,
+    ISSUE 34: the write ordered before the read of the same chunk)."""
+    import re
+
+    from paddle_tpu import ops
+    from paddle_tpu.ops import moe
+
+    monkeypatch.setattr(ops, "pallas_interpret",
+                        lambda requested=None: False)
+    n, d, e, eh, h, k = 16384, 2304, 64, 16, 896, 8
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    @jax.checkpoint
+    def block(x, wr, wg, wu, wd):
+        return x + moe.dropless_moe(x, wr, wg, wu, wd, top_k=k,
+                                    impl="pallas")[0]
+
+    def loss(*a):
+        return jnp.sum(block(*a).astype(jnp.float32) ** 2)
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
+                    shape((n, d)), shape((d, e), jnp.float32),
+                    shape((eh, d, h)), shape((eh, d, h)), shape((eh, h, d)))
+    assert text.count("tpu_custom_call") >= 9       # 3 products x 3 kernels
+    assert len(re.findall(r" while\(", text)) >= 9
+    chunk = moe._chunk_of(n * k)
+    long = rf"= bf16\[(?:{n * k}|{n * k // chunk},{chunk}),\d+\]\S* "
+    assert not re.findall(long + r"copy\(", text)
+    # what makes a whole buffer is a kernel, a loop's update or a loop
+    for line in re.findall(long + r"fusion\(.*", text):
+        assert "dynamic_update_slice" in line or "dynamic-update" in line, line
+
+
 # ---- the latent-attention decoder's kernels at the widths of the cell
 # `kimi_vl_a3b_ep8.train_seq8192`: 2 rows of 8,192 positions, 16 heads with
 # 192-wide queries and keys and 128-wide values; 98,304 slots of 2,048
