@@ -1,14 +1,16 @@
 """The layers of a present-day pre-norm decoder block, beside the `moe`
-layer (layers/moe.py): `rms_norm`, `gqa_attention`, `mla_attention`,
-`gated_mlp`, `lm_head_cost`.
+layer (layers/moe.py): `rms_norm`, `layer_norm`, `gqa_attention`,
+`mla_attention`, `diff_attention`, `mamba`, `gated_mlp`, `lm_head_cost`.
 
-`models/mellum.py` and `models/kimi.py` build decoders from them through
-the DSL; their parameter names (`_<layer>.w0`, `.wq` ...) are what a plain
-reference's `param_spec` names too, so one set of seeded weights serves
-both.
+`models/mellum.py`, `models/kimi.py` and `models/phi4flash.py` build
+decoders from them through the DSL; their parameter names (`_<layer>.w0`,
+`.wq` ...) are what a plain reference's `param_spec` names too, so one set
+of seeded weights serves both.
 """
 
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +24,20 @@ from paddle_tpu.ops import activations
 from paddle_tpu.ops import gqa_attention as _attn
 from paddle_tpu.ops import lm_head as _head
 from paddle_tpu.ops import rope as _rope
+from paddle_tpu.ops import selective_scan as _scan
+
+
+def _named(layer, slots) -> dict:
+    """slot -> a ParameterConf `_<layer>.<slot>` of the slot's dims; a
+    1-D slot without a user's init starts at its (strategy, value)."""
+    pcs = {}
+    for slot, dims, *init in slots:
+        pc = layer.weight_conf(0, dims)
+        pc.name = f"_{layer.name}.{slot}"
+        if init and pc.initial_std is None:
+            pc.initial_strategy, pc.initial_value = "constant", init[0]
+        pcs[slot] = pc
+    return pcs
 
 
 def _rms(v, w, eps):
@@ -51,6 +67,29 @@ class RMSNormLayer(Layer):
         return arg.with_value(_rms(arg.value, params["w0"], eps))
 
 
+@LAYERS.register("layer_norm")
+class LayerNormLayer(Layer):
+    """w * (x - mean) / sqrt(var + epsilon) + b over the last axis, float32
+    inside. attrs: epsilon (1e-5). Params w0 [D] (1 at the start), b0 [D]
+    (0)."""
+
+    def build(self, in_specs):
+        (s,) = in_specs
+        d = s.dim[-1]
+        return s, _named(self, (("w0", (d,), 1.0), ("b0", (d,), 0.0)))
+
+    def forward(self, params, inputs, ctx: Ctx):
+        (arg,) = inputs
+        eps = self.conf.attrs.get("epsilon", 1e-5)
+        x = arg.value.astype(jnp.float32)
+        mu = jnp.mean(x, -1, keepdims=True)
+        xc = x - mu
+        y = xc * lax.rsqrt(jnp.mean(jnp.square(xc), -1, keepdims=True) + eps)
+        y = (y * params["w0"].astype(jnp.float32)
+             + params["b0"].astype(jnp.float32))
+        return arg.with_value(y.astype(arg.value.dtype))
+
+
 @LAYERS.register("gqa_attention")
 class GQAAttentionLayer(Layer):
     """Causal self-attention with grouped query heads and rotary positions.
@@ -72,13 +111,9 @@ class GQAAttentionLayer(Layer):
         d, hd = s.size, a["head_dim"]
         h, kv = a["num_heads"], a["num_kv_heads"]
         assert h % kv == 0, f"{h} heads do not divide over {kv} KV heads"
-        pcs = {}
-        for slot, dims in (("wq", (d, h * hd)), ("wk", (d, kv * hd)),
-                           ("wv", (d, kv * hd)), ("wo", (h * hd, d))):
-            pc = self.weight_conf(0, dims)
-            pc.name = f"_{self.name}.{slot}"
-            pcs[slot] = pc
-        return Spec(dim=(d,), is_seq=True), pcs
+        return Spec(dim=(d,), is_seq=True), _named(self, (
+            ("wq", (d, h * hd)), ("wk", (d, kv * hd)),
+            ("wv", (d, kv * hd)), ("wo", (h * hd, d))))
 
     def forward(self, params, inputs, ctx: Ctx):
         (arg,) = inputs
@@ -123,16 +158,10 @@ class MLAAttentionLayer(Layer):
         d, h, r = s.size, a["num_heads"], a["kv_lora_rank"]
         dn, dr, dv = (a["qk_nope_head_dim"], a["qk_rope_head_dim"],
                       a["v_head_dim"])
-        pcs = {}
-        for slot, dims in (("wq", (d, h * (dn + dr))), ("wkva", (d, r + dr)),
-                           ("kv_norm", (r,)), ("wkvb", (r, h * (dn + dv))),
-                           ("wo", (h * dv, d))):
-            pc = self.weight_conf(0, dims)
-            pc.name = f"_{self.name}.{slot}"
-            if slot == "kv_norm" and pc.initial_std is None:
-                pc.initial_strategy, pc.initial_value = "constant", 1.0
-            pcs[slot] = pc
-        return Spec(dim=(d,), is_seq=True), pcs
+        return Spec(dim=(d,), is_seq=True), _named(self, (
+            ("wq", (d, h * (dn + dr))), ("wkva", (d, r + dr)),
+            ("kv_norm", (r,), 1.0), ("wkvb", (r, h * (dn + dv))),
+            ("wo", (h * dv, d))))
 
     def forward(self, params, inputs, ctx: Ctx):
         (arg,) = inputs
@@ -164,6 +193,179 @@ class MLAAttentionLayer(Layer):
         return Arg(value=y, seq_lens=arg.seq_lens)
 
 
+class _Gauge:
+    """A layer that publishes ONE float a step as a gauge by `layer`: the
+    extra output `<name>@stats` (`Network.stat_outputs`), read at the
+    trainer's fence. Subclasses name it (`gauge`) and set it in forward
+    (`_set_gauge`)."""
+
+    gauge = None
+
+    def extra_output_specs(self):
+        return {f"{self.name}@stats": Spec(dim=(1,))}
+
+    @property
+    def stats_output(self):
+        return f"{self.name}@stats"
+
+    def _set_gauge(self, value) -> None:
+        self._extra_outs = {f"{self.name}@stats": Arg(
+            value=lax.stop_gradient(value).astype(jnp.float32).reshape(1, 1))}
+
+    def publish_stats(self, values, registry) -> None:
+        registry.gauge(self.gauge).set(float(values[0]), layer=self.name)
+
+
+def lambda_init(layer_index: int) -> float:
+    """A differential-attention layer's constant lam0, by its published
+    index."""
+    return 0.8 - 0.6 * math.exp(-0.3 * layer_index)
+
+
+@LAYERS.register("diff_attention")
+class DiffAttentionLayer(_Gauge, Layer):
+    """Causal differential attention: a head's output is the difference of
+    two softmax maps over one value, normalised.
+
+    attrs: num_heads H, num_kv_heads KV, head_dim hd (40, 20, 64); window
+    or None; layer_index (the PUBLISHED index: lam0 = 0.8 - 0.6 exp(-0.3
+    l)); epsilon (the 2 hd-wide norm's). size = the model width D. Params
+    wqkv [D, (H + 2 KV) hd] with bqkv, wo [H hd, D] with bo, lambda_q1,
+    lambda_k1, lambda_q2, lambda_k2 [hd] (float32 masters, used in float32),
+    subln [2 hd].
+
+    First a plain grouped attention, which is what the kernel sees: query
+    head h reads key head h // 2; key heads 2j and 2j + 1 (pair j) share ONE
+    value [v[2j] | v[2j+1]], 2 hd wide. Then differential head i = 2j + a
+    takes its positive map from query head 4j + a and its negative map from
+    4j + 2 + a: o_i = (1 - lam0) RMSNorm(A_{4j+a} - lam A_{4j+2+a}), lam =
+    exp(<lq1, lk1>) - exp(<lq2, lk2>) + lam0 (the gauge `attn.lambda`). No
+    rotary positions. Sequences are taken as packed to their full length,
+    as `gqa_attention` takes them."""
+
+    float32_params = ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2")
+    gauge = "attn.lambda"
+
+    def build(self, in_specs):
+        (s,) = in_specs
+        assert s.is_seq, "diff_attention needs a sequence input"
+        a = self.conf.attrs
+        d, hd = s.size, a["head_dim"]
+        h, kv = a["num_heads"], a["num_kv_heads"]
+        assert h == 2 * kv and kv % 2 == 0, (
+            f"differential attention pairs 2 query heads a key head and 2 "
+            f"key heads a value; got {h} on {kv}")
+        wide = (h + 2 * kv) * hd
+        return Spec(dim=(d,), is_seq=True), _named(self, (
+            ("wqkv", (d, wide)), ("bqkv", (wide,), 0.0),
+            ("wo", (h * hd, d)), ("bo", (d,), 0.0),
+            *((lam, (hd,)) for lam in self.float32_params),
+            ("subln", (2 * hd,), 1.0)))
+
+    def forward(self, params, inputs, ctx: Ctx):
+        (arg,) = inputs
+        a = self.conf.attrs
+        hd, h, kv = a["head_dim"], a["num_heads"], a["num_kv_heads"]
+        x = arg.value
+        b, t, _ = x.shape
+        with jax.named_scope("attn.qkv"):
+            qkv = jnp.dot(x, params["wqkv"]) + params["bqkv"]
+            q = qkv[..., : h * hd].reshape(b, t, h, hd)
+            k = qkv[..., h * hd: (h + kv) * hd].reshape(b, t, kv, hd)
+            v = jnp.repeat(qkv[..., (h + kv) * hd:].reshape(
+                b, t, kv // 2, 2 * hd), 2, axis=2)
+        with jax.named_scope("attn.core"):
+            maps = _attn.gqa_attention(q, k, v, window=a.get("window"))
+        with jax.named_scope("attn.diff"):
+            lam0 = lambda_init(a["layer_index"])
+            # float32 masters, and a sum of products: no matmul rounds it
+            lam = (jnp.exp(jnp.sum(params["lambda_q1"] * params["lambda_k1"]))
+                   - jnp.exp(jnp.sum(params["lambda_q2"]
+                                     * params["lambda_k2"])) + lam0)
+            self._set_gauge(lam)
+            maps = maps.reshape(b, t, kv // 2, 2, 2, 2 * hd).astype(
+                jnp.float32)
+            diff = maps[:, :, :, 0] - lam * maps[:, :, :, 1]
+            o = (1.0 - lam0) * _rms(diff, params["subln"],
+                                    a.get("epsilon", 1e-5))
+            o = o.astype(x.dtype).reshape(b, t, h * hd)
+        with jax.named_scope("attn.out"):
+            y = jnp.dot(o, params["wo"]) + params["bo"]
+        return Arg(value=y, seq_lens=arg.seq_lens)
+
+
+@LAYERS.register("mamba")
+class MambaLayer(_Gauge, Layer):
+    """A Mamba (selective state-space) mixer.
+
+    attrs: d_state N (16), d_conv K (4), expand (2), dt_rank R. size = the
+    model width D; d_inner C = expand x D. Params, no bias but the two
+    named: w_in [D, 2 C]; conv_w [C, K] with conv_b [C]: a causal depthwise
+    convolution over time, zeros before a row's start; w_x [C, R + 2 N];
+    w_dt [R, C] with b_dt [C]; a_log [C, N] (A = -exp(a_log)); d [C]; w_out
+    [C, D]. a_log, d, b_dt and conv_b keep their float32 masters and are
+    used in float32; dt = softplus(r w_dt + b_dt), the recurrence and its
+    state are float32 (ops/selective_scan.py).
+
+        [xr | z] = u w_in;  x = silu(conv(xr));  [r | B | C] = x w_x
+        h_t = exp(dt_t A) h_{t-1} + (dt_t x_t) B_t;  s_t = <h_t, C_t> + d x_t
+        y = (s * silu(z)) w_out
+
+    The state runs through a packed row with no reset at a document's
+    start, as the attention layers see across them. The gauge
+    `ssm.state_absmax` is the largest |h| at a chunk's end."""
+
+    float32_params = ("a_log", "d", "b_dt", "conv_b")
+    gauge = "ssm.state_absmax"
+
+    def _sizes(self, d):
+        a = self.conf.attrs
+        return (a.get("expand", 2) * d, a.get("d_state", 16),
+                a.get("d_conv", 4), a["dt_rank"])
+
+    def build(self, in_specs):
+        (s,) = in_specs
+        assert s.is_seq, "mamba needs a sequence input"
+        d = s.size
+        c, n, k, r = self._sizes(d)
+        return Spec(dim=(d,), is_seq=True), _named(self, (
+            ("w_in", (d, 2 * c)), ("conv_w", (c, k)), ("conv_b", (c,), 0.0),
+            ("w_x", (c, r + 2 * n)), ("w_dt", (r, c)), ("b_dt", (c,), 0.0),
+            ("a_log", (c, n)), ("d", (c,), 1.0), ("w_out", (c, d))))
+
+    def forward(self, params, inputs, ctx: Ctx):
+        (arg,) = inputs
+        u = arg.value
+        t = u.shape[1]
+        c, n, k, r = self._sizes(u.shape[-1])
+        with jax.named_scope("ssm.in"):
+            xz = jnp.dot(u, params["w_in"])
+            xr, z = xz[..., :c], xz[..., c:]
+        with jax.named_scope("ssm.conv"):
+            padded = jnp.pad(xr, ((0, 0), (k - 1, 0), (0, 0))).astype(
+                jnp.float32)
+            w = params["conv_w"].astype(jnp.float32)
+            acc = params["conv_b"] + sum(
+                w[:, j] * padded[:, j: j + t] for j in range(k))
+            x = jax.nn.silu(acc).astype(u.dtype)
+        with jax.named_scope("ssm.proj"):
+            rbc = jnp.dot(x, params["w_x"])
+            dt = jax.nn.softplus(
+                jnp.dot(rbc[..., :r], params["w_dt"],
+                        preferred_element_type=jnp.float32)
+                + params["b_dt"])
+        with jax.named_scope("ssm.scan"):
+            s, hmax = _scan.selective_scan(
+                x, dt, -jnp.exp(params["a_log"]), rbc[..., r: r + n],
+                rbc[..., r + n:], params["d"], with_state_absmax=True)
+            self._set_gauge(hmax)
+        with jax.named_scope("ssm.gate"):
+            g = (s * jax.nn.silu(z.astype(jnp.float32))).astype(u.dtype)
+        with jax.named_scope("ssm.out"):
+            y = jnp.dot(g, params["w_out"])
+        return Arg(value=y, seq_lens=arg.seq_lens)
+
+
 @LAYERS.register("gated_mlp")
 class GatedMLPLayer(Layer):
     """(act(x w_gate) * (x w_up)) w_down: a decoder's dense feed-forward
@@ -174,13 +376,8 @@ class GatedMLPLayer(Layer):
     def build(self, in_specs):
         (s,) = in_specs
         d, f = s.size, self.conf.attrs["hidden"]
-        pcs = {}
-        for slot, dims in (("w_gate", (d, f)), ("w_up", (d, f)),
-                           ("w_down", (f, d))):
-            pc = self.weight_conf(0, dims)
-            pc.name = f"_{self.name}.{slot}"
-            pcs[slot] = pc
-        return s, pcs
+        return s, _named(self, (("w_gate", (d, f)), ("w_up", (d, f)),
+                                ("w_down", (f, d))))
 
     def forward(self, params, inputs, ctx: Ctx):
         (arg,) = inputs
@@ -195,7 +392,10 @@ class LMHeadCostLayer(CostLayerBase):
     """The vocabulary head fused with its softmax cross-entropy, in row
     chunks (ops/lm_head.py), so that [tokens, vocabulary] logits never
     stand whole. inputs: [hidden sequence, label ids]. attrs: vocab_size,
-    chunk_rows (2048), coeff. Param w0 [D, V], no bias.
+    chunk_rows (2048), coeff. Param w0 [D, V], no bias; with `tied_to`
+    (an embedding layer's name) it has no matrix of its own and reads that
+    layer's table `_<tied_to>.w0` [V, D] transposed: ONE leaf, whose
+    gradient is the sum of its two uses (`tie_word_embeddings`).
 
     A cost layer, so it is handed float32; the head's matmul operands are
     rounded to the policy's compute dtype (`Ctx.compute_dtype`: bfloat16
@@ -208,15 +408,21 @@ class LMHeadCostLayer(CostLayerBase):
         s = in_specs[0]
         assert s.is_seq, "lm_head_cost needs a sequence input"
         self._in_specs = in_specs
-        pc = self.weight_conf(0, (s.size, self.conf.attrs["vocab_size"]))
+        a = self.conf.attrs
+        if a.get("tied_to"):
+            pc = self.weight_conf(0, (a["vocab_size"], s.size))
+            pc.name, pc.is_shared = f"_{a['tied_to']}.w0", True
+        else:
+            pc = self.weight_conf(0, (s.size, a["vocab_size"]))
         return Spec(dim=(1,), is_seq=False), {"w0": pc}
 
     def forward(self, params, inputs, ctx: Ctx):
         hid, label = inputs
         a = self.conf.attrs
         b, t, d = hid.value.shape
+        w = params["w0"].T if a.get("tied_to") else params["w0"]
         per = _head.chunked_softmax_cost(
-            hid.value.reshape(b * t, d), params["w0"],
+            hid.value.reshape(b * t, d), w,
             label.ids.reshape(b * t), chunk=a.get("chunk_rows", 2048),
             compute_dtype=ctx.compute_dtype,
         ).reshape(b, t)

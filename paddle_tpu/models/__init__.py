@@ -20,3 +20,4 @@ from paddle_tpu.models.gan import GAN, gan_conf  # noqa: F401
 from paddle_tpu.models.vae import vae_conf  # noqa: F401
 from paddle_tpu.models.mellum import mellum  # noqa: F401
 from paddle_tpu.models.kimi import kimi  # noqa: F401
+from paddle_tpu.models.phi4flash import phi4flash  # noqa: F401

@@ -6,7 +6,8 @@ materialising the [T, T] scores or the KV heads repeated per query head.
 Query head h attends KV head h // (H / KV); position i sees j <= i and,
 with `window`, also i - j < window. The value head may differ in width
 from the query/key head (latent attention: 192-wide keys, 128-wide
-values); scores are scaled by 1/sqrt(D). Two lowerings (`impl`):
+values; differential attention: 64-wide keys, 128-wide values); scores are
+scaled by 1/sqrt(D). Two lowerings (`impl`):
 
 - "pallas": the TPU's splash-attention kernel (jax.experimental.pallas
   .ops.tpu.splash_attention), as multi-query attention over the query heads
@@ -39,11 +40,11 @@ BLOCKED_BLOCK_Q = 512
 
 def pallas_fits(t: int, d: int, dv: int = None) -> bool:
     """The kernel's tiles: the sequence in blocks of a lane multiple, the
-    value head a lane multiple, the query/key head a lane multiple or a
-    half lane over one (192: Mosaic pads the block's last tile itself;
-    compiled for a v5e in tests/test_tpu_compile.py)."""
+    value head a lane multiple, the query/key head a multiple of half a
+    lane tile (64, 192: Mosaic pads the block's last tile itself; compiled
+    for a v5e in tests/test_tpu_compile.py)."""
     dv = d if dv is None else dv
-    return (t % LANES == 0 and dv % LANES == 0 and d >= LANES
+    return (t % LANES == 0 and dv % LANES == 0 and d > 0
             and d % (LANES // 2) == 0)
 
 
@@ -141,7 +142,7 @@ def gqa_attention(q, k, v, *, window=None, impl=None, block_q=None,
             raise ValueError(
                 f"the attention kernel needs T and the value head in "
                 f"multiples of {LANES}, the query/key head in multiples of "
-                f"{LANES // 2} from {LANES}; got T={t}, D={d}, Dv={dv}")
+                f"{LANES // 2}; got T={t}, D={d}, Dv={dv}")
         return _pallas(q, k, v, window, block_q or KERNEL_BLOCK_Q,
                        block_kv or KERNEL_BLOCK_KV,
                        _ops.pallas_interpret(interpret))

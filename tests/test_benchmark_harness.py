@@ -2,13 +2,15 @@
 (files and names, the last line's keys, the no-TPU refusal, `trace_reduce`,
 the readers, a cell added as new files), `test_program_spans.py` (the
 readers of the program's spans and scopes), `test_mellum_cell.py` (the
-cell PR 28 added, at a tiny size) and `test_kimi_cell.py` (the cell PR 33
-added, likewise). They run here as they stand there, but
-for the two that `REPLACED` names with the reason: each fails as it stands
-since PR 28 appended the entries that ISSUE 28 named, no PR but a `benchmark`
-PR may edit those files, and so each is taken out of this module BY NAME (a
+cell PR 28 added, at a tiny size), `test_kimi_cell.py` (the cell PR 33
+added, likewise) and `test_phi4flash_cell.py` (PR 35's). They run here as
+they stand there, but
+for the three that `REPLACED` names with the reason: each fails as it stands
+since a later PR appended the entries that its issue named, no PR but a
+`benchmark` PR may edit those files, and so each is taken out of this module
+BY NAME (a
 test renamed there fails this module's collection, loudly) and its sense is
-held here by a test of another name. The repair of the two is the first item
+held here by a test of another name. The repair of the three is the first item
 of the next `benchmark` PR (PERF.md section 7). (The override that PR 25
 needed of `test_readers_read_the_run_and_return_nothing_where_nothing_is` is
 gone: PR 27 repaired that test, and it runs here as it stands.)"""
@@ -22,6 +24,8 @@ from benchmarks.tests.test_program_spans import NEW
 from benchmarks.tests.test_mellum_cell import *  # noqa: F401,F403,E402
 from benchmarks.tests.test_kimi_cell import *  # noqa: F401,F403,E402
 from benchmarks.tests.test_kimi_cell import KIMI_CELL, PR33
+from benchmarks.tests.test_phi4flash_cell import *  # noqa: F401,F403,E402
+from benchmarks.tests.test_phi4flash_cell import PHI_CELL, PR35
 
 REPLACED = {
     "test_a_token_counted_training_cell_is_new_files_and_appended_entries":
@@ -30,6 +34,9 @@ REPLACED = {
     "test_every_new_metric_resolves_to_a_reader_and_a_data_file":
         "holds PR 25's metrics to be the LAST of per_layer, which no "
         "appended metric leaves true",
+    "test_kimi_cells_files_are_found_and_say_what_the_issue_says":
+        "holds PR 33's configuration to be the LAST of configs, which no "
+        "appended configuration leaves true (PR 35 appended one)",
 }
 for _name in REPLACED:
     del globals()[_name]            # KeyError: renamed there; look again
@@ -48,6 +55,21 @@ def test_a_toy_token_cell_is_new_files_beside_the_one_the_tree_has(
         tmp_path)
 
 
+def test_kimi_cells_files_say_what_issue_33_says_beside_later_configs(
+        monkeypatch):
+    """The test as it stands there, on the benchmark cut after PR 33's
+    configuration: what it holds of the cell's files it holds still."""
+    from benchmarks.tests import test_kimi_cell as K
+
+    bench = harness.load_benchmark()
+    at = [c["name"] for c in bench["configs"]].index("kimi_vl_a3b_ep8")
+    assert at == 2 and len(bench["configs"]) > 3
+    cut = dict(bench, configs=bench["configs"][: at + 1])
+    monkeypatch.setattr(harness, "load_benchmark",
+                        lambda root=harness.ROOT: cut)
+    K.test_kimi_cells_files_are_found_and_say_what_the_issue_says()
+
+
 PR28 = ["mfu.tokens", "device_idle_share.tokens",
         "loop_input_wait_share.tokens", "idle_input_wait_share.tokens",
         "idle_dispatch_share.tokens", "idle_other_share.tokens",
@@ -58,8 +80,8 @@ PR28 = ["mfu.tokens", "device_idle_share.tokens",
 
 def test_pr25s_and_pr28s_metrics_resolve_in_their_order():
     """PR 25's metrics in their order, PR 28's twelve as one run in theirs,
-    followed by PR 33's thirteen in theirs (appended entries move nothing
-    that was there)."""
+    followed by PR 33's thirteen and PR 35's thirteen in theirs (appended
+    entries move nothing that was there)."""
     import json
 
     bench = harness.load_benchmark()
@@ -69,10 +91,12 @@ def test_pr25s_and_pr28s_metrics_resolve_in_their_order():
     at = names.index(PR28[0])
     assert names[at: at + len(PR28)] == PR28
     assert names[at + len(PR28): at + len(PR28) + len(PR33)] == PR33
+    assert names[at + len(PR28) + len(PR33):] == PR35
     for name, cell in ([(n, "resnet50.train_bs256") for n in NEW]
                        + [(n, "mellum2_12b_ep4.train_seq8192")
                           for n in PR28]
-                       + [(n, KIMI_CELL) for n in PR33]):
+                       + [(n, KIMI_CELL) for n in PR33]
+                       + [(n, PHI_CELL) for n in PR35]):
         m = entries[name]
         assert m["workloads"] == [cell]
         assert (m["unit"], m["moves"]) == ("%", "train_units_per_s")
@@ -83,7 +107,7 @@ def test_pr25s_and_pr28s_metrics_resolve_in_their_order():
                             "window_s": 0.0}, data) is None
     assert {entries[n]["layer"] for n in NEW} == {
         "entry points", "device", "step program and model graph"}
-    for mine in (PR28, PR33):
+    for mine in (PR28, PR33, PR35):
         assert {entries[n]["layer"] for n in mine} == {
             "entry points", "device", "step program and model graph",
             "kernels"}

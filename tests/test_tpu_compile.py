@@ -350,3 +350,99 @@ def test_grouped_matmul_at_the_latent_decoders_widths(one_chip, monkeypatch):
 
         text = _compile(jax.grad(loss, argnums=(0, 1)), rows, w, sizes)
         assert text.count("tpu_custom_call") >= 2  # the rows', the weights'
+
+
+# ---- the hybrid decoder's kernels at the widths of the cell
+# `phi4_mini_flash_pp8.train_seq8192`: 2 rows of 8,192 positions; a scan
+# over 5,120 channels of 16 states; 40 query heads on 20 key heads of 64,
+# each with a 128-wide value, over a window of 512 and full
+
+def test_selective_scan_forward_and_gradient(one_chip, monkeypatch):
+    from paddle_tpu import ops
+    from paddle_tpu.ops.selective_scan import pallas_fits, selective_scan
+
+    monkeypatch.setattr(ops, "pallas_interpret",
+                        lambda requested=None: False)
+    assert pallas_fits(8192, 5120)
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    args = (sds(2, 8192, 5120), sds(2, 8192, 5120), sds(5120, 16),
+            sds(2, 8192, 16), sds(2, 8192, 16), sds(5120))
+
+    def fwd(*a):
+        return selective_scan(*a, impl="pallas")
+
+    def loss(*a):
+        return jnp.sum(fwd(*a))
+
+    text = _compile(fwd, *args)
+    assert text.count("tpu_custom_call") == 1      # ONE call a layer call
+    assert jax.eval_shape(fwd, *args).shape == (2, 8192, 5120)
+    text = _compile(jax.grad(loss, argnums=tuple(range(6))), *args)
+    assert text.count("tpu_custom_call") == 2      # the forward, the backward
+    assert "while" not in text.split("ENTRY")[1]   # no loop around either
+
+
+@pytest.mark.parametrize("window", [512, None])
+def test_differential_attentions_maps_at_64_128(one_chip, monkeypatch,
+                                                window):
+    from paddle_tpu import ops
+    from paddle_tpu.ops.gqa_attention import gqa_attention, pallas_fits
+
+    monkeypatch.setattr(ops, "pallas_interpret",
+                        lambda requested=None: False)
+    assert pallas_fits(8192, 64, 128)
+    q = jax.ShapeDtypeStruct((2, 8192, 40, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    k = jax.ShapeDtypeStruct((2, 8192, 20, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    v = jax.ShapeDtypeStruct((2, 8192, 20, 128), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def fwd(q, k, v):
+        return gqa_attention(q, k, v, window=window, impl="pallas")
+
+    def loss(q, k, v):
+        return jnp.sum(fwd(q, k, v).astype(jnp.float32))
+
+    assert _has_kernel(_compile(fwd, q, k, v))
+    assert jax.eval_shape(fwd, q, k, v).shape == (2, 8192, 40, 128)
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, k, v)
+    assert text.count("tpu_custom_call") >= 3      # forward, dq, dk and dv
+
+
+@pytest.mark.parametrize("h,kv,d,dv,window", [
+    (32, 4, 128, 128, 1024), (32, 4, 128, 128, None), (16, 16, 192, 128, None),
+    (40, 20, 64, 128, 512)], ids=["mellum-window", "mellum-full", "kimi",
+                                  "phi-window"])
+def test_attention_hands_the_kernel_each_models_own_widths(
+        monkeypatch, h, kv, d, dv, window):
+    """What the other decoder cells' calls lower to did not move with the
+    64-wide head: the kernel is handed q, k and v at the model's own
+    widths, nothing padded, in tiles of 1,024, whatever the head (the
+    lowering, not a compile: no chip is described for it)."""
+    from paddle_tpu import ops
+    from paddle_tpu.ops import gqa_attention as GA
+
+    monkeypatch.setattr(ops, "pallas_interpret",
+                        lambda requested=None: False)
+    made = []
+    plain = GA._splash_kernel
+
+    def spy(*args):
+        made.append(args)
+        return plain(*args)
+
+    monkeypatch.setattr(GA, "_splash_kernel", spy)
+    q = jax.ShapeDtypeStruct((2, 8192, h, d), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((2, 8192, kv, d), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((2, 8192, kv, dv), jnp.bfloat16)
+    assert GA.pallas_fits(8192, d, dv)
+    jaxpr = str(jax.make_jaxpr(lambda q, k, v: GA.gqa_attention(
+        q, k, v, window=window, impl="pallas"))(q, k, v))
+    assert made == [(8192, h // kv, window, 1024, 1024, False)]
+    assert " pad[" not in jaxpr and "pallas_call" in jaxpr
+    assert f"bf16[2,{kv},{h // kv},8192,{d}]" in jaxpr      # q, grouped
+    assert f"bf16[2,{kv},8192,{dv}]" in jaxpr               # v, unpadded
