@@ -111,7 +111,9 @@ class ModelConf:
     sub_models: list = field(default_factory=list)  # list[SubModelConf]
     # groups of consecutive layer names (a decoder block each) whose
     # activations are not kept for the backward pass but recomputed in
-    # it: Network wraps each group in jax.checkpoint when training
+    # it: Network wraps each group in jax.checkpoint when training. What a
+    # kernel call produced and its backward reads is kept all the same
+    # (network._KEEP: the names the ops tag such values with)
     recompute: list = field(default_factory=list)  # list[list[str]]
 
     def layer(self, name: str) -> LayerConf:

@@ -19,6 +19,9 @@ from paddle_tpu.core import flags as _flags
 from paddle_tpu.core.arg import Arg
 from paddle_tpu.core.config import ModelConf, ParameterConf
 from paddle_tpu.layers.base import Ctx, create_layer, init_parameter
+from paddle_tpu.obs import get_registry
+from paddle_tpu.ops import gqa_attention as _attention
+from paddle_tpu.ops import selective_scan as _scan
 
 
 def _cast_arg(a: Arg, dtype) -> Arg:
@@ -34,6 +37,19 @@ def _cast_arg(a: Arg, dtype) -> Arg:
 
 # ensure all layer types are registered
 import paddle_tpu.layers  # noqa: F401
+
+
+# What a recompute group keeps from its forward to its backward beside its
+# inputs: the values tagged with these names where they are produced
+# (jax.ad_checkpoint.checkpoint_name), so that what produced them alone, a
+# kernel call, is dead code in the second forward. Worth a name is a kernel
+# call's output that its backward reads (the attention kernel's output and
+# log-sum-exp, the scan's output and chunk-end states), not an elementwise
+# value XLA fuses again for nothing. ONE object for every group and every
+# trace: jax caches its partial evaluation of a sub-jaxpr (an expert
+# layer's passes, shared by every block) by the policy's identity
+_KEEP = jax.checkpoint_policies.save_only_these_names(
+    _attention.KEPT, _scan.KEPT)
 
 
 class Network:
@@ -301,8 +317,12 @@ class Network:
         """A recompute group: its layers as one function of the group's
         parameters and of what it reads from outside, under
         jax.checkpoint, so that the backward pass keeps its inputs and
-        runs its forward again. The bfloat16 copies of its weights are
-        made inside and so are recomputed too."""
+        runs its forward again, but for the values its layers tagged with
+        a name `_KEEP` knows: those it keeps. The bfloat16 copies of its
+        weights are made inside and so are recomputed too. The gauge
+        `recompute.kept_bytes` by `group` (the group's first layer) is what
+        the group holds beyond its inputs: the bytes its ops tagged
+        (`ops.note_kept`) while its forward was traced."""
         inside = set(names)
         reads = sorted({
             n for ln in names for n in self.conf.layer(ln).input_names()
@@ -324,10 +344,14 @@ class Network:
         # `.../rematted_computation/<type>:<name>/...`, the first as
         # `.../checkpoint/<type>:<name>/...`: no scope of its own, so that
         # an operation's outermost scope stays its layer's
-        outs.update(jax.checkpoint(group)(
+        tagged = get_registry().counter("recompute.tagged_bytes")
+        before = tagged.get()
+        outs.update(jax.checkpoint(group, policy=_KEEP)(
             {g: params[g] for g in pnames},
             {n: outs[n] for n in reads}, ctx.rng,
         ))
+        get_registry().gauge("recompute.kept_bytes").set(
+            int(tagged.get() - before), group=names[0])
 
     def loss_fn(self, params, feed, state=None, train=True, rng=None):
         """Scalar batch-mean cost over all cost layers — what

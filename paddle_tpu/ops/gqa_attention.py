@@ -19,6 +19,12 @@ scaled by 1/sqrt(D). Two lowerings (`impl`):
   not the square's), each block rematerialised in the backward pass.
 
 None picks "pallas" on a TPU when the shapes fit its tiles, else "blocked".
+
+Both tag what they produce and their backward reads with the name `KEPT`
+(the kernel its output and log-sum-exp, the portable loop its output): a
+recompute group keeps values of that name (`network._KEEP`) and so does not
+run the attention forward a second time. Outside such a group the tag is
+the identity.
 """
 
 from __future__ import annotations
@@ -28,9 +34,11 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from paddle_tpu import ops as _ops
 
+KEPT = "attn.core"
 NEG_INF = -1e30
 LANES = 128
 # the kernel's tiles (query block, key block) and the portable loop's
@@ -72,7 +80,7 @@ def _splash_kernel(t, group, window, block_q, block_kv, interpret):
     )
     return sa.make_splash_mqa_single_device(
         sa.MultiHeadMask([one] * group), block_sizes=sizes,
-        interpret=interpret,
+        residual_checkpoint_name=KEPT, interpret=interpret,
     )
 
 
@@ -90,6 +98,9 @@ def _pallas(q, k, v, window, block_q, block_kv, interpret):
     qg = qg.transpose(0, 2, 1, 3).reshape(b, kv, g, t, d)
     kt, vt = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
     o = jax.vmap(jax.vmap(kernel))(qg, kt, vt)        # [B, KV, G, T, Dv]
+    # the library tags the output and, for its backward kernels, the
+    # log-sum-exp (float32 a query and head) itself
+    _ops.note_kept(o, jax.ShapeDtypeStruct(o.shape[:-1], jnp.float32))
     return o.reshape(b, h, t, v.shape[-1]).transpose(0, 2, 1, 3)
 
 
@@ -120,7 +131,9 @@ def _blocked(q, k, v, window, block_q):
         q1 = min(q0 + block_q, t)
         k0 = 0 if window is None else max(0, q0 - (window - 1))
         outs.append(one(qg[:, q0:q1], k[:, k0:q1], v[:, k0:q1], q0, k0))
-    return jnp.concatenate(outs, axis=1).reshape(b, t, h, v.shape[-1])
+    o = checkpoint_name(jnp.concatenate(outs, axis=1), KEPT)
+    _ops.note_kept(o)
+    return o.reshape(b, t, h, v.shape[-1])
 
 
 def gqa_attention(q, k, v, *, window=None, impl=None, block_q=None,

@@ -30,6 +30,12 @@ None picks "pallas" on a TPU when the shapes fit its tiles (T a multiple of
 the chunk, C of 128 in blocks of 8 tiles or as one block), else "chunked".
 `with_state_absmax` returns beside s the largest |h| at a chunk's end (no
 gradient): the overflow watch of a float32 recurrence.
+
+The kernel tags what it produces and its backward reads (y and the
+chunk-end states) with the name `KEPT`: a recompute group keeps values of
+that name (`network._KEEP`) and so does not run the scan forward a second
+time. Outside such a group the tag is the identity. The portable scan tags
+nothing: its backward reads no output of its forward.
 """
 
 from __future__ import annotations
@@ -39,11 +45,13 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu import ops as _ops
 
+KEPT = "ssm.scan"
 LANES = 128
 SUBLANES = 8
 CHUNK = 128                    # positions a grid step, and between saved states
@@ -284,11 +292,14 @@ def _backward_call(x, dt, a, bm, cm, hend, dy, chunk, interpret):
 def _pallas(x, dt, a, bm, cm, chunk, interpret):
     """Float32 in and out. -> (y without the D x term, largest |h| at a
     chunk's end)."""
-    return _pallas_fwd(x, dt, a, bm, cm, chunk, interpret)[0]
+    out, saved = _pallas_fwd(x, dt, a, bm, cm, chunk, interpret)
+    _ops.note_kept(out[0], saved[-1])           # y and the chunk-end states
+    return out
 
 
 def _pallas_fwd(x, dt, a, bm, cm, chunk, interpret):
-    y, hend = _forward_call(x, dt, a, bm, cm, chunk, interpret)
+    y, hend = (checkpoint_name(v, KEPT) for v in _forward_call(
+        x, dt, a, bm, cm, chunk, interpret))
     return (y, jnp.max(jnp.abs(hend))), (x, dt, a, bm, cm, hend)
 
 

@@ -17,6 +17,8 @@ processes; the persistent compile cache is off around them (an
 executable compiled for a described chip cannot be read back without
 one)."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -446,3 +448,74 @@ def test_attention_hands_the_kernel_each_models_own_widths(
     assert " pad[" not in jaxpr and "pallas_call" in jaxpr
     assert f"bf16[2,{kv},{h // kv},8192,{d}]" in jaxpr      # q, grouped
     assert f"bf16[2,{kv},8192,{dv}]" in jaxpr               # v, unpadded
+
+
+# ---- a recompute group keeps what its kernels produced (ISSUE 36): one
+# block of norm, mixer and residual as a recompute group of a small graph,
+# its gradient compiled for the chip at the three attention widths of the
+# decoder cells and at the scan's smallest block
+
+_MIXERS = {
+    "attention-128-128": ("gqa_attention", dict(
+        num_heads=2, num_kv_heads=1, head_dim=128, window=None,
+        rope={"rope_theta": 10000})),
+    "attention-192-128": ("mla_attention", dict(
+        num_heads=2, kv_lora_rank=64, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, rope_theta=10000)),
+    "attention-64-128": ("diff_attention", dict(
+        num_heads=4, num_kv_heads=2, head_dim=64, layer_index=1,
+        window=128)),
+    "scan": ("mamba", dict(d_state=4, dt_rank=8)),
+}
+
+
+@pytest.mark.parametrize("mixer", _MIXERS)
+def test_a_recompute_group_calls_its_kernel_forward_once(one_chip,
+                                                          monkeypatch, mixer):
+    """Forward, dq, dkv (the scan: forward, backward), ONE of them the
+    forward kernel; under a bare `jax.checkpoint` (`network._KEEP` None)
+    the program before ISSUE 36: one call more, the recomputed forward."""
+    import paddle_tpu.network as N
+    from paddle_tpu import dsl, ops
+    from paddle_tpu.core.arg import Arg
+    from paddle_tpu.network import Network
+
+    monkeypatch.setattr(ops, "pallas_interpret",
+                        lambda requested=None: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    kind, attrs = _MIXERS[mixer]
+    d, t, vocab = 256, 256, 128
+
+    def kernel_calls():
+        with dsl.model() as g:
+            ids = dsl.data("ids", dim=(), is_ids=True, is_seq=True)
+            label = dsl.data("label", dim=(), is_ids=True, is_seq=True)
+            x = dsl.embedding(ids, size=d, vocab_size=vocab, name="emb")
+            a = dsl._add("rms_norm", [x], name="norm", bias=False)
+            m = dsl._add(kind, [a], name="mixer", size=d, bias=False, **attrs)
+            x = dsl.addto(x, m, name="res")
+            dsl._add("lm_head_cost", [x, label], name="head", bias=False,
+                     vocab_size=vocab, chunk_rows=256)
+        g.conf.recompute.append(["norm", "mixer", "res"])
+        net = Network(g.conf)
+        params = {k: jax.ShapeDtypeStruct(tuple(pc.dims), jnp.float32,
+                                          sharding=one_chip)
+                  for k, pc in net.param_confs.items()}
+        ints = jax.ShapeDtypeStruct((2, t), jnp.int32, sharding=one_chip)
+        lens = jax.ShapeDtypeStruct((2,), jnp.int32, sharding=one_chip)
+
+        def loss(p, ids, label, lens):
+            feed = {"ids": Arg(ids=ids, seq_lens=lens),
+                    "label": Arg(ids=label, seq_lens=lens)}
+            return net.loss_fn(p, feed, train=True)[0]
+
+        calls = re.findall(r"%(splash_mqa_\w+?|selective_scan_\w+?)[.\d]* = .*"
+                           r"tpu_custom_call",
+                           _compile(jax.grad(loss), params, ints, ints, lens))
+        return len(calls), sum(c in FORWARD for c in calls)
+
+    FORWARD = ("splash_mqa_fwd_residuals", "selective_scan_forward")
+    want = 2 if kind == "mamba" else 3
+    assert kernel_calls() == (want, 1)
+    monkeypatch.setattr(N, "_KEEP", None)
+    assert kernel_calls() == (want + 1, 2)
