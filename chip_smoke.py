@@ -102,43 +102,32 @@ def say(**fields) -> None:
     print(json.dumps(fields), flush=True)
 
 
-class CompileClock:
-    """XLA compile seconds and persistent-cache traffic, from JAX's own
-    monitoring events (a cache hit is timed as the load it is)."""
+def compiled() -> tuple:
+    """(XLA compile seconds, persistent-cache hits, misses) so far,
+    from the program's own compile watch
+    (`paddle_tpu.core.compile_cache.watch`, on since `setup()`): a
+    cache hit is timed as the load it is."""
+    from paddle_tpu.obs.metrics import get_registry
 
-    _BACKEND = "/jax/core/compile/backend_compile_duration"
-
-    def __init__(self):
-        import jax.monitoring as mon
-
-        self.compile_s = 0.0
-        self.hits = self.misses = 0
-        mon.register_event_duration_secs_listener(self._on_duration)
-        mon.register_event_listener(self._on_event)
-
-    def _on_duration(self, event, secs, **_):
-        if event == self._BACKEND:
-            self.compile_s += secs
-
-    def _on_event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
+    reg = get_registry()
+    return (sum(reg.counter("compile.backend_s").snapshot().values()),
+            int(reg.counter("compile.cache_hits").get()),
+            int(reg.counter("compile.cache_misses").get()))
 
 
 @contextlib.contextmanager
-def phase(clock: CompileClock, name: str):
+def phase(name: str):
     """Time one phase; the body fills `out` (its `run_s` and checks)."""
     import jax
 
-    c0, h0, m0 = clock.compile_s, clock.hits, clock.misses
+    c0, h0, m0 = compiled()
     t0 = time.perf_counter()
     out: dict = {}
     yield out
     stats = jax.devices()[0].memory_stats() or {}
     wall_s = time.perf_counter() - t0
-    compile_s = clock.compile_s - c0
+    c1, h1, m1 = compiled()
+    compile_s = c1 - c0
     # a phase that times no warm call of its own (each kernel check
     # runs once) reports what its wall holds besides compiling
     out.setdefault("run_s", round(wall_s - compile_s, 3))
@@ -146,8 +135,8 @@ def phase(clock: CompileClock, name: str):
         phase=name,
         wall_s=round(wall_s, 3),
         compile_s=round(compile_s, 3),
-        cache_hits=clock.hits - h0,
-        cache_writes=clock.misses - m0,
+        cache_hits=h1 - h0,
+        cache_writes=m1 - m0,
         # the allocator's view since the process started; on the v5e
         # it does not count a running program's temporaries (a step's
         # `compiled.memory_analysis()` does: PERF.md has both)
@@ -808,7 +797,7 @@ def phase_kernels(sz: Sizes, out: dict) -> None:
 
 
 # ------------------------------------------------------- four chips
-def run_four_chips(sz: Sizes, clock: CompileClock) -> None:
+def run_four_chips(sz: Sizes) -> None:
     """The cross-chip path and what it is compared with, nothing else:
     the NMT step data-parallel over all four devices against the
     one-device run of the same seed, the collectives and placements
@@ -822,12 +811,12 @@ def run_four_chips(sz: Sizes, clock: CompileClock) -> None:
 
     steps = 3
     feed = _nmt_feed(sz)
-    with phase(clock, "nmt_one_device") as out:
+    with phase("nmt_one_device") as out:
         one = SGD(_nmt_conf(sz), _nmt_opt(), seed=SEED + 1)
         placed = jax.device_put(feed)
         one_losses = [one.train_batch(placed) for _ in range(steps)]
         out["losses"] = one_losses
-    with phase(clock, "nmt_four_devices") as out:
+    with phase("nmt_four_devices") as out:
         mesh = make_mesh({"data": 4})
         four = SGD(_nmt_conf(sz), _nmt_opt(), seed=SEED + 1, mesh=mesh)
         # every parameter lives on all four chips, not on the first
@@ -855,7 +844,7 @@ def run_four_chips(sz: Sizes, clock: CompileClock) -> None:
                                rtol=LOSS_RTOL_4CHIP)
     say(check="one_chip_vs_four_chip_losses", one=one_losses,
         four=four_losses, rtol=LOSS_RTOL_4CHIP)
-    with phase(clock, "dryrun_multichip_1x2x2") as out:
+    with phase("dryrun_multichip_1x2x2") as out:
         graft.dryrun_multichip(4)
         out["checked"] = ("sharded embedding, ring + ulysses attention, "
                           "MoE, pipeline: shards shrink, collectives found")
@@ -883,7 +872,6 @@ def run(chips: int, sz: Sizes) -> None:
 
     devices = jax.devices()
     cache_dir = setup()
-    clock = CompileClock()
     say(device=devices[0].device_kind, count=len(devices),
         jax=jax.__version__, jaxlib=jaxlib.__version__,
         compile_cache=cache_dir,
@@ -891,21 +879,21 @@ def run(chips: int, sz: Sizes) -> None:
         if os.path.isdir(cache_dir) else 0,
         sizes="full" if sz == FULL else "test-only override")
     if chips == 4:
-        run_four_chips(sz, clock)
+        run_four_chips(sz)
         return
-    with phase(clock, "1_train_image") as out:
+    with phase("1_train_image") as out:
         phase_train_image(sz, out)
-    with phase(clock, "2_train_sequence") as out:
+    with phase("2_train_sequence") as out:
         nmt_params = phase_train_sequence(sz, out)
-    with phase(clock, "3_generate") as out:
+    with phase("3_generate") as out:
         decoder = phase_generate(sz, nmt_params, out)
-    with phase(clock, "4_serve") as out:
+    with phase("4_serve") as out:
         phase_serve(sz, nmt_params, decoder, out)
     # the fused ResNet-50 step needs 14 of the chip's 16 GB: nothing
     # the earlier phases left on the device may stay
     del nmt_params, decoder
     gc.collect()
-    with phase(clock, "5_kernels") as out:
+    with phase("5_kernels") as out:
         phase_kernels(sz, out)
 
 

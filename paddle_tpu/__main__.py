@@ -443,8 +443,9 @@ def cmd_metrics(args):
     useful from code or a REPL. With --stream FILE, summarizes a JSONL
     event stream another process wrote (enable_event_stream /
     METRICS_FILE): event counts by kind, watchdog rungs, the last
-    per-pass timeline record. Deliberately jax-free: inspecting
-    telemetry must not initialize a device runtime."""
+    per-pass timeline record and the last trainer's `setup` record.
+    Deliberately jax-free: inspecting telemetry must not initialize a
+    device runtime."""
     from paddle_tpu.obs import metrics as om
 
     if args.spans:
@@ -463,12 +464,14 @@ def cmd_metrics(args):
             if r.get("kind") == "watchdog":
                 wd[r["event"]] = wd.get(r["event"], 0) + 1
         timelines = [r for r in recs if r.get("kind") == "timeline"]
+        setups = [r for r in recs if r.get("kind") == "setup"]
         summary = {
             "stream": args.stream,
             "events": len(recs),
             "by_kind": kinds,
             "watchdog_events": wd,
             "last_timeline": timelines[-1] if timelines else None,
+            "last_setup": setups[-1] if setups else None,
         }
         if args.json:
             print(json.dumps(summary, indent=2))
@@ -492,6 +495,22 @@ def cmd_metrics(args):
                         100 * t.get("host_overhead_frac", 0),
                         100 * t.get("device_frac", 0),
                         100 * t.get("checkpoint_stall_frac", 0),
+                    )
+                )
+            if setups:
+                u = setups[-1]
+                before = u.get("start_to_build_s")
+                print(
+                    "last setup: before the build %s  build %.2f s  "
+                    "first dispatch %.2f s (trace %.2f lower %.2f "
+                    "compile or load %.2f; cache %d hits %d misses)" % (
+                        "not known" if before is None
+                        else "%.2f s" % before,
+                        u.get("build_s", {}).get("all", 0),
+                        u.get("first_dispatch_s", 0),
+                        u.get("trace_s", 0), u.get("lower_s", 0),
+                        u.get("backend_s", 0),
+                        u.get("cache_hits", 0), u.get("cache_misses", 0),
                     )
                 )
         return 0

@@ -41,13 +41,19 @@ event per pass to the JSONL stream. `end_step` is the always-on
 reader of the spans: a step that took over `SLOW_FACTOR` times the
 median of the last `SLOW_WINDOW` is logged with its split, with or
 without a profiler or a sink.
+
+Before the steps there is set-up, and `process_age_s()` is its first
+number: how old the process was when the trainer began to build.
 """
 
 from __future__ import annotations
 
 import collections
 import logging
+import os
 import statistics
+import time
+from typing import Callable, Optional
 
 from paddle_tpu.obs import metrics as _metrics
 
@@ -67,14 +73,35 @@ SLOW_WINDOW = 32
 SLOW_MIN_HISTORY = 4     # steps before the median means anything
 
 
+def process_age_s() -> Optional[float]:
+    """Seconds since the kernel created this process: its start time
+    in `/proc/self/stat` (field 22, in clock ticks since boot) against
+    the boot clock. Everything that ran before the caller is in it,
+    the interpreter's own start too: imports, the chip's
+    initialisation, whatever the caller did first. None where the
+    platform has no such file or no such clock."""
+    try:
+        with open("/proc/self/stat") as f:
+            fields = f.read().rsplit(")", 1)[1].split()
+        started = int(fields[19]) / os.sysconf("SC_CLK_TCK")
+        return time.clock_gettime(time.CLOCK_BOOTTIME) - started
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+
+
 class StepTimeline:
     def __init__(self, sample_period: int = 16, prefix: str = "trainer",
-                 registry=None):
+                 registry=None,
+                 compile_spent: Optional[Callable] = None):
         """`sample_period`: fence (block_until_ready) every Nth step;
         0 disables fencing (device_step then measures only the result
-        fetches the loop makes anyway)."""
+        fetches the loop makes anyway). `compile_spent(t0_ns, t1_ns)`:
+        what this thread spent compiling in that stretch
+        (`core.compile_cache.spent`, handed in by the trainer: nothing
+        here imports jax); asked of a slow step alone."""
         self.sample_period = int(sample_period)
         self.prefix = prefix
+        self._compile_spent = compile_spent
         self._reg = registry or _metrics.get_registry()
         self._log = logging.getLogger(f"paddle_tpu.{prefix}")
         self._totals_ns = {p: 0 for p in PARTS}
@@ -116,6 +143,12 @@ class StepTimeline:
                 "handlers_s": split.get("train.handlers", 0),
             }
             parts = {k: round(v * 1e-9, 6) for k, v in parts.items()}
+            if self._compile_spent is not None:
+                # a step slow because it recompiled says so
+                spent = self._compile_spent(root.t0_ns, root.t1_ns)
+                parts["compile_s"] = round(
+                    spent["trace_s"] + spent["lower_s"]
+                    + spent["backend_s"], 6)
             self._reg.counter(f"{self.prefix}.slow_steps").inc()
             self._reg.event("slow_step", wall_s=round(wall * 1e-9, 6),
                             median_s=round(median_s, 6),

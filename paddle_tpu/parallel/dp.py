@@ -24,6 +24,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from paddle_tpu.analysis.recompile_guard import RecompileGuard
+from paddle_tpu.core import compile_cache
 from paddle_tpu.core.mesh import DATA_AXIS
 from paddle_tpu.obs import tracing as _tracing
 
@@ -107,6 +108,7 @@ class TrainStep:
         sharding_rules=None,
         watchdog=False,
     ):
+        compile_cache.watch()  # what the step's first call spends
         self.net = net
         self.opt = opt
         self.mesh = mesh

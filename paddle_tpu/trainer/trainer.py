@@ -9,6 +9,7 @@ forwardBackward + updater; the whole mesh runs it SPMD.
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import logging
 from typing import Callable, Optional
@@ -17,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from paddle_tpu.core import compile_cache as _compile_cache
 from paddle_tpu.core import flags as _flags
 from paddle_tpu.core import rng as _rng
 from paddle_tpu.core.config import ModelConf, OptimizationConf
@@ -24,7 +26,7 @@ from paddle_tpu.core.stat import GLOBAL_STATS
 from paddle_tpu.data.reader import Buffered
 from paddle_tpu.obs import metrics as _obs
 from paddle_tpu.obs import tracing as _tracing
-from paddle_tpu.obs.timeline import StepTimeline
+from paddle_tpu.obs.timeline import StepTimeline, process_age_s
 from paddle_tpu.evaluators import create_evaluator
 from paddle_tpu.network import Network
 from paddle_tpu.optimizers import create_optimizer
@@ -233,10 +235,38 @@ class SGD:
         self.steps_per_dispatch = steps_per_dispatch
         self.last_watchdog_report: Optional[wdg.WatchdogReport] = None
         self._resume_skip_batches = 0
-        self.net = Network(model_conf)
         self.opt_conf = opt_conf
-        self.opt = create_optimizer(opt_conf, self.net.param_confs)
         self.mesh = mesh
+        # set-up, told from inside (`_build_part`, `_note_first_dispatch`)
+        self._build_s = {}
+        self._setup_pending = True
+        self._start_to_build_s = process_age_s()
+        if self._start_to_build_s is not None:
+            _obs.get_registry().gauge("process.start_to_build_s").set(
+                self._start_to_build_s)
+        with self._build_part("all"):
+            self._build(model_conf, evaluators, seed, params)
+        self.global_step = 0
+
+    @contextlib.contextmanager
+    def _build_part(self, part: str):
+        """A stretch of the trainer's build under the span
+        `trainer.build.<part>` (the root, `all`: `trainer.build`); its
+        duration is the gauge `trainer.build_s{part=}` and the `setup`
+        event's: one pair of clock reads, three readers."""
+        name = "trainer.build" + ("" if part == "all" else "." + part)
+        with _tracing.span(name) as built:
+            yield
+        self._build_s[part] = built.dur_s
+        _obs.get_registry().gauge("trainer.build_s").set(
+            built.dur_s, part=part)
+
+    def _build(self, model_conf, evaluators, seed, params) -> None:
+        with self._build_part("network"):
+            self.net = Network(model_conf)
+        with self._build_part("optimizer"):
+            self.opt = create_optimizer(
+                self.opt_conf, self.net.param_confs)
         self.evaluator_confs = evaluators or []
         # FP-exception trap (TrainerMain.cpp:49 feenableexcept): jax
         # re-runs NaN-producing ops un-jitted and raises. Set from the
@@ -256,11 +286,14 @@ class SGD:
             "jax_default_prng_impl",
             _flags.get_flag("prng_impl") or _BASE_PRNG_IMPL,
         )
-        key = _rng.root_key(seed or _flags.get_flag("seed"))
-        init_key, self.step_key = jax.random.split(key)
-        self.params = params if params is not None else self.net.init_params(init_key)
-        self.state = self.net.init_state()
-        self.opt_state = self.opt.init_state(self.params)
+        with self._build_part("params"):
+            key = _rng.root_key(seed or _flags.get_flag("seed"))
+            init_key, self.step_key = jax.random.split(key)
+            self.params = (params if params is not None
+                           else self.net.init_params(init_key))
+            self.state = self.net.init_state()
+        with self._build_part("opt_state"):
+            self.opt_state = self.opt.init_state(self.params)
         eval_layers = {
             c[k]
             for c in self.evaluator_confs
@@ -268,13 +301,13 @@ class SGD:
             if k in c
         }
         self.step_fn = TrainStep(
-            self.net, self.opt, mesh=mesh, keep_outputs=eval_layers,
+            self.net, self.opt, mesh=self.mesh, keep_outputs=eval_layers,
             watchdog=self.watchdog_conf is not None,
         )
-        self.params, self.opt_state, self.state = self.step_fn.place(
-            self.params, self.opt_state, self.state
-        )
-        self.global_step = 0
+        with self._build_part("place"):
+            self.params, self.opt_state, self.state = self.step_fn.place(
+                self.params, self.opt_state, self.state
+            )
 
     # ---- eval-only forward (jitted separately, no grad) ----
     def _eval_forward(self, feed):
@@ -373,6 +406,8 @@ class SGD:
                 self.params, self.opt_state, self.state, feed,
                 self.global_step, rng, lr_scale=lr_scale,
             )
+        if self._setup_pending:
+            self._note_first_dispatch(dispatched)
         self.global_step += 1
         if fed is not None:
             fed.place_next()
@@ -384,6 +419,31 @@ class SGD:
                 result = float(loss), True, outs
         self._after_step(timeline, 1, dispatched, fetched, outs)
         return result
+
+    def _note_first_dispatch(self, dispatched) -> None:
+        """The first `train.dispatch` of this trainer's life says what
+        it spent, once: the gauges `trainer.first_dispatch_s` (the
+        span's own duration) and `trainer.first_dispatch.trace_s`,
+        `.lower_s`, `.backend_s`, `.cache_hits`, `.cache_misses` (what
+        the compile watch saw end inside it on this thread: the step's
+        and every function's it pulled in; `backend_s` is a compile
+        where the cache missed and a load where it hit). No later step,
+        pass or `train()` call touches them, nor does a later lowering
+        of the step. With them ONE `setup` event (no-op without a
+        sink): the operator's reading of a restart."""
+        self._setup_pending = False
+        spent = _compile_cache.spent(dispatched.t0_ns, dispatched.t1_ns)
+        reg = _obs.get_registry()
+        reg.gauge("trainer.first_dispatch_s").set(dispatched.dur_s)
+        for key, value in spent.items():
+            reg.gauge("trainer.first_dispatch." + key).set(value)
+        reg.event(
+            "setup",
+            start_to_build_s=self._start_to_build_s,
+            build_s={k: round(v, 6) for k, v in self._build_s.items()},
+            first_dispatch_s=round(dispatched.dur_s, 6),
+            **{k: round(v, 6) for k, v in spent.items()},
+        )
 
     def _after_step(self, timeline, n, dispatched, fetched,
                     outs=None) -> None:
@@ -444,6 +504,8 @@ class SGD:
                 self.params, self.opt_state, self.state, stacked,
                 self.global_step, self.step_key, lr_scale=lr_scale,
             )
+        if self._setup_pending:
+            self._note_first_dispatch(dispatched)
         self.global_step += n
         if fed is not None:
             fed.place_next()
@@ -571,7 +633,8 @@ class SGD:
         # where a session is on. The device is fenced every
         # `timeline_sample_period` steps.
         tl = StepTimeline(
-            sample_period=_flags.get_flag("timeline_sample_period")
+            sample_period=_flags.get_flag("timeline_sample_period"),
+            compile_spent=_compile_cache.spent,
         )
         self.last_timeline = tl
         # one trace per train() call, every step a `train.step` root

@@ -5,12 +5,12 @@ readers of the program's spans and scopes), `test_mellum_cell.py` (the
 cell PR 28 added, at a tiny size), `test_kimi_cell.py` (the cell PR 33
 added, likewise) and `test_phi4flash_cell.py` (PR 35's). They run here as
 they stand there, but
-for the three that `REPLACED` names with the reason: each fails as it stands
+for the six that `REPLACED` names with the reason: each fails as it stands
 since a later PR appended the entries that its issue named, no PR but a
 `benchmark` PR may edit those files, and so each is taken out of this module
 BY NAME (a
 test renamed there fails this module's collection, loudly) and its sense is
-held here by a test of another name. The repair of the three is the first item
+held here by a test of another name. The repair of the six is the first item
 of the next `benchmark` PR (PERF.md section 7). (The override that PR 25
 needed of `test_readers_read_the_run_and_return_nothing_where_nothing_is` is
 gone: PR 27 repaired that test, and it runs here as it stands.)"""
@@ -36,7 +36,16 @@ REPLACED = {
         "appended metric leaves true",
     "test_kimi_cells_files_are_found_and_say_what_the_issue_says":
         "holds PR 33's configuration to be the LAST of configs, which no "
-        "appended configuration leaves true (PR 35 appended one)",
+        "appended configuration leaves true (PR 35 appended one); and its "
+        "metrics to be the cell's only ones (PR 37 appended four)",
+    "test_each_layer_metric_moves_a_metric_its_cells_report":
+        "holds a train cell's per-layer metrics to move train_units_per_s "
+        "alone, and PR 37's four a cell move setup_s (ISSUE 37 named them)",
+    "test_the_cells_files_are_found_and_say_what_the_issue_says":
+        "holds the Mellum cell's metrics to be twelve (PR 37 appended four)",
+    "test_phi_cells_files_are_found_and_say_what_the_issue_says":
+        "holds the Phi cell's metrics to be PR 35's thirteen (PR 37 "
+        "appended four)",
 }
 for _name in REPLACED:
     del globals()[_name]            # KeyError: renamed there; look again
@@ -55,19 +64,60 @@ def test_a_toy_token_cell_is_new_files_beside_the_one_the_tree_has(
         tmp_path)
 
 
+CELLS = {"images": "resnet50.train_bs256",
+         "tokens": "mellum2_12b_ep4.train_seq8192",
+         "mla_tokens": KIMI_CELL, "ssm_tokens": PHI_CELL}
+PR37 = [f"{name}.{suffix}" for suffix in CELLS
+        for name in ("setup_before_build_s", "setup_build_s",
+                     "setup_step_trace_s", "setup_step_load_s")]
+
+
+def _before_pr37(monkeypatch, **cut):
+    """The benchmark as it stood before PR 37's sixteen entries (and with
+    what `cut` replaces): what the tests in `REPLACED` hold, they hold of
+    that."""
+    bench = harness.load_benchmark()
+    assert [m["name"] for m in bench["per_layer"]][-len(PR37):] == PR37
+    was = dict(bench, per_layer=bench["per_layer"][:-len(PR37)], **cut)
+    monkeypatch.setattr(harness, "load_benchmark",
+                        lambda root=harness.ROOT: was)
+    return was
+
+
 def test_kimi_cells_files_say_what_issue_33_says_beside_later_configs(
         monkeypatch):
     """The test as it stands there, on the benchmark cut after PR 33's
-    configuration: what it holds of the cell's files it holds still."""
+    configuration and before PR 37's metrics: what it holds of the cell's
+    files it holds still."""
     from benchmarks.tests import test_kimi_cell as K
 
     bench = harness.load_benchmark()
     at = [c["name"] for c in bench["configs"]].index("kimi_vl_a3b_ep8")
     assert at == 2 and len(bench["configs"]) > 3
-    cut = dict(bench, configs=bench["configs"][: at + 1])
-    monkeypatch.setattr(harness, "load_benchmark",
-                        lambda root=harness.ROOT: cut)
+    _before_pr37(monkeypatch, configs=bench["configs"][: at + 1])
     K.test_kimi_cells_files_are_found_and_say_what_the_issue_says()
+
+
+def test_layer_metrics_move_what_their_cells_report_before_pr37(
+        monkeypatch):
+    from benchmarks.tests import test_harness as H
+
+    H.test_each_layer_metric_moves_a_metric_its_cells_report(
+        _before_pr37(monkeypatch))
+
+
+def test_mellum_cells_files_say_what_issue_28_says_before_pr37(monkeypatch):
+    from benchmarks.tests import test_mellum_cell as M
+
+    _before_pr37(monkeypatch)
+    M.test_the_cells_files_are_found_and_say_what_the_issue_says()
+
+
+def test_phi_cells_files_say_what_issue_35_says_before_pr37(monkeypatch):
+    from benchmarks.tests import test_phi4flash_cell as P
+
+    _before_pr37(monkeypatch)
+    P.test_phi_cells_files_are_found_and_say_what_the_issue_says()
 
 
 PR28 = ["mfu.tokens", "device_idle_share.tokens",
@@ -80,8 +130,8 @@ PR28 = ["mfu.tokens", "device_idle_share.tokens",
 
 def test_pr25s_and_pr28s_metrics_resolve_in_their_order():
     """PR 25's metrics in their order, PR 28's twelve as one run in theirs,
-    followed by PR 33's thirteen and PR 35's thirteen in theirs (appended
-    entries move nothing that was there)."""
+    followed by PR 33's thirteen, PR 35's thirteen and PR 37's sixteen in
+    theirs (appended entries move nothing that was there)."""
     import json
 
     bench = harness.load_benchmark()
@@ -91,7 +141,9 @@ def test_pr25s_and_pr28s_metrics_resolve_in_their_order():
     at = names.index(PR28[0])
     assert names[at: at + len(PR28)] == PR28
     assert names[at + len(PR28): at + len(PR28) + len(PR33)] == PR33
-    assert names[at + len(PR28) + len(PR33):] == PR35
+    at += len(PR28) + len(PR33)
+    assert names[at: at + len(PR35)] == PR35
+    assert names[at + len(PR35):] == PR37
     for name, cell in ([(n, "resnet50.train_bs256") for n in NEW]
                        + [(n, "mellum2_12b_ep4.train_seq8192")
                           for n in PR28]
@@ -111,3 +163,50 @@ def test_pr25s_and_pr28s_metrics_resolve_in_their_order():
         assert {entries[n]["layer"] for n in mine} == {
             "entry points", "device", "step program and model graph",
             "kernels"}
+
+
+def test_pr37s_metrics_read_the_programs_own_setup_seconds(monkeypatch):
+    """Each of the sixteen: seconds that move `setup_s`, which its one cell
+    reports; a data file for the one reader; nothing on a run without a
+    trace's file, nothing from a registry without the series, and their sum
+    from a registry that holds them."""
+    from benchmarks.layer_metrics import program_setup_seconds
+    from paddle_tpu.obs import metrics as om
+
+    bench = harness.load_benchmark()
+    entries = {m["name"]: m for m in bench["per_layer"]}
+    setup = {m["name"]: m for m in bench["end_to_end"]}["setup_s"]
+    layers = {"setup_before_build_s": "entry points",
+              "setup_build_s": "entry points",
+              "setup_step_trace_s": "step program and model graph",
+              "setup_step_load_s": "step program and model graph"}
+    filled = om.MetricsRegistry()
+    filled.gauge("process.start_to_build_s").set(13.0)
+    filled.gauge("trainer.build_s").set(3.5, part="all")
+    filled.gauge("trainer.build_s").set(2.0, part="place")
+    filled.gauge("trainer.first_dispatch.trace_s").set(2.25)
+    filled.gauge("trainer.first_dispatch.lower_s").set(0.5)
+    filled.gauge("trainer.first_dispatch.backend_s").set(1.75)
+    filled.gauge("trainer.first_dispatch_s").set(5.0)
+    want = {"setup_before_build_s": 13.0, "setup_build_s": 3.5,
+            "setup_step_trace_s": 2.75, "setup_step_load_s": 1.75}
+    traced = {"trace_file": "some.xplane.pb", "trace": None, "spans": {},
+              "flops": 0, "window_s": 0.0}
+    for name in PR37:
+        m, (kind, suffix) = entries[name], name.split(".")
+        assert m["workloads"] == [CELLS[suffix]]
+        assert "workloads" not in setup            # every cell reports it
+        assert (m["unit"], m["better"], m["moves"], m["source"],
+                m["layer"]) == ("s", "lower", "setup_s", "program_counter",
+                                layers[kind])
+        reader, data = harness.Cell(CELLS[suffix]).layer_metric(name)
+        assert reader is program_setup_seconds
+        assert os.path.isfile(os.path.join(
+            harness.ROOT, "benchmarks", "layer_metrics", name + ".json"))
+        monkeypatch.setattr(om, "get_registry", lambda: filled)
+        assert reader.read(dict(traced, trace_file=None), data) is None
+        assert reader.read({k: v for k, v in traced.items()
+                            if k != "trace_file"}, data) is None
+        assert reader.read(traced, data) == want[kind]
+        monkeypatch.setattr(om, "get_registry", om.MetricsRegistry)
+        assert reader.read(traced, data) is None   # the parent's registry
