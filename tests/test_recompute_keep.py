@@ -29,7 +29,9 @@ from tests import test_mellum as TM
 from tests import test_phi4flash as TP
 
 # (the model's tests, its builder, the widths at which its kernels fit,
-#  attention layers, Mamba layers)
+#  attention layers, Mamba layers); of the attention layers under a window
+# of 64 at T 128: Mellum's three of four, none of Kimi's, Phi's first
+WINDOWED = {"mellum": 3, "kimi": 0, "phi": 1}
 MODELS = {
     "mellum": (TM, TM.mellum, dict(
         hidden_size=256, num_attention_heads=2, num_key_value_heads=1,
@@ -141,8 +143,10 @@ def on_a_tpu(monkeypatch):
 def test_a_kept_groups_gradient_calls_each_kernel_forward_once(
         model, monkeypatch, gauge, keep_nothing):
     """The kernels (a jaxpr of the gradient at T 128): one forward call a
-    layer, not two, the backward calls as they were; the gauge reads the
-    output and the log-sum-exp (the scan's output and chunk-end states)."""
+    layer, not two, the backward calls as they were (ISSUE 38: a `dq` call
+    only on a window layer; a full causal layer's `dkv` call also produces
+    dq); the gauge reads the output and the log-sum-exp (the scan's output
+    and chunk-end states)."""
     on_a_tpu(monkeypatch)
     _, _, wide, attn, mamba = MODELS[model]
 
@@ -154,10 +158,15 @@ def test_a_kept_groups_gradient_calls_each_kernel_forward_once(
             r"splash_mqa_\w+|selective_scan_\w+|$",
             str(c.params["name"])).group() for c in calls), conf
 
+    passes = get_registry().counter("attn.backward_passes")
+    traced = [passes.get(passes=1), passes.get(passes=2)]
     kept, conf = calls()
     read = gauge()
+    # a traced call a layer, counted with the backward it was traced with
+    assert [passes.get(passes=1) - traced[0], passes.get(passes=2)
+            - traced[1]] == [attn - WINDOWED[model], WINDOWED[model]]
     assert kept["splash_mqa_fwd_residuals"] == attn
-    assert kept["splash_mqa_dq_no_residuals"] == attn
+    assert kept["splash_mqa_dq_no_residuals"] == WINDOWED[model]
     assert kept["splash_mqa_dkv_no_residuals"] == attn
     assert kept["selective_scan_forward"] == mamba
     assert kept["selective_scan_backward"] == mamba

@@ -239,7 +239,8 @@ def test_window_attention_forward_and_gradient(one_chip, monkeypatch, window):
 
     assert _has_kernel(_compile(fwd, q, kv, kv))
     text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
-    assert text.count("tpu_custom_call") >= 3      # forward, dq, dk and dv
+    # a window: forward, dq, dk and dv; full causal: forward, one backward
+    assert text.count("tpu_custom_call") == (3 if window else 2)
 
 
 def test_grouped_matmul_forward_and_gradient(one_chip, monkeypatch):
@@ -331,7 +332,8 @@ def test_latent_attention_forward_and_gradient_at_192_128(one_chip,
     assert _has_kernel(_compile(fwd, qk, qk, v))
     assert jax.eval_shape(fwd, qk, qk, v).shape == (2, 8192, 16, 128)
     text = _compile(jax.grad(loss, argnums=(0, 1, 2)), qk, qk, v)
-    assert text.count("tpu_custom_call") >= 3      # forward, dq, dk and dv
+    # full causal: the forward, and ONE backward that takes the scores once
+    assert text.count("tpu_custom_call") == 2
 
 
 def test_grouped_matmul_at_the_latent_decoders_widths(one_chip, monkeypatch):
@@ -412,19 +414,59 @@ def test_differential_attentions_maps_at_64_128(one_chip, monkeypatch,
     assert _has_kernel(_compile(fwd, q, k, v))
     assert jax.eval_shape(fwd, q, k, v).shape == (2, 8192, 40, 128)
     text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, k, v)
-    assert text.count("tpu_custom_call") >= 3      # forward, dq, dk and dv
+    assert text.count("tpu_custom_call") == (3 if window else 2)
 
 
-@pytest.mark.parametrize("h,kv,d,dv,window", [
-    (32, 4, 128, 128, 1024), (32, 4, 128, 128, None), (16, 16, 192, 128, None),
-    (40, 20, 64, 128, 512)], ids=["mellum-window", "mellum-full", "kimi",
-                                  "phi-window"])
+@pytest.mark.parametrize("window", [None, 1024, 512])
+@pytest.mark.parametrize("g,d,dv", [(8, 128, 128), (1, 192, 128),
+                                    (2, 64, 128), (1, 384, 128)])
+def test_every_tile_of_the_attention_rule_compiles(one_chip, monkeypatch,
+                                                   g, d, dv, window):
+    """The WHOLE table of `kernel_tiles` at T 8,192: every mask of the
+    decoder cells against every head shape (one row, one KV head) and
+    against a head past the width at which the rule halves its largest
+    tile, so that a tile Mosaic refuses (VMEM at a wide head, a block off
+    the tiling) fails here and not on the chip; the calls are the form the
+    mask says."""
+    from paddle_tpu import ops
+    from paddle_tpu.ops.gqa_attention import gqa_attention
+
+    monkeypatch.setattr(ops, "pallas_interpret",
+                        lambda requested=None: False)
+    q = jax.ShapeDtypeStruct((1, 8192, g, d), jnp.bfloat16, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((1, 8192, 1, d), jnp.bfloat16, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, 8192, 1, dv), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(gqa_attention(q, k, v, window=window, impl="pallas"
+                                     ).astype(jnp.float32))
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, k, v)
+    calls = re.findall(r"%(splash_mqa_\w+?)[.\d]* = .*tpu_custom_call", text)
+    assert sorted(calls) == (
+        ["splash_mqa_dkv_no_residuals", "splash_mqa_dq_no_residuals",
+         "splash_mqa_fwd_residuals"] if window else
+        ["splash_mqa_dkv_no_residuals", "splash_mqa_fwd_residuals"])
+
+
+# with what `kernel_tiles` gives each (mask, head) pair of the decoder cells
+# at T 8,192 (the table of PERF.md section 6, PR 38): the forward's and the
+# dkv kernel's (query block, key block, keys a matmul) and the dq kernel's
+# tiles, None where the backward is ONE pass
+_W512 = ((512, 512, 512), (512, 512, 512), (512, 512))
+_FULL = ((1024, 1024, 512), (1024, 1024, 1024), None)
+
+
+@pytest.mark.parametrize("h,kv,d,dv,window,fwd,dkv,dq", [
+    (32, 4, 128, 128, 1024, *_W512), (32, 4, 128, 128, None, *_FULL),
+    (16, 16, 192, 128, None, *_FULL), (40, 20, 64, 128, 512, *_W512)],
+    ids=["mellum-window", "mellum-full", "kimi", "phi-window"])
 def test_attention_hands_the_kernel_each_models_own_widths(
-        monkeypatch, h, kv, d, dv, window):
-    """What the other decoder cells' calls lower to did not move with the
-    64-wide head: the kernel is handed q, k and v at the model's own
-    widths, nothing padded, in tiles of 1,024, whatever the head (the
-    lowering, not a compile: no chip is described for it)."""
+        monkeypatch, h, kv, d, dv, window, fwd, dkv, dq):
+    """The kernel is handed q, k and v at the model's own widths, nothing
+    padded, in the tiles and with the backward the rule gives that mask and
+    head (the lowering, not a compile: no chip is described for it)."""
     from paddle_tpu import ops
     from paddle_tpu.ops import gqa_attention as GA
 
@@ -444,7 +486,13 @@ def test_attention_hands_the_kernel_each_models_own_widths(
     assert GA.pallas_fits(8192, d, dv)
     jaxpr = str(jax.make_jaxpr(lambda q, k, v: GA.gqa_attention(
         q, k, v, window=window, impl="pallas"))(q, k, v))
-    assert made == [(8192, h // kv, window, 1024, 1024, False)]
+    (t, group, w, sizes, interpret), = made
+    assert (t, group, w, interpret) == (8192, h // kv, window, False)
+    assert (sizes.block_q, sizes.block_kv, sizes.block_kv_compute) == fwd
+    assert (sizes.block_q_dkv, sizes.block_kv_dkv,
+            sizes.block_kv_dkv_compute) == dkv
+    assert sizes.use_fused_bwd_kernel == (dq is None)
+    assert (sizes.block_q_dq, sizes.block_kv_dq) == (dq or (None, None))
     assert " pad[" not in jaxpr and "pallas_call" in jaxpr
     assert f"bf16[2,{kv},{h // kv},8192,{d}]" in jaxpr      # q, grouped
     assert f"bf16[2,{kv},8192,{dv}]" in jaxpr               # v, unpadded
@@ -472,9 +520,10 @@ _MIXERS = {
 @pytest.mark.parametrize("mixer", _MIXERS)
 def test_a_recompute_group_calls_its_kernel_forward_once(one_chip,
                                                           monkeypatch, mixer):
-    """Forward, dq, dkv (the scan: forward, backward), ONE of them the
-    forward kernel; under a bare `jax.checkpoint` (`network._KEEP` None)
-    the program before ISSUE 36: one call more, the recomputed forward."""
+    """Forward, dq, dkv on a window layer; forward and ONE backward on a
+    full causal layer (ISSUE 38) and for the scan; ONE of them the forward
+    kernel; under a bare `jax.checkpoint` (`network._KEEP` None) the program
+    before ISSUE 36: one call more, the recomputed forward."""
     import paddle_tpu.network as N
     from paddle_tpu import dsl, ops
     from paddle_tpu.core.arg import Arg
@@ -515,7 +564,7 @@ def test_a_recompute_group_calls_its_kernel_forward_once(one_chip,
         return len(calls), sum(c in FORWARD for c in calls)
 
     FORWARD = ("splash_mqa_fwd_residuals", "selective_scan_forward")
-    want = 2 if kind == "mamba" else 3
+    want = 3 if attrs.get("window") else 2
     assert kernel_calls() == (want, 1)
     monkeypatch.setattr(N, "_KEEP", None)
     assert kernel_calls() == (want + 1, 2)
