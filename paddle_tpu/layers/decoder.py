@@ -2,8 +2,8 @@
 layer (layers/moe.py): `rms_norm`, `layer_norm`, `gqa_attention`,
 `mla_attention`, `diff_attention`, `mamba`, `gated_mlp`, `lm_head_cost`.
 
-`models/mellum.py`, `models/kimi.py` and `models/phi4flash.py` build
-decoders from them through the DSL; their parameter names (`_<layer>.w0`,
+`models/mellum.py`, `models/kimi.py`, `models/phi4flash.py` and
+`models/laguna.py` build decoders from them through the DSL; their parameter names (`_<layer>.w0`,
 `.wq` ...) are what a plain reference's `param_spec` names too, so one set
 of seeded weights serves both.
 """
@@ -90,19 +90,63 @@ class LayerNormLayer(Layer):
         return arg.with_value(y.astype(arg.value.dtype))
 
 
+class _Gauge:
+    """A layer that publishes ONE float a step as a gauge by `layer`: the
+    extra output `<name>@stats` (`Network.stat_outputs`), read at the
+    trainer's fence. Subclasses name it (`gauge`) and set it in forward
+    (`_set_gauge`)."""
+
+    gauge = None
+
+    def extra_output_specs(self):
+        return {f"{self.name}@stats": Spec(dim=(1,))}
+
+    @property
+    def stats_output(self):
+        return f"{self.name}@stats"
+
+    def _set_gauge(self, value) -> None:
+        self._extra_outs = {f"{self.name}@stats": Arg(
+            value=lax.stop_gradient(value).astype(jnp.float32).reshape(1, 1))}
+
+    def publish_stats(self, values, registry) -> None:
+        registry.gauge(self.gauge).set(float(values[0]), layer=self.name)
+
+
 @LAYERS.register("gqa_attention")
-class GQAAttentionLayer(Layer):
+class GQAAttentionLayer(_Gauge, Layer):
     """Causal self-attention with grouped query heads and rotary positions.
 
     attrs: num_heads, num_kv_heads, head_dim; window (a query sees the
     last `window` positions, itself included) or None; rope (the model
     config's group: rope_theta, and for `rope_type: "yarn"` factor,
     original_max_position_embeddings, beta_fast, beta_slow,
-    attention_factor). size = the model width. Params wq [D, H*hd],
-    wk, wv [D, KV*hd], wo [H*hd, D]; no bias. Sequences are taken as
-    packed to their full length: positions past `seq_lens` are computed
-    like any other (causality keeps them out of the real ones) and masked
-    by the cost."""
+    attention_factor; with `partial_rotary_factor` below 1 only that
+    leading part of a head turns); gate: None, or "per_head": each head's
+    output is scaled, a token, by sigmoid(x wg) before `wo` (the logits a
+    float32 accumulation, the sigmoid float32; the gate then multiplies the
+    kernel's output in THAT output's dtype, so that no float32 copy of the
+    [B, T, H, hd] output is written on the way out or back), and the
+    layer publishes the mean gate as the gauge `attn.gate_mean`. size =
+    the model width. Params wq [D, H*hd], wk, wv [D, KV*hd], wo [H*hd, D],
+    with a gate wg [D, H]; no bias. Sequences are taken as packed to their
+    full length: positions past `seq_lens` are computed like any other
+    (causality keeps them out of the real ones) and masked by the cost."""
+
+    gauge = "attn.gate_mean"
+
+    def _gated(self) -> bool:
+        gate = self.conf.attrs.get("gate")
+        assert gate in (None, "per_head"), f"unknown attention gate {gate!r}"
+        return gate is not None
+
+    # a layer without a gate has no gauge and no extra output
+    def extra_output_specs(self):
+        return super().extra_output_specs() if self._gated() else {}
+
+    @property
+    def stats_output(self):
+        return f"{self.name}@stats" if self._gated() else None
 
     def build(self, in_specs):
         (s,) = in_specs
@@ -113,7 +157,8 @@ class GQAAttentionLayer(Layer):
         assert h % kv == 0, f"{h} heads do not divide over {kv} KV heads"
         return Spec(dim=(d,), is_seq=True), _named(self, (
             ("wq", (d, h * hd)), ("wk", (d, kv * hd)),
-            ("wv", (d, kv * hd)), ("wo", (h * hd, d))))
+            ("wv", (d, kv * hd)), ("wo", (h * hd, d)),
+            *((("wg", (d, h)),) if self._gated() else ())))
 
     def forward(self, params, inputs, ctx: Ctx):
         (arg,) = inputs
@@ -125,10 +170,17 @@ class GQAAttentionLayer(Layer):
         k = jnp.dot(x, params["wk"]).reshape(b, t, kv, hd)
         v = jnp.dot(x, params["wv"]).reshape(b, t, kv, hd)
         with jax.named_scope("attn.rope"):
-            cos, sin = _rope.tables(t, hd, a["rope"])
+            cos, sin = _rope.tables(t, _rope.rotary_width(hd, a["rope"]),
+                                    a["rope"])
             q, k = _rope.apply(q, cos, sin), _rope.apply(k, cos, sin)
         with jax.named_scope("attn.core"):
             o = _attn.gqa_attention(q, k, v, window=a.get("window"))
+        if self._gated():
+            with jax.named_scope("attn.gate"):
+                g = jax.nn.sigmoid(jnp.dot(
+                    x, params["wg"], preferred_element_type=jnp.float32))
+                self._set_gauge(jnp.mean(g))
+                o = o * g.astype(o.dtype)[..., None]
         y = jnp.dot(o.reshape(b, t, h * hd), params["wo"])
         return Arg(value=y, seq_lens=arg.seq_lens)
 
@@ -191,29 +243,6 @@ class MLAAttentionLayer(Layer):
         with jax.named_scope("attn.out"):
             y = jnp.dot(o.reshape(b, t, h * dv), params["wo"])
         return Arg(value=y, seq_lens=arg.seq_lens)
-
-
-class _Gauge:
-    """A layer that publishes ONE float a step as a gauge by `layer`: the
-    extra output `<name>@stats` (`Network.stat_outputs`), read at the
-    trainer's fence. Subclasses name it (`gauge`) and set it in forward
-    (`_set_gauge`)."""
-
-    gauge = None
-
-    def extra_output_specs(self):
-        return {f"{self.name}@stats": Spec(dim=(1,))}
-
-    @property
-    def stats_output(self):
-        return f"{self.name}@stats"
-
-    def _set_gauge(self, value) -> None:
-        self._extra_outs = {f"{self.name}@stats": Arg(
-            value=lax.stop_gradient(value).astype(jnp.float32).reshape(1, 1))}
-
-    def publish_stats(self, values, registry) -> None:
-        registry.gauge(self.gauge).set(float(values[0]), layer=self.name)
 
 
 def lambda_init(layer_index: int) -> float:

@@ -21,3 +21,4 @@ from paddle_tpu.models.vae import vae_conf  # noqa: F401
 from paddle_tpu.models.mellum import mellum  # noqa: F401
 from paddle_tpu.models.kimi import kimi  # noqa: F401
 from paddle_tpu.models.phi4flash import phi4flash  # noqa: F401
+from paddle_tpu.models.laguna import laguna  # noqa: F401

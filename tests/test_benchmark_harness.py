@@ -3,15 +3,18 @@
 the readers, a cell added as new files), `test_program_spans.py` (the
 readers of the program's spans and scopes), `test_mellum_cell.py` (the
 cell PR 28 added, at a tiny size), `test_kimi_cell.py` (the cell PR 33
-added, likewise) and `test_phi4flash_cell.py` (PR 35's). They run here as
-they stand there, but
+added, likewise), `test_phi4flash_cell.py` (PR 35's) and
+`test_laguna_cell.py` (PR 39's). They run here as they stand there, but
 for the six that `REPLACED` names with the reason: each fails as it stands
 since a later PR appended the entries that its issue named, no PR but a
 `benchmark` PR may edit those files, and so each is taken out of this module
 BY NAME (a
 test renamed there fails this module's collection, loudly) and its sense is
 held here by a test of another name. The repair of the six is the first item
-of the next `benchmark` PR (PERF.md section 7). (The override that PR 25
+of the next `benchmark` PR (PERF.md section 7). The twins run them on the
+benchmark cut back to where they held: `_before_pr37`, which since PR 39 first
+takes PR 39's own appended entries off (`_before_pr39`: PR 37's sixteen were
+the last of `per_layer` until then). (The override that PR 25
 needed of `test_readers_read_the_run_and_return_nothing_where_nothing_is` is
 gone: PR 27 repaired that test, and it runs here as it stands.)"""
 
@@ -26,6 +29,8 @@ from benchmarks.tests.test_kimi_cell import *  # noqa: F401,F403,E402
 from benchmarks.tests.test_kimi_cell import KIMI_CELL, PR33
 from benchmarks.tests.test_phi4flash_cell import *  # noqa: F401,F403,E402
 from benchmarks.tests.test_phi4flash_cell import PHI_CELL, PR35
+from benchmarks.tests.test_laguna_cell import *  # noqa: F401,F403,E402
+from benchmarks.tests.test_laguna_cell import LAGUNA_CELL, PR39
 
 REPLACED = {
     "test_a_token_counted_training_cell_is_new_files_and_appended_entries":
@@ -33,11 +38,12 @@ REPLACED = {
         "file, and the tree has that file since PR 28 (ISSUE 28 named it)",
     "test_every_new_metric_resolves_to_a_reader_and_a_data_file":
         "holds PR 25's metrics to be the LAST of per_layer, which no "
-        "appended metric leaves true",
+        "appended metric leaves true (PR 39 appended nineteen more)",
     "test_kimi_cells_files_are_found_and_say_what_the_issue_says":
         "holds PR 33's configuration to be the LAST of configs, which no "
-        "appended configuration leaves true (PR 35 appended one); and its "
-        "metrics to be the cell's only ones (PR 37 appended four)",
+        "appended configuration leaves true (PR 35 appended one, PR 39 "
+        "another); and its metrics to be the cell's only ones (PR 37 "
+        "appended four)",
     "test_each_layer_metric_moves_a_metric_its_cells_report":
         "holds a train cell's per-layer metrics to move train_units_per_s "
         "alone, and PR 37's four a cell move setup_s (ISSUE 37 named them)",
@@ -72,11 +78,28 @@ PR37 = [f"{name}.{suffix}" for suffix in CELLS
                      "setup_step_trace_s", "setup_step_load_s")]
 
 
-def _before_pr37(monkeypatch, **cut):
-    """The benchmark as it stood before PR 37's sixteen entries (and with
-    what `cut` replaces): what the tests in `REPLACED` hold, they hold of
-    that."""
+def _before_pr39():
+    """The benchmark as it stood before PR 39's configuration, cell and
+    nineteen metrics, each the last of its list."""
     bench = harness.load_benchmark()
+    assert [m["name"] for m in bench["per_layer"]][-len(PR39):] == PR39
+    assert bench["workloads"][-1]["name"] == LAGUNA_CELL
+    assert bench["configs"][-1]["name"] == "laguna_xs2_ep16"
+    rate = bench["end_to_end"][0]
+    assert rate["workloads"][-1] == LAGUNA_CELL
+    return dict(
+        bench, configs=bench["configs"][:-1],
+        workloads=bench["workloads"][:-1],
+        per_layer=bench["per_layer"][:-len(PR39)],
+        end_to_end=[dict(rate, workloads=rate["workloads"][:-1])]
+        + bench["end_to_end"][1:])
+
+
+def _before_pr37(monkeypatch, **cut):
+    """The benchmark as it stood before PR 37's sixteen entries, which were
+    the last of `per_layer` until PR 39 appended its own (and with what
+    `cut` replaces): what the tests in `REPLACED` hold, they hold of that."""
+    bench = _before_pr39()
     assert [m["name"] for m in bench["per_layer"]][-len(PR37):] == PR37
     was = dict(bench, per_layer=bench["per_layer"][:-len(PR37)], **cut)
     monkeypatch.setattr(harness, "load_benchmark",
@@ -130,8 +153,9 @@ PR28 = ["mfu.tokens", "device_idle_share.tokens",
 
 def test_pr25s_and_pr28s_metrics_resolve_in_their_order():
     """PR 25's metrics in their order, PR 28's twelve as one run in theirs,
-    followed by PR 33's thirteen, PR 35's thirteen and PR 37's sixteen in
-    theirs (appended entries move nothing that was there)."""
+    followed by PR 33's thirteen, PR 35's thirteen, PR 37's sixteen and PR
+    39's nineteen in theirs (appended entries move nothing that was
+    there)."""
     import json
 
     bench = harness.load_benchmark()
@@ -143,7 +167,9 @@ def test_pr25s_and_pr28s_metrics_resolve_in_their_order():
     assert names[at + len(PR28): at + len(PR28) + len(PR33)] == PR33
     at += len(PR28) + len(PR33)
     assert names[at: at + len(PR35)] == PR35
-    assert names[at + len(PR35):] == PR37
+    at += len(PR35)
+    assert names[at: at + len(PR37)] == PR37
+    assert names[at + len(PR37):] == PR39
     for name, cell in ([(n, "resnet50.train_bs256") for n in NEW]
                        + [(n, "mellum2_12b_ep4.train_seq8192")
                           for n in PR28]

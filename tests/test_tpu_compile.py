@@ -450,8 +450,58 @@ def test_every_tile_of_the_attention_rule_compiles(one_chip, monkeypatch,
         ["splash_mqa_dkv_no_residuals", "splash_mqa_fwd_residuals"])
 
 
-# with what `kernel_tiles` gives each (mask, head) pair of the decoder cells
-# at T 8,192 (the table of PERF.md section 6, PR 38): the forward's and the
+# ---- the gated decoder's kernels at the widths of the cell
+# `laguna_xs2_ep16.train_seq8192`: 2 rows of 8,192 positions; 48 query heads
+# on 8 KV heads of 128 under a full causal mask (6 a KV head) and 64 under a
+# window of 512 (8 a KV head); 131,072 slots through 16 held experts of 2,048
+# x 512
+
+@pytest.mark.parametrize("h,window", [(48, None), (64, 512)],
+                         ids=["48-on-8-full", "64-on-8-window-512"])
+def test_gated_attentions_two_head_counts_forward_and_gradient(
+        one_chip, monkeypatch, h, window):
+    from paddle_tpu import ops
+    from paddle_tpu.ops.gqa_attention import gqa_attention
+
+    monkeypatch.setattr(ops, "pallas_interpret",
+                        lambda requested=None: False)
+    q = jax.ShapeDtypeStruct((2, 8192, h, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((2, 8192, 8, 128), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def fwd(q, k, v):
+        return gqa_attention(q, k, v, window=window, impl="pallas")
+
+    def loss(q, k, v):
+        return jnp.sum(fwd(q, k, v).astype(jnp.float32))
+
+    assert _has_kernel(_compile(fwd, q, kv, kv))
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    # a window: forward, dq, dk and dv; full causal: forward, one backward
+    assert text.count("tpu_custom_call") == (3 if window else 2)
+
+
+def test_grouped_matmul_at_sixteen_small_experts(one_chip, monkeypatch):
+    from paddle_tpu import ops
+    from paddle_tpu.ops.moe import grouped_matmul
+
+    monkeypatch.setattr(ops, "pallas_interpret",
+                        lambda requested=None: False)
+    sizes = jax.ShapeDtypeStruct((16,), jnp.int32, sharding=one_chip)
+    for k, n in ((2048, 512), (512, 2048)):        # up and gate; down
+        rows = jax.ShapeDtypeStruct((131072, k), jnp.bfloat16,
+                                    sharding=one_chip)
+        w = jax.ShapeDtypeStruct((16, k, n), jnp.bfloat16, sharding=one_chip)
+
+        def loss(rows, w, sizes):
+            return jnp.sum(grouped_matmul(rows, w, sizes, impl="pallas")
+                           .astype(jnp.float32))
+
+        text = _compile(jax.grad(loss, argnums=(0, 1)), rows, w, sizes)
+        assert text.count("tpu_custom_call") >= 2  # the rows', the weights'
+
+
 # dkv kernel's (query block, key block, keys a matmul) and the dq kernel's
 # tiles, None where the backward is ONE pass
 _W512 = ((512, 512, 512), (512, 512, 512), (512, 512))
@@ -460,8 +510,10 @@ _FULL = ((1024, 1024, 512), (1024, 1024, 1024), None)
 
 @pytest.mark.parametrize("h,kv,d,dv,window,fwd,dkv,dq", [
     (32, 4, 128, 128, 1024, *_W512), (32, 4, 128, 128, None, *_FULL),
-    (16, 16, 192, 128, None, *_FULL), (40, 20, 64, 128, 512, *_W512)],
-    ids=["mellum-window", "mellum-full", "kimi", "phi-window"])
+    (16, 16, 192, 128, None, *_FULL), (40, 20, 64, 128, 512, *_W512),
+    (48, 8, 128, 128, None, *_FULL), (64, 8, 128, 128, 512, *_W512)],
+    ids=["mellum-window", "mellum-full", "kimi", "phi-window",
+         "laguna-full", "laguna-window"])
 def test_attention_hands_the_kernel_each_models_own_widths(
         monkeypatch, h, kv, d, dv, window, fwd, dkv, dq):
     """The kernel is handed q, k and v at the model's own widths, nothing
@@ -507,6 +559,16 @@ _MIXERS = {
     "attention-128-128": ("gqa_attention", dict(
         num_heads=2, num_kv_heads=1, head_dim=128, window=None,
         rope={"rope_theta": 10000})),
+    # a second branch off the block's normed input (the gate) and a rotary
+    # width of half the head (ISSUE 39)
+    "attention-128-128-gated": ("gqa_attention", dict(
+        num_heads=6, num_kv_heads=1, head_dim=128, window=None,
+        gate="per_head", rope={
+            "rope_theta": 500000, "rope_type": "yarn", "factor": 64,
+            "beta_fast": 64, "beta_slow": 1,
+            "original_max_position_embeddings": 4096,
+            "attention_factor": 1.4158883083359672,
+            "partial_rotary_factor": 0.5})),
     "attention-192-128": ("mla_attention", dict(
         num_heads=2, kv_lora_rank=64, qk_nope_head_dim=128,
         qk_rope_head_dim=64, v_head_dim=128, rope_theta=10000)),
