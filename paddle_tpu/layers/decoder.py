@@ -166,15 +166,19 @@ class GQAAttentionLayer(_Gauge, Layer):
         hd, h, kv = a["head_dim"], a["num_heads"], a["num_kv_heads"]
         x = arg.value
         b, t, _ = x.shape
-        q = jnp.dot(x, params["wq"]).reshape(b, t, h, hd)
-        k = jnp.dot(x, params["wk"]).reshape(b, t, kv, hd)
-        v = jnp.dot(x, params["wv"]).reshape(b, t, kv, hd)
-        with jax.named_scope("attn.rope"):
-            cos, sin = _rope.tables(t, _rope.rotary_width(hd, a["rope"]),
-                                    a["rope"])
-            q, k = _rope.apply(q, cos, sin), _rope.apply(k, cos, sin)
-        with jax.named_scope("attn.core"):
-            o = _attn.gqa_attention(q, k, v, window=a.get("window"))
+        r = _rope.rotary_width(hd, a["rope"])
+        if _rope.picks_pass(t, hd, r):
+            o = self._through_the_kernels_layout(params, x, r)
+        else:
+            q = jnp.dot(x, params["wq"]).reshape(b, t, h, hd)
+            k = jnp.dot(x, params["wk"]).reshape(b, t, kv, hd)
+            v = jnp.dot(x, params["wv"]).reshape(b, t, kv, hd)
+            with jax.named_scope("attn.rope"):
+                cos, sin = _rope.tables(t, r, a["rope"])
+                q, k = _rope.apply(q, cos, sin), _rope.apply(k, cos, sin)
+                _rope.note_path("plain", 2)
+            with jax.named_scope("attn.core"):
+                o = _attn.gqa_attention(q, k, v, window=a.get("window"))
         if self._gated():
             with jax.named_scope("attn.gate"):
                 g = jax.nn.sigmoid(jnp.dot(
@@ -183,6 +187,28 @@ class GQAAttentionLayer(_Gauge, Layer):
                 o = o * g.astype(o.dtype)[..., None]
         y = jnp.dot(o.reshape(b, t, h * hd), params["wo"])
         return Arg(value=y, seq_lens=arg.seq_lens)
+
+
+    def _through_the_kernels_layout(self, params, x, r):
+        """[B, T, H, hd], as `gqa_attention` on the turned q and k gives it,
+        where the rotary pass and the kernel both fit: q and k go from the
+        projections' outputs to the kernel's operands in ONE pass each
+        (`ops/rope.to_heads`: turned, q scaled, `[B, n, T, hd]`), with no
+        `[B, T, n, hd]` array, float32 half or transposed copy between."""
+        a = self.conf.attrs
+        hd, h, kv = a["head_dim"], a["num_heads"], a["num_kv_heads"]
+        b, t, _ = x.shape
+        q, k = jnp.dot(x, params["wq"]), jnp.dot(x, params["wk"])
+        v = jnp.dot(x, params["wv"]).reshape(b, t, kv, hd)
+        with jax.named_scope("attn.rope"):
+            cos, sin = _rope.tables(t, r, a["rope"])
+            q = _rope.to_heads(q, cos, sin, h, 1.0 / math.sqrt(hd))
+            k = _rope.to_heads(k, cos, sin, kv)
+        with jax.named_scope("attn.core"):
+            o = _attn.grouped(q.reshape(b, kv, h // kv, t, hd), k,
+                              v.transpose(0, 2, 1, 3),
+                              window=a.get("window"))
+        return o.reshape(b, h, t, hd).transpose(0, 2, 1, 3)
 
 
 @LAYERS.register("mla_attention")

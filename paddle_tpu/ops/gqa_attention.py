@@ -24,6 +24,8 @@ scaled by 1/sqrt(D). Two lowerings (`impl`):
   not the square's), each block rematerialised in the backward pass.
 
 None picks "pallas" on a TPU when the shapes fit its tiles, else "blocked".
+`grouped` is the kernel's call alone, for a caller whose operands are
+already in the kernel's layout.
 
 Both tag what they produce and their backward reads with the name `KEPT`
 (the kernel its output and log-sum-exp, the portable loop its output): a
@@ -162,25 +164,51 @@ def _note_tiles(t, window, in_tiles, sizes) -> None:
         1, passes=1 if sizes.use_fused_bwd_kernel else 2)
 
 
-def _pallas(q, k, v, window, block_q, block_kv, interpret):
-    b, t, h, d = q.shape
-    kv = k.shape[2]
-    g = h // kv
-    sizes = kernel_tiles(t, d, v.shape[-1], window, block_q, block_kv)
+def _require_fit(t, d, dv) -> None:
+    if not pallas_fits(t, d, dv):
+        raise ValueError(
+            f"the attention kernel needs T and the value head in "
+            f"multiples of {LANES}, the query/key head in multiples of "
+            f"{LANES // 2}; got T={t}, D={d}, Dv={dv}")
+
+
+def grouped(qg, kt, vt, *, window=None, block_q=None, block_kv=None,
+            interpret=None):
+    """The kernel on operands that are already its own: qg [B, KV, G, T, D]
+    SCALED by 1/sqrt(D) (the kernel does not scale), kt [B, KV, T, D], vt
+    [B, KV, T, Dv] -> [B, KV, G, T, Dv]. `gqa_attention(impl="pallas")` is
+    this behind a scale and four transposes; a layer whose projections'
+    outputs reach the kernel's layout another way (`ops/rope.to_heads`)
+    calls it directly."""
+    t, d = qg.shape[-2:]
+    dv = vt.shape[-1]
+    _require_fit(t, d, dv)
+    if window is not None and window >= t:
+        window = None
+    sizes = kernel_tiles(t, d, dv, window, block_q, block_kv)
     # the mask's block tables are built with numpy when the kernel is
     # made: outside any trace, so that they are constants of the program
     with jax.ensure_compile_time_eval():
-        kernel, in_tiles = _splash_kernel(t, g, window, sizes,
-                                          bool(interpret))
+        kernel, in_tiles = _splash_kernel(
+            t, qg.shape[2], window, sizes,
+            bool(_ops.pallas_interpret(interpret)))
     _note_tiles(t, window, in_tiles, sizes)
-    # the kernel does not scale: fold 1/sqrt(D) into q
-    qg = (q * (1.0 / math.sqrt(d))).astype(q.dtype)
-    qg = qg.transpose(0, 2, 1, 3).reshape(b, kv, g, t, d)
-    kt, vt = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
     o = jax.vmap(jax.vmap(kernel))(qg, kt, vt)        # [B, KV, G, T, Dv]
     # the library tags the output and, for its backward kernels, the
     # log-sum-exp (float32 a query and head) itself
     _ops.note_kept(o, jax.ShapeDtypeStruct(o.shape[:-1], jnp.float32))
+    return o
+
+
+def _pallas(q, k, v, window, block_q, block_kv, interpret):
+    b, t, h, d = q.shape
+    kv = k.shape[2]
+    # the kernel does not scale: fold 1/sqrt(D) into q
+    qg = (q * (1.0 / math.sqrt(d))).astype(q.dtype)
+    qg = qg.transpose(0, 2, 1, 3).reshape(b, kv, h // kv, t, d)
+    kt, vt = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+    o = grouped(qg, kt, vt, window=window, block_q=block_q,
+                block_kv=block_kv, interpret=interpret)
     return o.reshape(b, h, t, v.shape[-1]).transpose(0, 2, 1, 3)
 
 
@@ -232,11 +260,7 @@ def gqa_attention(q, k, v, *, window=None, impl=None, block_q=None,
         on_tpu = jax.default_backend() == "tpu"
         impl = "pallas" if on_tpu and pallas_fits(t, d, dv) else "blocked"
     if impl == "pallas":
-        if not pallas_fits(t, d, dv):
-            raise ValueError(
-                f"the attention kernel needs T and the value head in "
-                f"multiples of {LANES}, the query/key head in multiples of "
-                f"{LANES // 2}; got T={t}, D={d}, Dv={dv}")
+        _require_fit(t, d, dv)
         return _pallas(q, k, v, window, block_q, block_kv,
                        _ops.pallas_interpret(interpret))
     if impl == "blocked":

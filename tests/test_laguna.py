@@ -287,6 +287,60 @@ def test_a_layer_without_gate_and_partial_rotary_traces_as_the_parents(
     np.testing.assert_array_equal(now(p, x), then(p, x))
 
 
+def _parent_gated_forward(params, x, a):
+    """`GQAAttentionLayer.forward` as PR 39 left it, letter for letter but
+    for the gauge: `rope.apply` over the rotary width, the wrapper, the gate."""
+    hd, h, kv = a["head_dim"], a["num_heads"], a["num_kv_heads"]
+    b, t, _ = x.shape
+    q = jnp.dot(x, params["wq"]).reshape(b, t, h, hd)
+    k = jnp.dot(x, params["wk"]).reshape(b, t, kv, hd)
+    v = jnp.dot(x, params["wv"]).reshape(b, t, kv, hd)
+    cos, sin = rope.tables(t, rope.rotary_width(hd, a["rope"]), a["rope"])
+    q, k = rope.apply(q, cos, sin), rope.apply(k, cos, sin)
+    o = GA.gqa_attention(q, k, v, window=a.get("window"))
+    g = jax.nn.sigmoid(jnp.dot(x, params["wg"],
+                               preferred_element_type=jnp.float32))
+    o = o * g.astype(o.dtype)[..., None]
+    return jnp.dot(o.reshape(b, t, h * hd), params["wo"])
+
+
+@pytest.mark.parametrize("kind", ["full", "window"])
+def test_the_gated_layer_on_the_plain_path_is_the_parents_to_the_bit(kind):
+    """ISSUE 40 gave the layer a second way to the kernel, taken on a TPU
+    where the rotary pass fits. Everywhere else (here) the layer is the
+    parent's: the cell's two kinds, gated, rotary on half a head under YaRN
+    and on the whole head, output and every parameter's gradient bit for
+    bit, and the call counted as `plain`."""
+    from paddle_tpu import obs
+
+    attrs = dict(num_heads=4 if kind == "full" else 8, num_kv_heads=2,
+                 head_dim=16, window=8 if kind == "window" else None,
+                 rope=dict(FULL if kind == "full" else WINDOW),
+                 gate="per_head")
+    net = _attention_layer(**attrs)
+    p = {k: 0.2 * jax.random.normal(jax.random.key(i), tuple(v.dims))
+         for i, (k, v) in enumerate(sorted(net.param_confs.items()))}
+    x = jax.random.normal(jax.random.key(9), (2, 32, 64))
+    calls = obs.get_registry().counter("attn.rope_calls")
+    before = calls.get(path="plain"), calls.get(path="pass")
+
+    def now(p):
+        return net.forward(p, _seq(x))[0]["a"].value
+
+    def then(p):
+        return _parent_gated_forward({k[3:]: v for k, v in p.items()}, x,
+                                     attrs)
+
+    np.testing.assert_array_equal(now(p), then(p))
+    assert (calls.get(path="plain"), calls.get(path="pass")) == (
+        before[0] + 2, before[1])                       # q and k
+    grad = (lambda f: jax.grad(lambda p: jnp.sum(jnp.sin(f(p)))))
+    got, want = grad(now)(p), grad(then)(p)
+    assert sorted(got) == sorted(want) == sorted(p)
+    for name in want:
+        np.testing.assert_array_equal(got[name], want[name], err_msg=name)
+
+
 # ---- rotary positions over a rotary width ----
 
 def test_yarn_blends_over_the_rotary_width_the_config_implies():

@@ -161,6 +161,42 @@ def test_rotary_turns_half_split_pairs_as_the_reference_does(kind):
     np.testing.assert_allclose(n_out, n_in * factor, rtol=1e-5)
 
 
+@pytest.mark.parametrize("kind", ["sliding_attention", "full_attention"])
+def test_the_attention_layer_on_the_plain_path_is_the_parents_to_the_bit(
+        kind):
+    """ISSUE 40 gave `gqa_attention` layers a second way to the kernel,
+    taken on a TPU where the rotary pass fits. Everywhere else (here) the
+    layer is the parent's: this cell's two kinds (4 heads on 2 KV heads of
+    16, the configuration's own rotary groups), output and every parameter's
+    gradient bit for bit, under `jit` as a step runs it."""
+    from tests.test_laguna import _attention_layer, _parent_forward, _seq
+
+    attrs = dict(num_heads=4, num_kv_heads=2, head_dim=16,
+                 window=8 if kind == "sliding_attention" else None,
+                 rope=dict(tiny_cfg()["rope_parameters"][kind]))
+    net = _attention_layer(**attrs)
+    p = {k: 0.2 * jax.random.normal(jax.random.key(i), tuple(v.dims))
+         for i, (k, v) in enumerate(sorted(net.param_confs.items()))}
+    x = jax.random.normal(jax.random.key(9), (2, 32, 64))
+
+    def now(p):
+        return net.forward(p, _seq(x))[0]["a"].value
+
+    def then(p):
+        return _parent_forward({k[3:]: v for k, v in p.items()}, x, attrs)
+
+    def both(f):
+        return jax.jit(lambda p: (f(p), jax.grad(
+            lambda p: jnp.sum(jnp.sin(f(p))))(p)))(p)
+
+    (got, got_g), (want, want_g) = both(now), both(then)
+    np.testing.assert_array_equal(got, want)
+    assert sorted(got_g) == sorted(want_g) == sorted(p)
+    for name in want_g:
+        np.testing.assert_array_equal(got_g[name], want_g[name],
+                                      err_msg=name)
+
+
 # ---- attention: the window's mask, the two lowerings ----
 
 def _dense(q, k, v, window):

@@ -550,6 +550,81 @@ def test_attention_hands_the_kernel_each_models_own_widths(
     assert f"bf16[2,{kv},8192,{dv}]" in jaxpr               # v, unpadded
 
 
+# ---- q and k from the projections' outputs to the kernel's operands in one
+# pass each (ISSUE 40), at the three `gqa_attention` shapes of the cells: 2
+# rows of 8,192 positions, heads of 128
+
+_ROTARY = {
+    "laguna-full": (48, 8, {
+        "rope_theta": 500000, "rope_type": "yarn", "factor": 64,
+        "beta_fast": 64, "beta_slow": 1,
+        "original_max_position_embeddings": 4096,
+        "attention_factor": 1.4158883083359672,
+        "partial_rotary_factor": 0.5}),
+    "laguna-window": (64, 8, {"rope_type": "default", "rope_theta": 10000}),
+    "mellum": (32, 4, {"rope_type": "default", "rope_theta": 500000}),
+}
+
+
+@pytest.mark.parametrize("cell", _ROTARY)
+def test_the_rotary_pass_and_its_backward_move_q_and_k_once(one_chip,
+                                                             monkeypatch,
+                                                             cell):
+    """The pass and its backward compile for the chip with no copy beside
+    them, and the whole way of q and k from projection output to kernel
+    operand (the tables made, q turned and scaled, k turned) moves under 1.5
+    times q and k read once and written once, forward, and back again: the
+    guard that no later change brings the float32 halves back (the plain
+    composition moves 5 to 9 times that; held here too, so that the guard
+    is known to see them)."""
+    import math
+
+    from paddle_tpu import ops
+    from paddle_tpu.ops import rope
+
+    monkeypatch.setattr(ops, "pallas_interpret",
+                        lambda requested=None: False)
+    h, kv, group = _ROTARY[cell]
+    b, t, d = 2, 8192, 128
+    r = rope.rotary_width(d, group)
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def there(impl):
+        def f(q, k):
+            cos, sin = rope.tables(t, r, group)
+            return (rope.to_heads(q, cos, sin, h, 1 / math.sqrt(d),
+                                  impl=impl),
+                    rope.to_heads(k, cos, sin, kv, impl=impl))
+        return f
+
+    def there_and_back(impl):
+        def f(q, k, gq, gk):
+            out, back = jax.vjp(there(impl), q, k)
+            return out, back((gq, gk))
+        return f
+
+    once = 2 * 2 * b * t * (h + kv) * d          # bfloat16, read and written
+    shapes = (sds(b, t, h * d), sds(b, t, kv * d))
+    grads = (sds(b, h, t, d), sds(b, kv, t, d))
+    moved = {}
+    for impl in ("pass", "plain"):
+        fwd = jax.jit(there(impl)).lower(*shapes).compile()
+        both = jax.jit(there_and_back(impl)).lower(*shapes, *grads).compile()
+        moved[impl] = (fwd.cost_analysis()["bytes accessed"] / once,
+                       both.cost_analysis()["bytes accessed"] / (2 * once))
+        if impl == "pass":
+            text = both.as_text()
+            assert text.count("tpu_custom_call") == 4 and " copy(" not in text
+            for name in ("rope_to_heads", "rope_from_heads"):
+                assert len(re.findall(name + r"[\w.]* = .*tpu_custom_call",
+                                      text)) == 2
+            assert "f32[2,8192" not in text
+    assert max(moved["pass"]) < 1.5, moved
+    assert min(moved["plain"]) > 3.0, moved
+
+
 # ---- a recompute group keeps what its kernels produced (ISSUE 36): one
 # block of norm, mixer and residual as a recompute group of a small graph,
 # its gradient compiled for the chip at the three attention widths of the
