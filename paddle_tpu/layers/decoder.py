@@ -1,9 +1,11 @@
 """The layers of a present-day pre-norm decoder block, beside the `moe`
 layer (layers/moe.py): `rms_norm`, `layer_norm`, `gqa_attention`,
-`mla_attention`, `diff_attention`, `mamba`, `gated_mlp`, `lm_head_cost`.
+`mla_attention`, `diff_attention`, `mamba`, `short_conv`, `gated_mlp`,
+`lm_head_cost`.
 
-`models/mellum.py`, `models/kimi.py`, `models/phi4flash.py` and
-`models/laguna.py` build decoders from them through the DSL; their parameter names (`_<layer>.w0`,
+`models/mellum.py`, `models/kimi.py`, `models/phi4flash.py`,
+`models/laguna.py` and `models/lfm2.py` build decoders from them through the
+DSL; their parameter names (`_<layer>.w0`,
 `.wq` ...) are what a plain reference's `param_spec` names too, so one set
 of seeded weights serves both.
 """
@@ -47,6 +49,17 @@ def _rms(v, w, eps):
     y = x * lax.rsqrt(jnp.mean(jnp.square(x), -1, keepdims=True) + eps)
     y = y * w.astype(jnp.float32)
     return y.astype(v.dtype)
+
+
+def _causal_depthwise(x, w, b=None):
+    """A causal depthwise convolution over time, float32: x [B, T, C], w
+    [C, K] -> s_t = b + sum_j w[:, j] x_{t - (K - 1) + j}, zeros before a
+    row's start."""
+    k, t = w.shape[-1], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0))).astype(jnp.float32)
+    w = w.astype(jnp.float32)
+    acc = sum(w[:, j] * padded[:, j: j + t] for j in range(k))
+    return acc if b is None else b + acc
 
 
 @LAYERS.register("rms_norm")
@@ -127,13 +140,17 @@ class GQAAttentionLayer(_Gauge, Layer):
     float32 accumulation, the sigmoid float32; the gate then multiplies the
     kernel's output in THAT output's dtype, so that no float32 copy of the
     [B, T, H, hd] output is written on the way out or back), and the
-    layer publishes the mean gate as the gauge `attn.gate_mean`. size =
+    layer publishes the mean gate as the gauge `attn.gate_mean`; qk_norm
+    (False): an RMS norm over each head's hd lanes of q and of k, with the
+    float32 weights q_norm and k_norm [hd] (1 at the start) and `epsilon`
+    (1e-6), after the projections and before the rotary positions. size =
     the model width. Params wq [D, H*hd], wk, wv [D, KV*hd], wo [H*hd, D],
     with a gate wg [D, H]; no bias. Sequences are taken as packed to their
     full length: positions past `seq_lens` are computed like any other
     (causality keeps them out of the real ones) and masked by the cost."""
 
     gauge = "attn.gate_mean"
+    float32_params = ("q_norm", "k_norm")
 
     def _gated(self) -> bool:
         gate = self.conf.attrs.get("gate")
@@ -158,7 +175,9 @@ class GQAAttentionLayer(_Gauge, Layer):
         return Spec(dim=(d,), is_seq=True), _named(self, (
             ("wq", (d, h * hd)), ("wk", (d, kv * hd)),
             ("wv", (d, kv * hd)), ("wo", (h * hd, d)),
-            *((("wg", (d, h)),) if self._gated() else ())))
+            *((("wg", (d, h)),) if self._gated() else ()),
+            *((("q_norm", (hd,), 1.0), ("k_norm", (hd,), 1.0))
+              if a.get("qk_norm") else ())))
 
     def forward(self, params, inputs, ctx: Ctx):
         (arg,) = inputs
@@ -167,12 +186,18 @@ class GQAAttentionLayer(_Gauge, Layer):
         x = arg.value
         b, t, _ = x.shape
         r = _rope.rotary_width(hd, a["rope"])
-        if _rope.picks_pass(t, hd, r):
+        # the rotary pass has no norm inside: a QK-normed layer goes plain
+        if not a.get("qk_norm") and _rope.picks_pass(t, hd, r):
             o = self._through_the_kernels_layout(params, x, r)
         else:
             q = jnp.dot(x, params["wq"]).reshape(b, t, h, hd)
             k = jnp.dot(x, params["wk"]).reshape(b, t, kv, hd)
             v = jnp.dot(x, params["wv"]).reshape(b, t, kv, hd)
+            if a.get("qk_norm"):
+                with jax.named_scope("attn.qk_norm"):
+                    eps = a.get("epsilon", 1e-6)
+                    q = _rms(q, params["q_norm"], eps)
+                    k = _rms(k, params["k_norm"], eps)
             with jax.named_scope("attn.rope"):
                 cos, sin = _rope.tables(t, r, a["rope"])
                 q, k = _rope.apply(q, cos, sin), _rope.apply(k, cos, sin)
@@ -391,17 +416,12 @@ class MambaLayer(_Gauge, Layer):
     def forward(self, params, inputs, ctx: Ctx):
         (arg,) = inputs
         u = arg.value
-        t = u.shape[1]
-        c, n, k, r = self._sizes(u.shape[-1])
+        c, n, _, r = self._sizes(u.shape[-1])
         with jax.named_scope("ssm.in"):
             xz = jnp.dot(u, params["w_in"])
             xr, z = xz[..., :c], xz[..., c:]
         with jax.named_scope("ssm.conv"):
-            padded = jnp.pad(xr, ((0, 0), (k - 1, 0), (0, 0))).astype(
-                jnp.float32)
-            w = params["conv_w"].astype(jnp.float32)
-            acc = params["conv_b"] + sum(
-                w[:, j] * padded[:, j: j + t] for j in range(k))
+            acc = _causal_depthwise(xr, params["conv_w"], params["conv_b"])
             x = jax.nn.silu(acc).astype(u.dtype)
         with jax.named_scope("ssm.proj"):
             rbc = jnp.dot(x, params["w_x"])
@@ -419,6 +439,50 @@ class MambaLayer(_Gauge, Layer):
         with jax.named_scope("ssm.out"):
             y = jnp.dot(g, params["w_out"])
         return Arg(value=y, seq_lens=arg.seq_lens)
+
+
+@LAYERS.register("short_conv")
+class ShortConvLayer(_Gauge, Layer):
+    """A gated short convolution mixer (the `conv` layers of LFM2).
+
+    attrs: L (3), the convolution's length. size = the model width D.
+    Params, no bias: w_in [D, 3 D], conv_w [D, L], w_out [D, D].
+
+        [B | C | x] = u w_in;  z = B * x
+        s_t = sum_j conv_w[:, j] z_{t - (L - 1) + j}, zeros before a row's
+              start (a causal depthwise convolution over time)
+        y = (C * s) w_out
+
+    The gates, the convolution and C * s are float32; C * s goes to w_out in
+    u's dtype. The state runs through a packed row with no reset at a
+    document's start, as the attention layers see across them. The gauge
+    `conv.mix_absmax` is the largest |C * s|."""
+
+    gauge = "conv.mix_absmax"
+
+    def build(self, in_specs):
+        (s,) = in_specs
+        assert s.is_seq, "short_conv needs a sequence input"
+        d = s.size
+        return Spec(dim=(d,), is_seq=True), _named(self, (
+            ("w_in", (d, 3 * d)), ("conv_w", (d, self.conf.attrs.get("L", 3))),
+            ("w_out", (d, d))))
+
+    def forward(self, params, inputs, ctx: Ctx):
+        (arg,) = inputs
+        u = arg.value
+        d = u.shape[-1]
+        with jax.named_scope("conv.in"):
+            bcx = jnp.dot(u, params["w_in"])
+        with jax.named_scope("conv.mix"):
+            f32 = bcx.astype(jnp.float32)
+            s = _causal_depthwise(f32[..., :d] * f32[..., 2 * d:],
+                                  params["conv_w"])
+            y = f32[..., d: 2 * d] * s
+            self._set_gauge(jnp.max(jnp.abs(y)))
+        with jax.named_scope("conv.out"):
+            out = jnp.dot(y.astype(u.dtype), params["w_out"])
+        return Arg(value=out, seq_lens=arg.seq_lens)
 
 
 @LAYERS.register("gated_mlp")

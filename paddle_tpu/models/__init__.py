@@ -22,3 +22,4 @@ from paddle_tpu.models.mellum import mellum  # noqa: F401
 from paddle_tpu.models.kimi import kimi  # noqa: F401
 from paddle_tpu.models.phi4flash import phi4flash  # noqa: F401
 from paddle_tpu.models.laguna import laguna  # noqa: F401
+from paddle_tpu.models.lfm2 import lfm2  # noqa: F401

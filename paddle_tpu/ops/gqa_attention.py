@@ -64,12 +64,12 @@ BLOCKED_BLOCK_Q = 512
 
 def pallas_fits(t: int, d: int, dv: int = None) -> bool:
     """The kernel's tiles: the sequence in blocks of a lane multiple, the
-    value head a lane multiple, the query/key head a multiple of half a
-    lane tile (64, 192: Mosaic pads the block's last tile itself; compiled
-    for a v5e in tests/test_tpu_compile.py)."""
+    query/key head and the value head multiples of half a lane tile (64,
+    192: Mosaic pads the block's last tile itself; 64/128, 192/128 and
+    64/64 compiled for a v5e in tests/test_tpu_compile.py)."""
     dv = d if dv is None else dv
-    return (t % LANES == 0 and dv % LANES == 0 and d > 0
-            and d % (LANES // 2) == 0)
+    half = LANES // 2
+    return t % LANES == 0 and d > 0 and d % half == 0 and dv % half == 0
 
 
 def _block(t: int, cap: int) -> int:
@@ -167,9 +167,9 @@ def _note_tiles(t, window, in_tiles, sizes) -> None:
 def _require_fit(t, d, dv) -> None:
     if not pallas_fits(t, d, dv):
         raise ValueError(
-            f"the attention kernel needs T and the value head in "
-            f"multiples of {LANES}, the query/key head in multiples of "
-            f"{LANES // 2}; got T={t}, D={d}, Dv={dv}")
+            f"the attention kernel needs T in multiples of {LANES}, the "
+            f"query/key and the value head in multiples of {LANES // 2}; "
+            f"got T={t}, D={d}, Dv={dv}")
 
 
 def grouped(qg, kt, vt, *, window=None, block_q=None, block_kv=None,

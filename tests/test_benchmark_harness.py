@@ -3,8 +3,9 @@
 the readers, a cell added as new files), `test_program_spans.py` (the
 readers of the program's spans and scopes), `test_mellum_cell.py` (the
 cell PR 28 added, at a tiny size), `test_kimi_cell.py` (the cell PR 33
-added, likewise), `test_phi4flash_cell.py` (PR 35's) and
-`test_laguna_cell.py` (PR 39's). They run here as they stand there, but
+added, likewise), `test_phi4flash_cell.py` (PR 35's), `test_laguna_cell.py`
+(PR 39's) and `test_lfm2_cell.py` (PR 41's). They run here as they stand
+there, but
 for the six that `REPLACED` names with the reason: each fails as it stands
 since a later PR appended the entries that its issue named, no PR but a
 `benchmark` PR may edit those files, and so each is taken out of this module
@@ -14,7 +15,8 @@ held here by a test of another name. The repair of the six is the first item
 of the next `benchmark` PR (PERF.md section 7). The twins run them on the
 benchmark cut back to where they held: `_before_pr37`, which since PR 39 first
 takes PR 39's own appended entries off (`_before_pr39`: PR 37's sixteen were
-the last of `per_layer` until then). (The override that PR 25
+the last of `per_layer` until then), and since PR 41 PR 41's before those
+(`_before_pr41`). (The override that PR 25
 needed of `test_readers_read_the_run_and_return_nothing_where_nothing_is` is
 gone: PR 27 repaired that test, and it runs here as it stands.)"""
 
@@ -31,6 +33,8 @@ from benchmarks.tests.test_phi4flash_cell import *  # noqa: F401,F403,E402
 from benchmarks.tests.test_phi4flash_cell import PHI_CELL, PR35
 from benchmarks.tests.test_laguna_cell import *  # noqa: F401,F403,E402
 from benchmarks.tests.test_laguna_cell import LAGUNA_CELL, PR39
+from benchmarks.tests.test_lfm2_cell import *  # noqa: F401,F403,E402
+from benchmarks.tests.test_lfm2_cell import LFM2_CELL, PR41
 
 REPLACED = {
     "test_a_token_counted_training_cell_is_new_files_and_appended_entries":
@@ -78,21 +82,33 @@ PR37 = [f"{name}.{suffix}" for suffix in CELLS
                      "setup_step_trace_s", "setup_step_load_s")]
 
 
-def _before_pr39():
-    """The benchmark as it stood before PR 39's configuration, cell and
-    nineteen metrics, each the last of its list."""
-    bench = harness.load_benchmark()
-    assert [m["name"] for m in bench["per_layer"]][-len(PR39):] == PR39
-    assert bench["workloads"][-1]["name"] == LAGUNA_CELL
-    assert bench["configs"][-1]["name"] == "laguna_xs2_ep16"
+def _cut_last(bench, config, cell, metrics):
+    """`bench` without its last configuration, cell and `metrics`, which
+    must be those named, each the last of its list."""
+    assert [m["name"] for m in bench["per_layer"]][-len(metrics):] == metrics
+    assert bench["workloads"][-1]["name"] == cell
+    assert bench["configs"][-1]["name"] == config
     rate = bench["end_to_end"][0]
-    assert rate["workloads"][-1] == LAGUNA_CELL
+    assert rate["workloads"][-1] == cell
     return dict(
         bench, configs=bench["configs"][:-1],
         workloads=bench["workloads"][:-1],
-        per_layer=bench["per_layer"][:-len(PR39)],
+        per_layer=bench["per_layer"][:-len(metrics)],
         end_to_end=[dict(rate, workloads=rate["workloads"][:-1])]
         + bench["end_to_end"][1:])
+
+
+def _before_pr41():
+    """The benchmark as it stood before PR 41's configuration, cell and
+    twenty metrics, each the last of its list."""
+    return _cut_last(harness.load_benchmark(), "lfm2_8b_a1b_ep4", LFM2_CELL,
+                     PR41)
+
+
+def _before_pr39():
+    """The benchmark as it stood before PR 39's configuration, cell and
+    nineteen metrics, each the last of its list once PR 41's are off."""
+    return _cut_last(_before_pr41(), "laguna_xs2_ep16", LAGUNA_CELL, PR39)
 
 
 def _before_pr37(monkeypatch, **cut):
@@ -153,9 +169,9 @@ PR28 = ["mfu.tokens", "device_idle_share.tokens",
 
 def test_pr25s_and_pr28s_metrics_resolve_in_their_order():
     """PR 25's metrics in their order, PR 28's twelve as one run in theirs,
-    followed by PR 33's thirteen, PR 35's thirteen, PR 37's sixteen and PR
-    39's nineteen in theirs (appended entries move nothing that was
-    there)."""
+    followed by PR 33's thirteen, PR 35's thirteen, PR 37's sixteen, PR
+    39's nineteen and PR 41's twenty in theirs (appended entries move nothing
+    that was there)."""
     import json
 
     bench = harness.load_benchmark()
@@ -169,7 +185,7 @@ def test_pr25s_and_pr28s_metrics_resolve_in_their_order():
     assert names[at: at + len(PR35)] == PR35
     at += len(PR35)
     assert names[at: at + len(PR37)] == PR37
-    assert names[at + len(PR37):] == PR39
+    assert names[at + len(PR37):] == PR39 + PR41
     for name, cell in ([(n, "resnet50.train_bs256") for n in NEW]
                        + [(n, "mellum2_12b_ep4.train_seq8192")
                           for n in PR28]

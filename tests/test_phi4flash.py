@@ -179,7 +179,8 @@ def test_on_a_tpu_the_kernel_is_what_the_cells_shapes_get(monkeypatch):
     assert not SS.pallas_fits(8192, 5000) and not SS.pallas_fits(8200, 5120)
     assert not SS.pallas_fits(8192, 9 * 128)       # 9 tiles: no block of 8
     assert GA.pallas_fits(8192, 64, 128) and GA.pallas_fits(8192, 128)
-    assert not GA.pallas_fits(8192, 64) and not GA.pallas_fits(8192, 32, 128)
+    assert GA.pallas_fits(8192, 64)                 # 64/64 since ISSUE 41
+    assert not GA.pallas_fits(8192, 32, 128) and not GA.pallas_fits(8192, 64, 96)
     taken = []
     monkeypatch.setattr(SS, "_pallas", lambda *a: taken.append(
         (a[0].shape, a[5], a[6])) or SS._chunked(*a[:6]))

@@ -17,6 +17,7 @@ processes; the persistent compile cache is off around them (an
 executable compiled for a described chip cannot be read back without
 one)."""
 
+import math
 import re
 
 import jax
@@ -419,7 +420,8 @@ def test_differential_attentions_maps_at_64_128(one_chip, monkeypatch,
 
 @pytest.mark.parametrize("window", [None, 1024, 512])
 @pytest.mark.parametrize("g,d,dv", [(8, 128, 128), (1, 192, 128),
-                                    (2, 64, 128), (1, 384, 128)])
+                                    (2, 64, 128), (1, 384, 128),
+                                    (4, 64, 64)])
 def test_every_tile_of_the_attention_rule_compiles(one_chip, monkeypatch,
                                                    g, d, dv, window):
     """The WHOLE table of `kernel_tiles` at T 8,192: every mask of the
@@ -511,9 +513,10 @@ _FULL = ((1024, 1024, 512), (1024, 1024, 1024), None)
 @pytest.mark.parametrize("h,kv,d,dv,window,fwd,dkv,dq", [
     (32, 4, 128, 128, 1024, *_W512), (32, 4, 128, 128, None, *_FULL),
     (16, 16, 192, 128, None, *_FULL), (40, 20, 64, 128, 512, *_W512),
-    (48, 8, 128, 128, None, *_FULL), (64, 8, 128, 128, 512, *_W512)],
+    (48, 8, 128, 128, None, *_FULL), (64, 8, 128, 128, 512, *_W512),
+    (32, 8, 64, 64, None, *_FULL)],
     ids=["mellum-window", "mellum-full", "kimi", "phi-window",
-         "laguna-full", "laguna-window"])
+         "laguna-full", "laguna-window", "lfm2"])
 def test_attention_hands_the_kernel_each_models_own_widths(
         monkeypatch, h, kv, d, dv, window, fwd, dkv, dq):
     """The kernel is handed q, k and v at the model's own widths, nothing
@@ -651,6 +654,11 @@ _MIXERS = {
         num_heads=4, num_kv_heads=2, head_dim=64, layer_index=1,
         window=128)),
     "scan": ("mamba", dict(d_state=4, dt_rank=8)),
+    # q and k normed a head before the rotary positions, 64-wide values
+    # (ISSUE 41)
+    "attention-64-64-qk-norm": ("gqa_attention", dict(
+        num_heads=4, num_kv_heads=1, head_dim=64, window=None, qk_norm=True,
+        rope={"rope_theta": 1000000})),
 }
 
 
@@ -705,3 +713,106 @@ def test_a_recompute_group_calls_its_kernel_forward_once(one_chip,
     assert kernel_calls() == (want, 1)
     monkeypatch.setattr(N, "_KEEP", None)
     assert kernel_calls() == (want + 1, 2)
+
+
+# ---- the step of the cell `lfm2_8b_a1b_ep4.train_seq8192` (ISSUE 41), whole:
+# 2 rows of 8,192 positions through four gated short convolutions, one
+# QK-normed attention layer of 32 heads on 8 of 64, a dense block and four
+# expert layers of 8 held experts, Adam and the watchdog, compiled from shapes
+# alone (no parameter is made)
+
+_SIZES = {"f32": 4, "bf16": 2, "s32": 4, "u32": 4, "pred": 1}
+_NOT_MOVED = ("parameter", "get-tuple-element", "tuple", "constant", "bitcast")
+_SHAPE = re.compile(r"\b(f32|bf16|s32|u32|pred)\[([\d,]*)\]")
+
+
+def _bytes_under(text, scopes):
+    """{scope: bytes}: the shapes (result and operands) of the compiled
+    program's top-level operations, those outside any fused computation,
+    whose `op_name` holds the scope: what the step moves through HBM under
+    it, forward, recomputed and backward."""
+    got, comp = dict.fromkeys(scopes, 0), ""
+    for line in text.splitlines():
+        if not line.startswith(" ") and line.endswith("{"):
+            comp = line
+            continue
+        name = re.search(r'op_name="([^"]*)"', line)
+        op = re.match(r"\s*(?:ROOT )?%\S+ = .*? ([a-z][\w\-]*)\(", line)
+        if ("fused" in comp or "wrapped" in comp or not name or not op
+                or op.group(1) in _NOT_MOVED):
+            continue
+        shapes = line.split(", metadata=")[0].split(" = ", 1)[1]
+        n = sum(_SIZES[dt] * math.prod(int(x) for x in dims.split(",") if x)
+                for dt, dims in _SHAPE.findall(shapes.split("calls=")[0]))
+        for s in scopes:
+            if s in name.group(1):
+                got[s] += n
+    return got
+
+
+def test_the_lfm2_cells_step_compiles_and_fits(one_chip, monkeypatch):
+    """The step fits the chip: `memory_analysis()` 10.84 GB (arguments: the
+    float32 parameters and Adam's two moments, 6.09 GB; temporaries 4.74
+    GB); ONE splash forward and ONE one-pass backward (the attention layer,
+    full causal); 36 grouped products and 12 of their weight gradients (4
+    expert layers). The bytes moved under the new scopes, the numbers a later
+    change to them is sized from: `conv.mix` 4.03 GB a step (four layers, the
+    products, the convolution and the gates, forward, recomputed and
+    backward), `attn.qk_norm` 0.20 GB, `attn.rope` 0.81 GB (the plain
+    composition: a 64-wide head is half a lane tile)."""
+    from benchmarks import harness
+    from benchmarks.kinds import train as T
+    from paddle_tpu import ops
+    from paddle_tpu.core import flags
+    from paddle_tpu.core.arg import Arg
+    from paddle_tpu.network import Network
+    from paddle_tpu.optimizers import create_optimizer
+    from paddle_tpu.parallel.dp import TrainStep
+
+    monkeypatch.setattr(ops, "pallas_interpret",
+                        lambda requested=None: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cell = harness.Cell("lfm2_8b_a1b_ep4.train_seq8192")
+    cfg = cell.config
+    was = flags.get_flag("matmul_precision")
+    flags.set_flag("matmul_precision", cfg["matmul_precision"])
+    try:
+        net = Network(cell.model.program_conf(cfg))
+        opt = create_optimizer(T.optimizer(cfg), net.param_confs)
+
+        def sds(a):
+            return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+        params = {k: jax.ShapeDtypeStruct(tuple(pc.dims), jnp.float32,
+                                          sharding=one_chip)
+                  for k, pc in net.param_confs.items()}
+        opt_state = jax.tree_util.tree_map(
+            sds, jax.eval_shape(opt.init_state, params))
+        ints = jax.ShapeDtypeStruct((2, 8192), jnp.int32, sharding=one_chip)
+        lens = jax.ShapeDtypeStruct((2,), jnp.int32, sharding=one_chip)
+        feed = {"ids": Arg(ids=ints, seq_lens=lens),
+                "label": Arg(ids=ints, seq_lens=lens)}
+        rng = sds(jax.eval_shape(lambda: jax.random.key(0)))
+        compiled = TrainStep(net, opt, watchdog=True)._step.lower(
+            params, opt_state, {}, feed,
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip), rng,
+            jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+        ).compile()
+    finally:
+        flags.set_flag("matmul_precision", was)
+    ma = compiled.memory_analysis()
+    total = (ma.temp_size_in_bytes + ma.argument_size_in_bytes
+             + ma.output_size_in_bytes - ma.alias_size_in_bytes)
+    assert 10.0e9 < total < 12.0e9, total
+    assert ma.argument_size_in_bytes == pytest.approx(12 * 507820288, rel=1e-3)
+    text = compiled.as_text()
+    calls = re.findall(r"%([a-z_]+?)[.\d]* = [^\n]*tpu_custom_call", text)
+    assert sorted(set(calls)) == ["gmm", "splash_mqa_dkv_no_residuals",
+                                  "splash_mqa_fwd_residuals", "tgmm"]
+    assert (calls.count("splash_mqa_fwd_residuals"),
+            calls.count("splash_mqa_dkv_no_residuals"),
+            calls.count("gmm"), calls.count("tgmm")) == (1, 1, 36, 12)
+    moved = _bytes_under(text, ("conv.mix", "attn.qk_norm", "attn.rope"))
+    assert 3.0e9 < moved["conv.mix"] < 5.0e9, moved
+    assert 0.1e9 < moved["attn.qk_norm"] < 0.4e9, moved
+    assert 0.5e9 < moved["attn.rope"] < 1.2e9, moved
