@@ -27,6 +27,7 @@ from paddle_tpu.ops import gqa_attention as _attn
 from paddle_tpu.ops import lm_head as _head
 from paddle_tpu.ops import rope as _rope
 from paddle_tpu.ops import selective_scan as _scan
+from paddle_tpu.ops import short_conv as _conv
 
 
 def _named(layer, slots) -> dict:
@@ -49,17 +50,6 @@ def _rms(v, w, eps):
     y = x * lax.rsqrt(jnp.mean(jnp.square(x), -1, keepdims=True) + eps)
     y = y * w.astype(jnp.float32)
     return y.astype(v.dtype)
-
-
-def _causal_depthwise(x, w, b=None):
-    """A causal depthwise convolution over time, float32: x [B, T, C], w
-    [C, K] -> s_t = b + sum_j w[:, j] x_{t - (K - 1) + j}, zeros before a
-    row's start."""
-    k, t = w.shape[-1], x.shape[1]
-    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0))).astype(jnp.float32)
-    w = w.astype(jnp.float32)
-    acc = sum(w[:, j] * padded[:, j: j + t] for j in range(k))
-    return acc if b is None else b + acc
 
 
 @LAYERS.register("rms_norm")
@@ -421,7 +411,8 @@ class MambaLayer(_Gauge, Layer):
             xz = jnp.dot(u, params["w_in"])
             xr, z = xz[..., :c], xz[..., c:]
         with jax.named_scope("ssm.conv"):
-            acc = _causal_depthwise(xr, params["conv_w"], params["conv_b"])
+            acc = _conv.causal_depthwise(xr, params["conv_w"],
+                                         params["conv_b"])
             x = jax.nn.silu(acc).astype(u.dtype)
         with jax.named_scope("ssm.proj"):
             rbc = jnp.dot(x, params["w_x"])
@@ -454,9 +445,10 @@ class ShortConvLayer(_Gauge, Layer):
         y = (C * s) w_out
 
     The gates, the convolution and C * s are float32; C * s goes to w_out in
-    u's dtype. The state runs through a packed row with no reset at a
-    document's start, as the attention layers see across them. The gauge
-    `conv.mix_absmax` is the largest |C * s|."""
+    u's dtype (`ops/short_conv.mix`: on a TPU one Pallas pass each way that
+    reads [B | C | x] as the product gives it). The state runs through a
+    packed row with no reset at a document's start, as the attention layers
+    see across them. The gauge `conv.mix_absmax` is the largest |C * s|."""
 
     gauge = "conv.mix_absmax"
 
@@ -471,17 +463,13 @@ class ShortConvLayer(_Gauge, Layer):
     def forward(self, params, inputs, ctx: Ctx):
         (arg,) = inputs
         u = arg.value
-        d = u.shape[-1]
         with jax.named_scope("conv.in"):
             bcx = jnp.dot(u, params["w_in"])
         with jax.named_scope("conv.mix"):
-            f32 = bcx.astype(jnp.float32)
-            s = _causal_depthwise(f32[..., :d] * f32[..., 2 * d:],
-                                  params["conv_w"])
-            y = f32[..., d: 2 * d] * s
-            self._set_gauge(jnp.max(jnp.abs(y)))
+            y, absmax = _conv.mix(bcx, params["conv_w"])
+            self._set_gauge(absmax)
         with jax.named_scope("conv.out"):
-            out = jnp.dot(y.astype(u.dtype), params["w_out"])
+            out = jnp.dot(y, params["w_out"])
         return Arg(value=out, seq_lens=arg.seq_lens)
 
 

@@ -1,9 +1,9 @@
 """The hybrid decoder of gated short convolutions and QK-normed grouped
 attention over dense and routed-expert blocks (ISSUE 41): `short_conv`, the
-helper `_causal_depthwise` it shares with `mamba`, `gqa_attention` with
-`qk_norm`, the splash kernel at a 64-wide value head, `models/lfm2.py` and
-the `moe` layer under sigmoid scores with a selection bias 32 wide, each alone
-and then together against the plain float32 reference
+helper `causal_depthwise` (ops/short_conv.py) it shares with `mamba`,
+`gqa_attention` with `qk_norm`, the splash kernel at a 64-wide value head,
+`models/lfm2.py` and the `moe` layer under sigmoid scores with a selection
+bias 32 wide, each alone and then together against the plain float32 reference
 `benchmarks/reference/lfm2.py`, at a tiny size on the CPU (hidden 64, 4 query
 heads on 2 KV heads of 16; published layers 0 (conv, dense), 2 (attention)
 and 3 (conv) over 32 experts top 4; T 32), on seeded weights.

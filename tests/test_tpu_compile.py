@@ -726,11 +726,17 @@ _NOT_MOVED = ("parameter", "get-tuple-element", "tuple", "constant", "bitcast")
 _SHAPE = re.compile(r"\b(f32|bf16|s32|u32|pred)\[([\d,]*)\]")
 
 
+def _nbytes(shapes):
+    return sum(_SIZES[dt] * math.prod(int(x) for x in dims.split(",") if x)
+               for dt, dims in shapes)
+
+
 def _bytes_under(text, scopes):
     """{scope: bytes}: the shapes (result and operands) of the compiled
     program's top-level operations, those outside any fused computation,
     whose `op_name` holds the scope: what the step moves through HBM under
-    it, forward, recomputed and backward."""
+    it, forward, recomputed and backward. A kernel's operand that it names
+    more than once (an array read through several blocks) counts once."""
     got, comp = dict.fromkeys(scopes, 0), ""
     for line in text.splitlines():
         if not line.startswith(" ") and line.endswith("{"):
@@ -742,8 +748,15 @@ def _bytes_under(text, scopes):
                 or op.group(1) in _NOT_MOVED):
             continue
         shapes = line.split(", metadata=")[0].split(" = ", 1)[1]
-        n = sum(_SIZES[dt] * math.prod(int(x) for x in dims.split(",") if x)
-                for dt, dims in _SHAPE.findall(shapes.split("calls=")[0]))
+        if op.group(1) == "custom-call":
+            result, rest = shapes.split(" custom-call(", 1)
+            operands = re.sub(r"/\*[^*]*\*/", "", rest.split(")", 1)[0])
+            read = dict(zip([o.strip() for o in operands.split(",")],
+                            _SHAPE.findall(rest.split(
+                                "operand_layout_constraints=", 1)[-1])))
+            n = _nbytes(_SHAPE.findall(result)) + _nbytes(read.values())
+        else:
+            n = _nbytes(_SHAPE.findall(shapes.split("calls=")[0]))
         for s in scopes:
             if s in name.group(1):
                 got[s] += n
@@ -751,15 +764,26 @@ def _bytes_under(text, scopes):
 
 
 def test_the_lfm2_cells_step_compiles_and_fits(one_chip, monkeypatch):
-    """The step fits the chip: `memory_analysis()` 10.84 GB (arguments: the
-    float32 parameters and Adam's two moments, 6.09 GB; temporaries 4.74
-    GB); ONE splash forward and ONE one-pass backward (the attention layer,
-    full causal); 36 grouped products and 12 of their weight gradients (4
-    expert layers). The bytes moved under the new scopes, the numbers a later
-    change to them is sized from: `conv.mix` 4.03 GB a step (four layers, the
-    products, the convolution and the gates, forward, recomputed and
-    backward), `attn.qk_norm` 0.20 GB, `attn.rope` 0.81 GB (the plain
-    composition: a 64-wide head is half a lane tile)."""
+    """The step fits the chip: `memory_analysis()` 10.22 GB (arguments: the
+    float32 parameters and Adam's two moments, 6.09 GB; temporaries 4.12
+    GB; 10.84 GB before the short convolution's pass); ONE splash
+    forward and ONE one-pass backward (the attention layer, full causal); 36
+    grouped products and 12 of their weight gradients (4 expert layers); the
+    short convolution's pass 8 times forward (4 layers, forward and
+    recomputed) and its backward 4 times, with no float32 [2, 8192, 6144]
+    array left and no concatenation of the pieces of its gradient. The bytes
+    moved under the scopes, the numbers a later change to them is sized
+    from: `conv.mix` 4.04 GB a step (the pass reads [B | C | x] in bfloat16
+    and writes y, or the gradient of [B | C | x], in bfloat16: 0.27 GB
+    forward and 0.47 GB backward a layer; the float32 composition before it
+    moved 4.03 GB at a quarter of the HBM's speed), `conv.in` 1.98 GB,
+    `conv.out` 0.84 GB, `attn.qk_norm` 0.20 GB, `attn.rope` 0.81 GB (the
+    plain composition: a 64-wide head is half a lane tile).
+
+    The trace pin (what tracing and lowering the step costs, held without a
+    clock): in the LOWERED program each of the pass's kernels is defined
+    once for all four layers, the forward twice (with the gauge, and
+    recomputed without it), however many layers call it."""
     from benchmarks import harness
     from benchmarks.kinds import train as T
     from paddle_tpu import ops
@@ -793,13 +817,16 @@ def test_the_lfm2_cells_step_compiles_and_fits(one_chip, monkeypatch):
         feed = {"ids": Arg(ids=ints, seq_lens=lens),
                 "label": Arg(ids=ints, seq_lens=lens)}
         rng = sds(jax.eval_shape(lambda: jax.random.key(0)))
-        compiled = TrainStep(net, opt, watchdog=True)._step.lower(
+        lowered = TrainStep(net, opt, watchdog=True)._step.lower(
             params, opt_state, {}, feed,
             jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip), rng,
-            jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
-        ).compile()
+            jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip))
+        compiled = lowered.compile()
     finally:
         flags.set_flag("matmul_precision", was)
+    defined = re.findall(r'kernel_name = "(short_conv\w*)"', lowered.as_text())
+    assert sorted(defined) == ["short_conv_mix", "short_conv_mix",
+                               "short_conv_mix_bwd"]
     ma = compiled.memory_analysis()
     total = (ma.temp_size_in_bytes + ma.argument_size_in_bytes
              + ma.output_size_in_bytes - ma.alias_size_in_bytes)
@@ -807,12 +834,90 @@ def test_the_lfm2_cells_step_compiles_and_fits(one_chip, monkeypatch):
     assert ma.argument_size_in_bytes == pytest.approx(12 * 507820288, rel=1e-3)
     text = compiled.as_text()
     calls = re.findall(r"%([a-z_]+?)[.\d]* = [^\n]*tpu_custom_call", text)
-    assert sorted(set(calls)) == ["gmm", "splash_mqa_dkv_no_residuals",
+    assert sorted(set(calls)) == ["gmm", "short_conv_mix",
+                                  "short_conv_mix_bwd",
+                                  "splash_mqa_dkv_no_residuals",
                                   "splash_mqa_fwd_residuals", "tgmm"]
     assert (calls.count("splash_mqa_fwd_residuals"),
             calls.count("splash_mqa_dkv_no_residuals"),
             calls.count("gmm"), calls.count("tgmm")) == (1, 1, 36, 12)
-    moved = _bytes_under(text, ("conv.mix", "attn.qk_norm", "attn.rope"))
-    assert 3.0e9 < moved["conv.mix"] < 5.0e9, moved
+    assert (calls.count("short_conv_mix"),
+            calls.count("short_conv_mix_bwd")) == (8, 4)
+    assert "f32[2,8192,6144]" not in text
+    # the backward's gradient of [B | C | x] goes to `conv.in`'s two
+    # products as the kernel wrote it: no pass in between
+    grads = re.findall(r"%(\S+) = bf16\[2,8192,6144\]\S* get-tuple-element"
+                       r"\(%short_conv_mix_bwd[.\d]*\), index=0", text)
+    assert len(grads) == 4, grads
+    for grad in grads:
+        users = [line for line in text.splitlines()
+                 if re.search(rf"[(, ]%{re.escape(grad)}[,)]", line)]
+        assert len(users) == 2, users
+        assert all("/conv.in/" in line for line in users), users
+    moved = _bytes_under(text, ("conv.mix", "conv.in", "conv.out",
+                                "attn.qk_norm", "attn.rope"))
+    assert 3.9e9 < moved["conv.mix"] < 4.2e9, moved
+    assert 1.5e9 < moved["conv.in"] < 2.5e9, moved
+    assert 0.5e9 < moved["conv.out"] < 1.2e9, moved
     assert 0.1e9 < moved["attn.qk_norm"] < 0.4e9, moved
     assert 0.5e9 < moved["attn.rope"] < 1.2e9, moved
+
+
+# ---- the short convolution's pass: a kernel body whose size does not grow
+# with the block or the width, so that tracing and lowering it costs the same
+# at any shape (the pin beside the step's above)
+
+def _equations(jaxpr):
+    """Equations of a jaxpr, those of the jaxprs inside them counted too."""
+    from jax.extend import core
+
+    def inner(eqn):
+        for v in eqn.params.values():
+            for x in v if isinstance(v, (tuple, list)) else (v,):
+                if isinstance(x, core.ClosedJaxpr):
+                    yield x.jaxpr
+                elif isinstance(x, core.Jaxpr):
+                    yield x
+
+    return sum(1 + sum(_equations(j) for j in inner(e)) for e in jaxpr.eqns)
+
+
+def _kernel_bodies(jaxpr, found):
+    """{kernel name: equations of its body} of every Pallas call in a
+    jaxpr."""
+    from jax.extend import core
+
+    for e in jaxpr.eqns:
+        if e.primitive.name == "pallas_call":
+            name = e.params["name"]
+            found[str(getattr(name, "name", name))] = _equations(
+                e.params["jaxpr"])
+            continue
+        for v in e.params.values():
+            if isinstance(v, core.ClosedJaxpr):
+                _kernel_bodies(v.jaxpr, found)
+    return found
+
+
+@pytest.mark.parametrize("t,d", [(1024, 256), (8192, 2048), (8192, 4096)])
+def test_the_short_conv_pass_kernels_do_not_grow_with_the_shape(monkeypatch,
+                                                                t, d):
+    """The forward's and the backward's bodies: a fixed number of equations
+    (49 and 98 at L 3) at a small shape, the cell's, and a wider one (the
+    same kernels with their pieces of lanes unrolled held 201 and 407 at the
+    cell's shape)."""
+    from paddle_tpu import ops
+    from paddle_tpu.ops import short_conv
+
+    monkeypatch.setattr(ops, "pallas_interpret",
+                        lambda requested=None: False)
+    bcx = jax.ShapeDtypeStruct((2, t, 3 * d), jnp.bfloat16)
+    w = jax.ShapeDtypeStruct((d, 3), jnp.bfloat16)
+
+    def loss(bcx, w):
+        y, _ = short_conv.mix(bcx, w, impl="pass")
+        return jnp.sum(y.astype(jnp.float32))
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(bcx, w).jaxpr
+    assert _kernel_bodies(jaxpr, {}) == {"short_conv_mix": 49,
+                                         "short_conv_mix_bwd": 98}
