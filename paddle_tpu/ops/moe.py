@@ -192,15 +192,47 @@ def route_topk(x, router_w, top_k: int, norm_topk: bool = True, *,
     return top, idx.astype(jnp.int32)
 
 
+# The grouped products' tiles: rows a tile, and the largest tile of the
+# contracted and the output dimension. A smaller tile than MIN_TILE streams
+# the operand the grid does not tile on that dimension once a tile again.
+LANES, ROW_TILE, TILE_CAP, MIN_TILE = 128, 512, 1024, 512
+
+
 def _tile(n: int, cap: int) -> int:
-    """A tile of a lane multiple for a dimension of n: the largest <= cap
-    that divides n, else cap (the kernel masks the remainder)."""
+    """The tile of a contracted or output dimension of n: n itself where it
+    is at most cap, else the lane multiple in [MIN_TILE, cap] that pads n
+    least, the larger of two that pad it alike. A divisor pads nothing, so
+    the largest divisor there wins; a width with none there (1,408 = 11 x
+    128) takes a tile that covers it in a few pieces, and the kernel masks
+    the remainder."""
     if n <= cap:
         return n
-    for t in range(cap // 128 * 128, 127, -128):
-        if n % t == 0:
-            return t
-    return cap // 128 * 128
+    top = cap // LANES * LANES
+    return min(range(top, min(MIN_TILE, top) - 1, -LANES),
+               key=lambda t: -n % t)
+
+
+def _row_tile(m: int) -> int:
+    """Rows a tile: the kernel's groups are laid out in whole row tiles, so
+    the largest lane multiple <= ROW_TILE that divides m."""
+    if m <= ROW_TILE:
+        return m
+    return next((t for t in range(ROW_TILE, LANES - 1, -LANES)
+                 if m % t == 0), ROW_TILE)
+
+
+def _tiling(m: int, k: int, n: int):
+    """(rows, contracted, output) tiles of a call, and `moe.gmm_calls` by
+    `path` ("divides": every tile divides its dimension; "masked": the
+    kernel masks a remainder), raised once a TRACED call, read by nobody on
+    the step's path."""
+    from paddle_tpu import obs
+
+    tiles = (_row_tile(m), _tile(k, TILE_CAP), _tile(n, TILE_CAP))
+    path = ("divides" if k % tiles[1] == 0 and n % tiles[2] == 0
+            else "masked")
+    obs.get_registry().counter("moe.gmm_calls").inc(1, path=path)
+    return tiles
 
 
 def _megablox():
@@ -217,8 +249,7 @@ def _gmm_kernel(lhs, rhs, group_sizes, transpose_rhs, interpret):
 
     m, k = lhs.shape
     n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
-    tiling = (_tile(m, 512), _tile(k, 1024), _tile(n, 1024))
-    return backend.gmm(lhs, rhs, group_sizes, lhs.dtype, tiling,
+    return backend.gmm(lhs, rhs, group_sizes, lhs.dtype, _tiling(m, k, n),
                        transpose_rhs=transpose_rhs, interpret=interpret)
 
 
@@ -227,10 +258,8 @@ def _tgmm_kernel(lhs, grad, group_sizes, interpret):
     backend = _megablox()
 
     m, k = lhs.shape
-    n = grad.shape[1]
-    tiling = (_tile(m, 512), _tile(k, 1024), _tile(n, 1024))
     return backend.tgmm(lhs.swapaxes(0, 1), grad, group_sizes, lhs.dtype,
-                        tiling, interpret=interpret)
+                        _tiling(m, k, grad.shape[1]), interpret=interpret)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))

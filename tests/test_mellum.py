@@ -375,6 +375,84 @@ def test_the_grouped_matmul_kernel_in_interpret_mode_and_ragged_dot_agree(
     np.testing.assert_allclose(got[5:], lhs[5:105] @ rhs[2], atol=1e-4)
 
 
+def _gmm_calls(path):
+    from paddle_tpu import obs
+
+    return obs.get_registry().counter("moe.gmm_calls").get(path=path)
+
+
+@pytest.fixture
+def small_tiles(monkeypatch):
+    """The rule at a small size: a cap of 256 (its range of tiles is 256
+    alone) and row tiles of 128, so a group straddles row tiles."""
+    monkeypatch.setattr(M, "TILE_CAP", 256)
+    monkeypatch.setattr(M, "ROW_TILE", 128)
+
+
+@pytest.mark.parametrize("f,path", [(384, "masked"), (256, "divides")])
+@pytest.mark.parametrize("kind", ["up", "down"])
+def test_the_kernel_masks_the_remainder_of_a_tile(small_tiles, kind, f,
+                                                  path):
+    """d 256 and f 384 in tiles of 256: "up" [m, 256] @ [G, 256, 384] has
+    a remainder in n (forward, weights' gradient) and in k (rows'
+    gradient); "down" [m, 384] @ [G, 384, 256] the other way round. The
+    forward, the rows' gradient (`transpose_rhs`) and the weights' gradient
+    (`tgmm`) against `lax.ragged_dot`, with empty groups, and NaN planted in
+    the rows past the last group, on the way out and on the way back
+    (interpret mode pads a block past the array's edge with NaN too). f 256
+    divides: the same products with no remainder."""
+    d, sizes = 256, jnp.asarray([100, 0, 150, 0, 130], jnp.int32)
+    k, n = (d, f) if kind == "up" else (f, d)
+    m, held = 512, 380
+    ks = jax.random.split(jax.random.key(3), 3)
+    lhs = jax.random.normal(ks[0], (m, k))
+    rhs = jax.random.normal(ks[1], (5, k, n))
+    g = jax.random.normal(ks[2], (m, n))
+    past = (jnp.arange(m) >= held)[:, None]
+    clean = [jnp.where(past, 0.0, a) for a in (lhs, g)]
+    nan = [jnp.where(past, jnp.nan, a) for a in (lhs, g)]
+
+    def run(impl, lhs, g):
+        out, pull = jax.vjp(
+            lambda a, b: M.grouped_matmul(a, b, sizes, impl=impl), lhs, rhs)
+        return (out, *pull(g))
+
+    before = _gmm_calls("masked"), _gmm_calls("divides")
+    got = run("pallas", *nan)
+    grew = _gmm_calls("masked") - before[0], _gmm_calls("divides") - before[1]
+    # one traced forward, its rows' and its weights' gradient
+    assert grew == ((3, 0) if path == "masked" else (0, 3))
+    before = _gmm_calls("masked"), _gmm_calls("divides")
+    want = run("ragged", *clean)
+    assert (_gmm_calls("masked"), _gmm_calls("divides")) == before
+    for a, b in zip(got[:2], want[:2]):          # the rows past are not defined
+        assert bool(jnp.all(jnp.isfinite(a[:held])))
+        np.testing.assert_allclose(a[:held], b[:held], rtol=2e-5, atol=1e-4)
+    assert bool(jnp.all(jnp.isfinite(got[2])))
+    np.testing.assert_allclose(got[2], want[2], rtol=2e-5, atol=1e-4)
+    assert not np.any(np.asarray(got[2][jnp.asarray([1, 3])]))  # empty groups
+
+
+def test_the_layer_in_masked_tiles_and_ragged_dot_agree(small_tiles):
+    """The whole expert layer at an expert width of 384 in tiles of 256,
+    forward and every gradient, interpret mode against `lax.ragged_dot`."""
+    x, wr, wg, wu, wd = _moe_weights(d=256, f=384)
+
+    def run(impl):
+        def f(x, wr, wg, wu, wd):
+            return M.dropless_moe(x, wr, wg, wu, wd, top_k=2, impl=impl)[0]
+        return f(x, wr, wg, wu, wd), jax.grad(
+            lambda *a: jnp.sum(jnp.sin(f(*a))), (0, 1, 2, 3, 4))(
+                x, wr, wg, wu, wd)
+
+    before = _gmm_calls("masked")
+    (y1, g1), (y2, g2) = run("pallas"), run("ragged")
+    assert _gmm_calls("masked") - before >= 9       # 3 products x 3 kernels
+    for a, b in zip((y1, *g1), (y2, *g2)):
+        np.testing.assert_allclose(
+            a, b, atol=LEAF_TOL * float(jnp.max(jnp.abs(b))))
+
+
 def test_undefined_rows_of_the_row_buffer_never_reach_a_result():
     """The kernel leaves the rows of slots no held expert owns as they lay
     in memory. Planted NaNs there (a grouped product that fills them)

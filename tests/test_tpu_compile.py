@@ -275,7 +275,7 @@ def test_the_expert_layers_passes_run_in_place_chunk_by_chunk(one_chip,
     ISSUE 34: the write ordered before the read of the same chunk)."""
     import re
 
-    from paddle_tpu import ops
+    from paddle_tpu import obs, ops
     from paddle_tpu.ops import moe
 
     monkeypatch.setattr(ops, "pallas_interpret",
@@ -293,10 +293,14 @@ def test_the_expert_layers_passes_run_in_place_chunk_by_chunk(one_chip,
     def loss(*a):
         return jnp.sum(block(*a).astype(jnp.float32) ** 2)
 
+    calls = obs.get_registry().counter("moe.gmm_calls")
+    masked = calls.get(path="masked")
     text = _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
                     shape((n, d)), shape((d, e), jnp.float32),
                     shape((eh, d, h)), shape((eh, d, h)), shape((eh, h, d)))
     assert text.count("tpu_custom_call") >= 9       # 3 products x 3 kernels
+    # 2,304 and 896 take tiles that divide them: nothing is masked
+    assert calls.get(path="masked") == masked and calls.get(path="divides")
     assert len(re.findall(r" while\(", text)) >= 9
     chunk = moe._chunk_of(n * k)
     long = rf"= bf16\[(?:{n * k}|{n * k // chunk},{chunk}),\d+\]\S* "
@@ -337,24 +341,86 @@ def test_latent_attention_forward_and_gradient_at_192_128(one_chip,
     assert text.count("tpu_custom_call") == 2
 
 
+def _spy_on_tiles(monkeypatch):
+    """[(kernel, tiling)] of every grouped-product call traced from here
+    on, handed on to megablox as they were asked for."""
+    from types import SimpleNamespace
+
+    from paddle_tpu.ops import moe
+
+    real, seen = moe._megablox(), []
+
+    def gmm(lhs, rhs, sizes, dtype, tiling, **kw):
+        seen.append(("gmm", tiling))
+        return real.gmm(lhs, rhs, sizes, dtype, tiling, **kw)
+
+    def tgmm(lhs, rhs, sizes, dtype, tiling, **kw):
+        seen.append(("tgmm", tiling))
+        return real.tgmm(lhs, rhs, sizes, dtype, tiling, **kw)
+
+    monkeypatch.setattr(moe, "_megablox",
+                        lambda: SimpleNamespace(gmm=gmm, tgmm=tgmm))
+    return seen
+
+
 def test_grouped_matmul_at_the_latent_decoders_widths(one_chip, monkeypatch):
+    """The tiles the rule gives a 1,408-wide expert (two of 768, the
+    remainder masked, never 128) compile for the chip: no VMEM refusal;
+    the forward, the rows' gradient and the weights' gradient."""
     from paddle_tpu import ops
     from paddle_tpu.ops.moe import grouped_matmul
 
     monkeypatch.setattr(ops, "pallas_interpret",
                         lambda requested=None: False)
+    seen = _spy_on_tiles(monkeypatch)
     sizes = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip)
     for k, n in ((2048, 1408), (1408, 2048)):      # up and gate; down
         rows = jax.ShapeDtypeStruct((98304, k), jnp.bfloat16,
                                     sharding=one_chip)
         w = jax.ShapeDtypeStruct((8, k, n), jnp.bfloat16, sharding=one_chip)
 
-        def loss(rows, w, sizes):
+        def loss(rows, w, sizes):       # squared: the forward is kept
             return jnp.sum(grouped_matmul(rows, w, sizes, impl="pallas")
-                           .astype(jnp.float32))
+                           .astype(jnp.float32) ** 2)
 
+        seen.clear()
         text = _compile(jax.grad(loss, argnums=(0, 1)), rows, w, sizes)
-        assert text.count("tpu_custom_call") >= 2  # the rows', the weights'
+        # the product, the rows' gradient, the weights' gradient
+        assert text.count("tpu_custom_call") == 3
+        tk, tn = (1024, 768) if k == 2048 else (768, 1024)
+        assert seen == [("gmm", (512, tk, tn)), ("gmm", (512, tn, tk)),
+                        ("tgmm", (512, tk, tn))]
+
+
+def test_the_latent_decoders_expert_layer_counts_masked_calls(one_chip,
+                                                             monkeypatch):
+    """The expert layer at the widths of `kimi_vl_a3b_ep8.train_seq8192`,
+    out and back, compiled for the chip: its nine grouped-product calls
+    (an expert of 1,408 in tiles of 768) count into `moe.gmm_calls` as
+    "masked" and none as "divides" (the Mellum cell's widths count
+    "divides" alone: the chunk-by-chunk test above)."""
+    from paddle_tpu import obs, ops
+    from paddle_tpu.ops import moe
+
+    monkeypatch.setattr(ops, "pallas_interpret",
+                        lambda requested=None: False)
+    n, d, e, eh, h, k = 16384, 2048, 64, 8, 1408, 6
+    calls = obs.get_registry().counter("moe.gmm_calls")
+    before = calls.get(path="masked"), calls.get(path="divides")
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def loss(x, wr, wg, wu, wd):
+        y = moe.dropless_moe(x, wr, wg, wu, wd, top_k=k, impl="pallas")[0]
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
+                    shape((n, d)), shape((d, e), jnp.float32),
+                    shape((eh, d, h)), shape((eh, d, h)), shape((eh, h, d)))
+    assert text.count("tpu_custom_call") == 9      # 3 products x 3 kernels
+    assert (calls.get(path="masked") - before[0],
+            calls.get(path="divides") - before[1]) == (9, 0)
 
 
 # ---- the hybrid decoder's kernels at the widths of the cell
