@@ -91,8 +91,6 @@ TINY = Sizes(
 # (PERF.md section 6, PR 21) with room for a rounding, none for a fault
 LOSS_RTOL_4CHIP = 1e-4     # seen 4e-6; one step's descent is 9e-3
 FLASH_TOL = 3e-2           # atol and rtol: kernel dots vs f32-exact dense
-FUSED_LOSS_RTOL = 2e-3     # fused vs plain ResNet-50, first loss; seen 5e-4
-FUSED_KERNEL_RTOL = 1e-2   # bn_act_conv1x1 vs plain jnp, grads too; seen 8e-4
 SPARSE_ATOL = 1e-5         # same f32 arithmetic, row for row
 RESCORE_RTOL = 1e-3        # beam score vs training graph; seen 1.8e-4
 BEAM_SCORE_RTOL = 5e-3     # best beam, K-token vs one-token; seen 2.4e-3
@@ -661,139 +659,12 @@ def _check_sparse(sz: Sizes) -> dict:
             "atol": SPARSE_ATOL}
 
 
-def _fused_params_from_plain(fused_params: dict, plain: dict) -> dict:
-    """resnet(fused=True) names its bottleneck weights anew; the math is
-    the plain graph's, so the plain weights map over by name."""
-    import jax.numpy as jnp
-
-    renames = {
-        "_a.bng": "_a_bn.w0", "_a.bnb": "_a_bn.wbias",
-        "_tail.w0": "_c.w0",
-        "_tail.bnig": "_b_bn.w0", "_tail.bnib": "_b_bn.wbias",
-        "_tail.bnog": "_c_bn.w0", "_tail.bnob": "_c_bn.wbias",
-    }
-    out = {}
-    for k, like in fused_params.items():
-        src = k
-        if k not in plain:
-            suffix = next(s for s in renames if k.endswith(s))
-            src = k[:-len(suffix)] + renames[suffix]
-        # a copy: the plain trainer donates its buffers to its step
-        out[k] = jnp.array(plain[src], copy=True).reshape(like.shape)
-    return out
-
-
-def _check_fused_resnet(sz: Sizes) -> dict:
-    """One train step of resnet(fused=True) — bn_act_conv1x1 forward and
-    backward inside the step program — against the plain graph on the
-    same weights and batch: the kernels are in the program that ran and
-    the two first-step losses agree. That holds the forward kernel;
-    `_check_fused_kernel` holds the gradients."""
-    import jax
-
-    from paddle_tpu.models import resnet
-    from paddle_tpu.network import Network
-    from paddle_tpu.trainer import SGD
-
-    feed = jax.device_put(_image_feed(sz))
-    plain_conf = resnet(depth=50, image_shape=sz.image,
-                        num_classes=sz.classes)
-    fused_conf = resnet(depth=50, image_shape=sz.image,
-                        num_classes=sz.classes, fused=True)
-    plain_params = Network(plain_conf).init_params(jax.random.key(SEED))
-    fused_params = _fused_params_from_plain(
-        Network(fused_conf).init_params(jax.random.key(SEED)), plain_params)
-    # same program as phase 1: the persistent cache hands it back
-    loss_plain = SGD(plain_conf, _resnet_opt(), seed=SEED + 1,
-                     params=plain_params).train_batch(feed)
-    fused = SGD(fused_conf, _resnet_opt(), seed=SEED + 1,
-                params=fused_params)
-    run, text = fused.step_fn.aot(
-        fused.params, fused.opt_state, fused.state, feed, 0,
-        jax.random.key(SEED))
-    kind = _kernel_in(text, "resnet(fused=True) train step")
-    health = np.asarray(run()[3])  # watchdog step: [loss, all_finite]
-    loss_fused = float(health[0])
-    assert health[1] == 1.0 and np.isfinite(loss_fused), health
-    assert abs(loss_fused - loss_plain) <= FUSED_LOSS_RTOL * loss_plain, (
-        f"fused ResNet-50 loss {loss_fused} vs plain {loss_plain}"
-    )
-    return {"kernel": kind, "loss_fused": round(loss_fused, 5),
-            "loss_plain": round(loss_plain, 5), "rtol": FUSED_LOSS_RTOL}
-
-
-def _check_fused_kernel(sz: Sizes) -> list:
-    """bn_act_conv1x1 alone, forward and backward (its custom VJP: two
-    more kernels), at the three sites where resnet(fused=True) calls
-    it, against the plain jnp chain it replaces. Every output and
-    every gradient is compared: a loss cannot see a wrong gradient,
-    and a bf16 ResNet's gradient at random weights is rounding in all
-    but its norm (plain bf16 against plain f32 differ by 130% of it),
-    so the whole step cannot either."""
-    import jax
-    import jax.numpy as jnp
-
-    from paddle_tpu.ops.pallas_fused import bn_act_conv1x1
-
-    def plain(u, scale, shift, w):
-        z = jnp.maximum(u.astype(jnp.float32) * scale + shift, 0.0)
-        y = jnp.dot(z.astype(u.dtype), w.astype(u.dtype),
-                    preferred_element_type=jnp.float32)
-        return y.astype(u.dtype), jnp.sum(y, 0), jnp.sum(y * y, 0)
-
-    def outputs_and_grads(f):
-        def run(args, cotangents):
-            out, vjp = jax.vjp(f, *args)
-            return (*out, *vjp(cotangents))
-
-        return run
-
-    @jax.jit
-    def rel_errors(got, want):
-        """|got - want| / |want| of each output, flattened (L2)."""
-        def f32(x):
-            return x.astype(jnp.float32)
-
-        return [jnp.linalg.norm(f32(g) - f32(w)) / jnp.linalg.norm(f32(w))
-                for g, w in zip(got, want)]
-
-    normal = jax.random.normal
-    sites = []
-    rows = sz.resnet_batch * (sz.image[0] // 4) * (sz.image[1] // 4)
-    for n, cin, cout in ((rows, 64, 256), (rows // 16, 256, 1024),
-                         (rows // 64, 512, 2048)):
-        k = jax.random.split(jax.random.key(SEED + 6), 7)
-        args = (normal(k[0], (n, cin), jnp.bfloat16),
-                jax.random.uniform(k[1], (cin,), minval=0.5, maxval=1.5),
-                0.1 * normal(k[2], (cin,)),
-                normal(k[3], (cin, cout)) / np.sqrt(cin))
-        cotangents = (normal(k[4], (n, cout), jnp.bfloat16),
-                      normal(k[5], (cout,)), 0.01 * normal(k[6], (cout,)))
-        kernel = jax.jit(outputs_and_grads(bn_act_conv1x1)).lower(
-            args, cotangents).compile()
-        errors = rel_errors(
-            kernel(args, cotangents),
-            jax.jit(outputs_and_grads(plain))(args, cotangents))
-        errors = dict(zip(
-            ("y", "sum", "sum_sq", "du", "dscale", "dshift", "dw"),
-            (round(float(e), 6) for e in errors)))
-        assert max(errors.values()) <= FUSED_KERNEL_RTOL, (
-            f"bn_act_conv1x1 vs plain jnp at {(n, cin, cout)}: {errors}")
-        sites.append({"shape": [n, cin, cout], "errors": errors,
-                      "kernel": _kernel_in(kernel.as_text(),
-                                           "bn_act_conv1x1")})
-    return sites
-
-
 def phase_kernels(sz: Sizes, out: dict) -> None:
     """Every Pallas kernel a default path reaches on a TPU really ran:
     it is in the compiled text of the program that ran, and its output
-    agrees with the plain jnp path. (pallas_rnn sits behind a
-    deprecated flag and is left out.)"""
+    agrees with the plain jnp path."""
     out["flash_attention"] = _check_flash(sz)
     out["sparse_updater"] = _check_sparse(sz)
-    out["bn_act_conv1x1"] = {"sites": _check_fused_kernel(sz),
-                             "resnet_step": _check_fused_resnet(sz)}
 
 
 # ------------------------------------------------------- four chips
@@ -865,7 +736,6 @@ def _watchdog():
 def run(chips: int, sz: Sizes) -> None:
     """Every phase, in order, on whatever devices JAX has. `main` has
     checked by now that they are TPU chips."""
-    import gc
 
     import jax
     import jaxlib
@@ -889,10 +759,6 @@ def run(chips: int, sz: Sizes) -> None:
         decoder = phase_generate(sz, nmt_params, out)
     with phase("4_serve") as out:
         phase_serve(sz, nmt_params, decoder, out)
-    # the fused ResNet-50 step needs 14 of the chip's 16 GB: nothing
-    # the earlier phases left on the device may stay
-    del nmt_params, decoder
-    gc.collect()
     with phase("5_kernels") as out:
         phase_kernels(sz, out)
 

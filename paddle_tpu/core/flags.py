@@ -92,8 +92,6 @@ _DEFAULTS: dict[str, Any] = {
     "fleet_burn_min_decisions": 20,
     "fleet_incident_min_interval_s": 60.0,
     "fleet_incident_max_bundles": 8,
-    # kernels: None = auto (fused Pallas cells on TPU, lax.scan elsewhere)
-    "use_pallas_rnn": None,
     # precision policy: params in float32, matmuls in bfloat16 by default
     "default_dtype": "float32",
     "matmul_precision": "default",

@@ -70,8 +70,7 @@ _stack: list = []
 # DSL time (it holds num_filters; spatial dims resolve at build)
 _SIZE_AT_BUILD_ONLY = {
     "exconv", "exconvt", "conv", "cudnn_conv", "conv_operator",
-    "pool", "spp", "maxout", "blockexpand", "fused_conv1x1_bn",
-    "fused_bottleneck_tail",
+    "pool", "spp", "maxout", "blockexpand",
 }
 
 _SIZE_PRESERVING = {
@@ -290,30 +289,6 @@ def conv(x, num_filters, filter_size, stride=1, padding=0, groups=1,
                 param=param, num_filters=num_filters, filter_size=filter_size,
                 stride=stride, padding=padding, groups=groups,
                 dilation=dilation, **kw)
-
-
-def fused_conv1x1_bn(x, num_filters, act="relu", name=None,
-                     use_global_stats=False,
-                     moving_average_fraction=0.9, epsilon=1e-5):
-    """1x1 conv + batch norm with epilogue stats (layers/fused.py —
-    the ResNet bottleneck MFU lever). BN kwargs mirror batch_norm."""
-    return _add("fused_conv1x1_bn", [x], name=name, size=num_filters,
-                act=act, bias=False, use_global_stats=use_global_stats,
-                moving_average_fraction=moving_average_fraction,
-                epsilon=epsilon)
-
-
-def fused_bottleneck_tail(x, num_filters, residual=None, act="relu",
-                          name=None, use_global_stats=False,
-                          moving_average_fraction=0.9, epsilon=1e-5):
-    """BN+ReLU -> 1x1 conv -> BN [+ residual] -> act as one fused layer
-    (layers/fused.py). BN kwargs mirror batch_norm."""
-    ins = [x] if residual is None else [x, residual]
-    return _add("fused_bottleneck_tail", ins, name=name,
-                size=num_filters, act=act, bias=False,
-                use_global_stats=use_global_stats,
-                moving_average_fraction=moving_average_fraction,
-                epsilon=epsilon)
 
 
 def conv_trans(x, num_filters, filter_size, stride=1, padding=0, name=None,

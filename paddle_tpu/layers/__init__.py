@@ -9,8 +9,6 @@ from paddle_tpu.layers import (  # noqa: F401
     decoder,
     detection,
     extras,
-    fused,
-    fused_text,
     moe,
     norm,
     pool,

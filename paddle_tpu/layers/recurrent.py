@@ -5,7 +5,8 @@ with fused CUDA cells (cuda/src/hl_cuda_lstm.cu, hl_gpu_gru.cuh) and
 SequenceToBatch reordering (SequenceToBatch.h) so unequal-length sequences
 advance together without padding.
 
-TPU-first redesign: `lax.scan` over the time axis of a dense [B,T,*] batch.
+TPU-first redesign: the cells are a `lax.scan` over the time axis of a
+dense [B,T,*] batch; that scan is the capability the CUDA cells provide.
 Variable lengths are handled by masked state carry — at a padded timestep
 the hidden/cell state is carried through unchanged and the output is zeroed,
 which reproduces SequenceToBatch semantics exactly (padding can never leak
@@ -25,69 +26,11 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from paddle_tpu import ops
 from paddle_tpu.core.arg import Arg
 from paddle_tpu.core.registry import LAYERS
 from paddle_tpu.layers.base import Layer, Spec
 from paddle_tpu.ops import activations
 from paddle_tpu.ops import sequence_ops as sops
-
-
-def _use_fused(bsz=None, t_max=None, h=None, mult=4) -> bool:
-    """Fused Pallas cell policy: explicit flag only.
-
-    Round-3 interleaved A/B measurement (the pre-chip harness's
-    fused-vs-scan LSTM row: both arms compiled+warmed, alternating
-    timing windows, min per arm — immune to the host-stall bias that
-    produced round 2's contradictory numbers) shows XLA's
-    lax.scan lowering BEATS the fused Pallas kernels on v5e at every
-    tested shape, training AND inference:
-      train  scan/fused: bs128 h256 0.85x, bs128 h512 1.04x (noise),
-             bs128 h1280 0.81x, bs256 h256 0.59x, bs256 h512 0.64x
-      fwd    bs128 h256 0.92x, bs128 h512 0.87x, bs256 h512 0.52x
-    So auto NEVER engages the kernels; they remain available for
-    explicit opt-in (flags.set_flag('use_pallas_rnn', True)) and are
-    correctness-tested in test_pallas_kernels.py. The capability match
-    for cuda/src/hl_cuda_lstm.cu is the kernels' existence; the perf
-    match on TPU is the scan+XLA path.
-
-    The shape parameters are intentionally retained (unused) so call
-    sites keep passing them — if a future XLA/Mosaic shift flips the
-    A/B (no cell measures it: ROADMAP Design 5), the policy slots
-    back in without touching callers.
-
-    Round-6 verdict (ROADMAP 5a, PERF.md "fused-RNN family retired"):
-    the family is formally RETIRED as a production path. The GRU
-    backward was never landed (it recomputes through the scan
-    reference, so fused-GRU training pays kernel forward + scan
-    backward), and the completed LSTM pair loses to the scan at every
-    measured shape — engaging the flag now warns DeprecationWarning
-    once per process. The kernels stay in-tree, correctness-tested, as
-    the hl_cuda_lstm.cu capability match and the A/B tripwire arm."""
-    from paddle_tpu.core.flags import get_flag
-
-    v = get_flag("use_pallas_rnn")
-    if v is not None:
-        if bool(v) and not _WARNED_FUSED_OPTIN:
-            import warnings
-
-            _WARNED_FUSED_OPTIN.append(True)
-            warnings.warn(
-                "use_pallas_rnn=True engages the RETIRED fused Pallas "
-                "RNN path: measured slower than XLA lax.scan at every "
-                "tested shape (PERF.md), and GRU has no fused backward "
-                "(training recomputes through the scan). Kept for "
-                "kernel A/B testing only.",
-                DeprecationWarning,
-                stacklevel=3,
-            )
-        return bool(v)
-    return False
-
-
-# once-per-process latch: a caller that flips the flag per timing
-# window must not get a warning per engaged forward
-_WARNED_FUSED_OPTIN: list = []
 
 
 def _scan_rnn(step, x_btd, seq_lens, init_carry, reverse=False):
@@ -188,28 +131,6 @@ class LstmLayer(Layer):
             gb = jnp.zeros((4 * h,), arg.value.dtype)
             wci = wcf = wco = jnp.zeros((h,), arg.value.dtype)
 
-        default_acts = (
-            (self.conf.active_type or "tanh") == "tanh"
-            and self.conf.attrs.get("active_gate_type", "sigmoid") == "sigmoid"
-            and self.conf.attrs.get("active_state_type", "tanh") == "tanh"
-        )
-        if default_acts and _use_fused(
-            arg.value.shape[0], arg.value.shape[1], h, mult=4
-        ):
-            from paddle_tpu.ops import pallas_rnn
-
-            x = arg.value
-            rev = self.conf.attrs.get("reversed", False)
-            if rev:
-                x = sops.reverse_seq(x, arg.seq_lens)
-            y = pallas_rnn.lstm_fused(
-                x, w, gb, wci, wcf, wco, arg.seq_lens,
-                ops.pallas_interpret(),
-            )
-            if rev:
-                y = sops.reverse_seq(y, arg.seq_lens)
-            return Arg(value=y, seq_lens=arg.seq_lens)
-
         def step(carry, x_t):
             h_prev, c_prev = carry
             g = x_t + jnp.dot(h_prev, w) + gb
@@ -223,7 +144,7 @@ class LstmLayer(Layer):
             return (out, c), out
 
         bsz = arg.value.shape[0]
-        zeros = jnp.zeros((bsz, h), arg.value.dtype)
+        zeros = jnp.zeros((bsz, h), jnp.result_type(arg.value, w))
         y = _scan_rnn(
             step,
             arg.value,
@@ -260,27 +181,6 @@ class GruLayer(Layer):
         w_c = params["w_c"]  # [h, h] candidate
         b = params.get("b", jnp.zeros((3 * h,), arg.value.dtype))
 
-        default_acts = (
-            (self.conf.active_type or "tanh") == "tanh"
-            and self.conf.attrs.get("active_gate_type", "sigmoid") == "sigmoid"
-        )
-        if default_acts and _use_fused(
-            arg.value.shape[0], arg.value.shape[1], h, mult=3
-        ):
-            from paddle_tpu.ops import pallas_rnn
-
-            x = arg.value
-            rev = self.conf.attrs.get("reversed", False)
-            if rev:
-                x = sops.reverse_seq(x, arg.seq_lens)
-            y = pallas_rnn.gru_fused(
-                x, w_g, w_c, b, arg.seq_lens,
-                ops.pallas_interpret(),
-            )
-            if rev:
-                y = sops.reverse_seq(y, arg.seq_lens)
-            return Arg(value=y, seq_lens=arg.seq_lens)
-
         def step(h_prev, x_t):
             xu, xr, xc = jnp.split(x_t + b, 3, axis=-1)
             gur = jnp.dot(h_prev, w_g)
@@ -291,7 +191,7 @@ class GruLayer(Layer):
             return out, out
 
         bsz = arg.value.shape[0]
-        h0 = jnp.zeros((bsz, h), arg.value.dtype)
+        h0 = jnp.zeros((bsz, h), jnp.result_type(arg.value, w_g))
         y = _scan_rnn(
             step, arg.value, arg.seq_lens, h0, self.conf.attrs.get("reversed", False)
         )

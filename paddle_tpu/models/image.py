@@ -127,18 +127,12 @@ def googlenet(image_shape=(224, 224, 3), num_classes=1000) -> ModelConf:
     return g.conf
 
 
-def _bottleneck(name, x, ch, stride, project, fused=False):
+def _bottleneck(name, x, ch, stride, project):
     """ResNet bottleneck: 1x1 -> 3x3 -> 1x1(4ch) + shortcut
-    (v1_api_demo/model_zoo/resnet/resnet.py bottleneck blocks).
-    fused=True routes the stride-1 1x1 sites through the Mosaic
-    fused BN/ReLU/GEMM layers (layers/fused.py, the MFU lever) —
-    same math, fewer HBM passes."""
-    if fused and stride == 1:
-        h = dsl.fused_conv1x1_bn(x, ch, act="relu", name=f"{name}_a")
-    else:
-        h = dsl.conv(x, ch, 1, stride=stride, act="", bias=False,
-                     name=f"{name}_a")
-        h = dsl.batch_norm(h, act="relu", name=f"{name}_a_bn")
+    (v1_api_demo/model_zoo/resnet/resnet.py bottleneck blocks)."""
+    h = dsl.conv(x, ch, 1, stride=stride, act="", bias=False,
+                 name=f"{name}_a")
+    h = dsl.batch_norm(h, act="relu", name=f"{name}_a_bn")
     h = dsl.conv(h, ch, 3, padding=1, act="", bias=False, name=f"{name}_b")
     if project:
         sc = dsl.conv(x, ch * 4, 1, stride=stride, act="", bias=False,
@@ -146,10 +140,6 @@ def _bottleneck(name, x, ch, stride, project, fused=False):
         sc = dsl.batch_norm(sc, act="", name=f"{name}_sc_bn")
     else:
         sc = x
-    if fused:
-        return dsl.fused_bottleneck_tail(
-            h, ch * 4, residual=sc, act="relu", name=f"{name}_tail"
-        )
     h = dsl.batch_norm(h, act="relu", name=f"{name}_b_bn")
     h = dsl.conv(h, ch * 4, 1, act="", bias=False, name=f"{name}_c")
     h = dsl.batch_norm(h, act="", name=f"{name}_c_bn")
@@ -158,9 +148,11 @@ def _bottleneck(name, x, ch, stride, project, fused=False):
 
 def resnet(depth=50, image_shape=(224, 224, 3), num_classes=1000,
            fused=False) -> ModelConf:
-    """ResNet-50/101/152 (v1_api_demo/model_zoo/resnet/resnet.py).
-    fused=True uses the Mosaic fused bottleneck layers (new parameter
-    names — not checkpoint-compatible with the plain graph)."""
+    """ResNet-50/101/152 (v1_api_demo/model_zoo/resnet/resnet.py)."""
+    # `fused` stays only because the benchmark's ResNet cell passes fused=False
+    if fused:
+        raise ValueError("resnet(fused=True): the fused Pallas bottleneck "
+                         "was retired; build the plain graph (fused=False)")
     stages = {
         50: (3, 4, 6, 3),
         101: (3, 4, 23, 3),
@@ -178,7 +170,7 @@ def resnet(depth=50, image_shape=(224, 224, 3), num_classes=1000,
                 stride = 2 if (si > 0 and bi == 0) else 1
                 h = _bottleneck(
                     f"res{si + 2}{chr(ord('a') + bi)}", h, ch, stride,
-                    project=(bi == 0), fused=fused,
+                    project=(bi == 0),
                 )
         final = max(image_shape[0] // 32, 1)  # global avg pool
         h = dsl.pool(h, final, 1, pool_type="avg")

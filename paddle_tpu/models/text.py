@@ -158,22 +158,11 @@ def seq2seq_attention(
     trg_vocab=30000,
     emb_dim=128,
     hidden=256,
-    fused_decoder=False,
 ) -> ModelConf:
     """Attention NMT trainer config (the quick_start seqToseq demo /
     SURVEY.md north-star NMT). Teacher forcing: decoder consumes
     `trg_in` (BOS-prefixed) and is scored against `trg_out` (EOS-suffixed).
-    Encoder hidden size = `hidden` (bidi concat of hidden/2 each).
-
-    fused_decoder=True runs the decoder recurrence as the fused layer
-    (layers/fused_text.py: hoisted input/context projections, merged
-    prev-GEMMs — identical math and parameter names). Built to test
-    the r4 hypothesis that the step was bound on the scan's serial op
-    chain; MEASURED LOSING 0.93x on a healthy chip (PERF.md round 5 —
-    the hypothesis was wrong, XLA's scan lowering was not
-    overhead-bound), so it ships opt-in and the bench NMT row keeps a
-    permanent plain-vs-fused A/B tripwire. False (default) is the
-    generic recurrent_group lowering of the step net."""
+    Encoder hidden size = `hidden` (bidi concat of hidden/2 each)."""
     from paddle_tpu import dsl
     from paddle_tpu.core.config import InputConf, ParameterConf
 
@@ -199,32 +188,20 @@ def seq2seq_attention(
         # backward GRU's output at t=0 has processed the whole source
         # (its scan runs right-to-left and is re-reversed to time order)
         enc_summary = dsl.first_seq(bwd, name="enc_summary")
-        boot = dsl.fc(enc_summary, size=hidden, act="tanh", name="dec_boot")
-        if fused_decoder:
-            trg_emb = dsl.embedding(
-                trg_in, size=emb_dim, vocab_size=trg_vocab,
-                param=ParameterConf(name="trg_emb"),
-                name="trg_emb_lookup",
-            )
-            states = dsl._add(
-                "fused_att_decoder", [trg_emb, enc, boot],
-                name="decoder", size=hidden, bias=True,
-            )
-        else:
-            states = dsl.recurrent_group(
-                step, [trg_in, dsl.StaticInput(enc)], name="decoder"
-            )
+        dsl.fc(enc_summary, size=hidden, act="tanh", name="dec_boot")
+        states = dsl.recurrent_group(
+            step, [trg_in, dsl.StaticInput(enc)], name="decoder"
+        )
         prob = dsl.fc(states, size=trg_vocab, act="softmax",
                       name="dec_prob")
         dsl.cross_entropy(prob, trg_out, name="cost")
         g.conf.output_layer_names.append("dec_prob")
-    if not fused_decoder:
-        # wire the decoder-state boot to the parent layer
-        rg = g.conf.layer("decoder")
-        for m in rg.attrs["memories"]:
-            if m["layer"] == "dec_state":
-                m["boot_layer"] = "dec_boot"
-        rg.inputs.append(InputConf("dec_boot"))
+    # wire the decoder-state boot to the parent layer
+    rg = g.conf.layer("decoder")
+    for m in rg.attrs["memories"]:
+        if m["layer"] == "dec_state":
+            m["boot_layer"] = "dec_boot"
+    rg.inputs.append(InputConf("dec_boot"))
     return g.conf
 
 

@@ -468,124 +468,3 @@ def test_kmax_short_sequence_sentinel():
         outputs=["out"],
     )
     assert np.asarray(outs["out"].ids).tolist() == [[1, 0, -1, -1]]
-
-
-class TestFusedBottleneck:
-    """layers/fused.py — the Mosaic fused bottleneck layers match the
-    plain conv/batch_norm/addto graph numerically (same math, fewer
-    HBM passes; the ResNet-50 MFU lever)."""
-
-    def _tiny_resnetish(self, fused):
-        from paddle_tpu import dsl
-        from paddle_tpu.models.image import _bottleneck
-
-        with dsl.model() as g:
-            img = dsl.data("image", (8, 8, 16))
-            lbl = dsl.data("label", (1,), is_ids=True)
-            h = _bottleneck("blk_a", img, 4, 1, project=True, fused=fused)
-            h = _bottleneck("blk_b", h, 4, 1, project=False, fused=fused)
-            h = dsl.pool(h, 8, 1, pool_type="avg")
-            out = dsl.fc(h, size=3, name="output", act="softmax")
-            dsl.classification_cost(out, lbl, name="cost")
-        return g.conf
-
-    def test_forward_and_grad_parity(self):
-        import jax
-
-        from paddle_tpu.core.arg import id_arg, non_seq
-        from paddle_tpu.network import Network
-
-        plain = Network(self._tiny_resnetish(fused=False))
-        fused = Network(self._tiny_resnetish(fused=True))
-        pp = plain.init_params(jax.random.key(0))
-
-        # copy plain params into the fused layout
-        fp = fused.init_params(jax.random.key(0))
-        ren = {}
-        for blk in ("blk_a", "blk_b"):
-            ren[f"_{blk}_a.w0"] = ("conv", f"_{blk}_a.w0")
-            ren[f"_{blk}_a.bng"] = ("copy", f"_{blk}_a_bn.w0")
-            ren[f"_{blk}_a.bnb"] = ("copy", f"_{blk}_a_bn.wbias")
-            ren[f"_{blk}_tail.w0"] = ("conv", f"_{blk}_c.w0")
-            ren[f"_{blk}_tail.bnig"] = ("copy", f"_{blk}_b_bn.w0")
-            ren[f"_{blk}_tail.bnib"] = ("copy", f"_{blk}_b_bn.wbias")
-            ren[f"_{blk}_tail.bnog"] = ("copy", f"_{blk}_c_bn.w0")
-            ren[f"_{blk}_tail.bnob"] = ("copy", f"_{blk}_c_bn.wbias")
-        for k in fp:
-            if k in ren:
-                kind, src = ren[k]
-                v = pp[src]
-                fp[k] = v.reshape(fp[k].shape) if kind == "conv" else v
-            else:
-                assert k in pp, f"unmapped fused param {k}"
-                fp[k] = pp[k]
-
-        rng = np.random.default_rng(0)
-        feed = {
-            "image": non_seq(
-                jnp.asarray(rng.standard_normal((4, 8, 8, 16)),
-                            jnp.float32)
-            ),
-            "label": id_arg(rng.integers(0, 3, 4).astype(np.int32)),
-        }
-
-        # training forward (batch stats) parity
-        (lp, (op, sp)) = plain.loss_fn(pp, feed, state=plain.init_state(),
-                                       train=True)
-        (lf, (of, sf)) = fused.loss_fn(fp, feed, state=fused.init_state(),
-                                       train=True)
-        np.testing.assert_allclose(float(lp), float(lf), rtol=2e-3)
-
-        # gradient parity on a shared param (the 3x3 conv)
-        def loss_p(params):
-            l, _ = plain.loss_fn(params, feed, state=plain.init_state(),
-                                 train=True)
-            return l
-
-        def loss_f(params):
-            l, _ = fused.loss_fn(params, feed, state=fused.init_state(),
-                                 train=True)
-            return l
-
-        gp = jax.grad(loss_p)(pp)
-        gf = jax.grad(loss_f)(fp)
-        np.testing.assert_allclose(
-            np.asarray(gf["_blk_a_b.w0"]), np.asarray(gp["_blk_a_b.w0"]),
-            rtol=5e-2, atol=5e-4,
-        )
-        # and on a fused-owned param vs its plain counterpart
-        np.testing.assert_allclose(
-            np.asarray(gf["_blk_b_tail.bnig"]),
-            np.asarray(gp["_blk_b_b_bn.w0"]),
-            rtol=5e-2, atol=5e-4,
-        )
-
-    def test_inference_uses_running_stats(self):
-        import jax
-
-        from paddle_tpu.core.arg import id_arg, non_seq
-        from paddle_tpu.network import Network
-
-        net = Network(self._tiny_resnetish(fused=True))
-        params = net.init_params(jax.random.key(1))
-        rng = np.random.default_rng(1)
-        feed = {
-            "image": non_seq(
-                jnp.asarray(rng.standard_normal((2, 8, 8, 16)),
-                            jnp.float32)
-            ),
-            "label": id_arg(rng.integers(0, 3, 2).astype(np.int32)),
-        }
-        st = net.init_state()
-        # two train steps advance the running stats
-        _, (_, st1) = net.loss_fn(params, feed, state=st, train=True)
-        assert not np.allclose(
-            np.asarray(st1["blk_a_tail"]["out_mean"]),
-            np.asarray(st["blk_a_tail"]["out_mean"]),
-        )
-        # eval forward runs (global stats path) and is deterministic
-        o1, _ = net.forward(params, feed, state=st1, train=False)
-        o2, _ = net.forward(params, feed, state=st1, train=False)
-        np.testing.assert_array_equal(
-            np.asarray(o1["output"].value), np.asarray(o2["output"].value)
-        )

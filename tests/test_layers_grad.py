@@ -118,15 +118,19 @@ def test_recurrent_grad():
         check_layer_grad(lc, dcs, feed_for(dcs, batch=3, max_len=4))
 
 
-def test_lstm_grad():
+@pytest.mark.parametrize("rev", [False, True])
+def test_lstm_grad(rev):
     dcs = [data_conf("x", 12, is_seq=True)]
-    lc = LayerConf(name="lstm", type="lstmemory", size=3, inputs=[InputConf("x")])
+    lc = LayerConf(name="lstm", type="lstmemory", size=3, inputs=[InputConf("x")],
+                   attrs={"reversed": rev})
     check_layer_grad(lc, dcs, feed_for(dcs, batch=3, max_len=4))
 
 
-def test_gru_grad():
+@pytest.mark.parametrize("rev", [False, True])
+def test_gru_grad(rev):
     dcs = [data_conf("x", 9, is_seq=True)]
-    lc = LayerConf(name="gru", type="grumemory", size=3, inputs=[InputConf("x")])
+    lc = LayerConf(name="gru", type="grumemory", size=3, inputs=[InputConf("x")],
+                   attrs={"reversed": rev})
     check_layer_grad(lc, dcs, feed_for(dcs, batch=3, max_len=4))
 
 

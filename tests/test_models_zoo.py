@@ -46,6 +46,11 @@ def test_resnet50_param_count():
     assert 24e6 < total < 27e6, total
 
 
+def test_resnet_refuses_the_retired_fused_path():
+    with pytest.raises(ValueError, match="fused=True"):
+        resnet(depth=50, fused=True)
+
+
 def test_lenet_forward_shape():
     conf = lenet()
     net = Network(conf)

@@ -93,49 +93,6 @@ def test_seq2seq_train_and_generate():
     assert correct >= 6, f"only {correct}/8 correct"
 
 
-def test_fused_decoder_matches_recurrent_group():
-    """The fused decoder layer (layers/fused_text.py) is a pure
-    performance lowering: identical parameter names AND identical
-    outputs/loss vs the generic recurrent_group lowering of the same
-    step net, including variable-length masking."""
-    kw = dict(src_vocab=V, trg_vocab=V, emb_dim=E, hidden=H)
-    nf = Network(seq2seq_attention(fused_decoder=True, **kw))
-    nu = Network(seq2seq_attention(fused_decoder=False, **kw))
-    assert set(nf.param_confs) == set(nu.param_confs)
-    params = nf.init_params(jax.random.key(0))
-    rng = np.random.default_rng(3)
-    src, src_l, ti, to, tl = make_batch(rng, 6)
-    feed = {
-        "src": id_arg(src, src_l),
-        "trg_in": id_arg(ti, tl),
-        "trg_out": id_arg(to, tl),
-    }
-    # ONE value_and_grad program per model yields loss, grads AND the
-    # dec_prob output (aux) — 2 compiles instead of 6 keeps the suite
-    # inside its wall budget
-    def run(net):
-        (loss, (outs, _st)), grads = jax.jit(
-            jax.value_and_grad(
-                lambda p: net.loss_fn(p, feed), has_aux=True
-            )
-        )(params)
-        return loss, outs["dec_prob"].value, grads
-
-    lf, pf, gf = run(nf)
-    lu, pu, gu = run(nu)
-    t = ti.shape[1]
-    m = np.arange(t)[None, :, None] < tl[:, None, None]
-    np.testing.assert_allclose(
-        np.asarray(pf) * m, np.asarray(pu) * m, rtol=1e-5, atol=1e-6,
-    )
-    np.testing.assert_allclose(float(lf), float(lu), rtol=1e-6)
-    # gradients agree too (the scan/einsum backward path)
-    for k in gf:
-        np.testing.assert_allclose(
-            np.asarray(gf[k]), np.asarray(gu[k]), rtol=2e-4, atol=2e-5,
-        )
-
-
 def test_dsl_simple_attention_in_group():
     """dsl.simple_attention (networks.py:1298) builds the same additive
     attention the seq2seq model inlines; a decoder step using it trains."""

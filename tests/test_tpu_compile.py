@@ -93,34 +93,32 @@ RESNET_1X1 = [(802816, 64, 256), (50176, 256, 1024), (12544, 512, 2048)]
 
 
 @pytest.mark.parametrize("n,cin,cout", RESNET_1X1)
-def test_bn_act_conv1x1_forward_and_gradient(one_chip, monkeypatch,
-                                             n, cin, cout):
-    from paddle_tpu import ops
-    from paddle_tpu.ops.pallas_fused import bn_act_conv1x1
+def test_the_plain_1x1_convolution_and_batch_norm(one_chip, n, cin, cout):
+    """The plain graph's 1x1 site (conv without bias, then batch norm on
+    batch statistics and relu): forward and gradient compile as one
+    program, with no custom kernel."""
+    from paddle_tpu import dsl
+    from paddle_tpu.core.arg import non_seq
+    from paddle_tpu.network import Network
 
-    # the kernel asks the default backend, which is the CPU here; the
-    # compile is for the chip, where interpret mode does not exist
-    monkeypatch.setattr(ops, "pallas_interpret",
-                        lambda requested=None: False)
+    side = math.isqrt(n // 256)
+    with dsl.model() as g:
+        img = dsl.data("image", (side, side, cin))
+        h = dsl.conv(img, cout, 1, act="", bias=False, name="c")
+        dsl.batch_norm(h, act="relu", name="bn")
+    net = Network(g.conf)
+    params = {k: jax.ShapeDtypeStruct(tuple(pc.dims), jnp.float32,
+                                      sharding=one_chip)
+              for k, pc in net.param_confs.items()}
+    x = jax.ShapeDtypeStruct((256, side, side, cin), jnp.float32,
+                             sharding=one_chip)
 
-    def sds(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    def loss(p, x):
+        y = net.forward(p, {"image": non_seq(x)}, train=True)[0]["bn"].value
+        return jnp.sum(y)
 
-    u = sds((n, cin), jnp.bfloat16)
-    vec = sds((cin,), jnp.float32)
-    w = sds((cin, cout), jnp.float32)
-
-    def fwd(u, scale, shift, w, res):
-        return bn_act_conv1x1(u, scale, shift, w, residual=res)
-
-    def loss(u, scale, shift, w, res):
-        y, s1, s2 = fwd(u, scale, shift, w, res)
-        return (jnp.sum(y.astype(jnp.float32)) + jnp.sum(s1)
-                + jnp.sum(s2))
-
-    assert _has_kernel(_compile(fwd, u, vec, vec, w, u))
-    assert _has_kernel(_compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
-                                u, vec, vec, w, u))
+    text = _compile(jax.value_and_grad(loss, argnums=(0, 1)), params, x)
+    assert "convolution(" in text and not _has_kernel(text)
 
 
 def test_sparse_updater_row_kernel(one_chip):
@@ -145,74 +143,42 @@ def test_sparse_updater_row_kernel(one_chip):
     assert _has_kernel(text)
 
 
-def _fallbacks() -> float:
-    from paddle_tpu import obs
-    from paddle_tpu.obs.aggregate import family_total
-
-    return family_total(obs.get_registry().snapshot()["counters"],
-                        "pallas_rnn.fallbacks")
-
-
-def _rnn_shapes(one_chip, b, t, h, mult, dtype=jnp.float32):
-    def sds(shape, dt=dtype):
-        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
-
-    return sds, sds((b, t, mult * h)), sds((b,), jnp.int32)
+# (b, t, h, activations, weights): the reference's LSTM benchmark width, and
+# bfloat16 activations against float32 weights, where a scan's carry must
+# keep the type its step returns
+RNN_SHAPES = {
+    "b64-t100-h256-f32": (64, 100, 256, jnp.float32, jnp.float32),
+    "b128-t32-h512-bf16-on-f32": (128, 32, 512, jnp.bfloat16, jnp.float32),
+}
 
 
-def test_fused_lstm_and_gru_hold_their_kernels(one_chip):
-    """b=64, t=100, h=256 (the reference's LSTM benchmark width): both
-    fused cells, forward and gradient, compile WITH their kernels and
-    report no fallback."""
-    from paddle_tpu.ops import pallas_rnn as pr
+@pytest.mark.parametrize("shape", RNN_SHAPES)
+@pytest.mark.parametrize("cell,mult", [("lstmemory", 4), ("grumemory", 3)])
+def test_the_scan_cells_compile_forward_and_gradient(one_chip, cell, mult,
+                                                     shape):
+    """The LSTM and GRU cells are a `lax.scan`: forward and gradient
+    compile as one program, a loop over time with no custom kernel."""
+    from paddle_tpu import dsl
+    from paddle_tpu.core.arg import Arg
+    from paddle_tpu.network import Network
 
-    b, t, h = 64, 100, 256
-    before = _fallbacks()
-    sds, x4, lens = _rnn_shapes(one_chip, b, t, h, 4)
-    lstm_args = (x4, sds((h, 4 * h)), sds((4 * h,)), sds((h,)),
-                 sds((h,)), sds((h,)), lens)
+    b, t, h, act_dtype, w_dtype = RNN_SHAPES[shape]
+    with dsl.model() as g:
+        x = dsl.data("x", (mult * h,), is_seq=True)
+        getattr(dsl, cell)(x, h, name="rnn")
+    net = Network(g.conf)
+    params = {k: jax.ShapeDtypeStruct(tuple(pc.dims), w_dtype,
+                                      sharding=one_chip)
+              for k, pc in net.param_confs.items()}
+    x = jax.ShapeDtypeStruct((b, t, mult * h), act_dtype, sharding=one_chip)
+    lens = jax.ShapeDtypeStruct((b,), jnp.int32, sharding=one_chip)
 
-    def lstm(*a):
-        return pr.lstm_fused(*a, False)
+    def loss(p, x, lens):
+        y = net.forward(p, {"x": Arg(value=x, seq_lens=lens)})[0]["rnn"].value
+        return jnp.sum(y.astype(jnp.float32))
 
-    assert _has_kernel(_compile(lstm, *lstm_args))
-    assert _has_kernel(_compile(
-        jax.grad(lambda *a: jnp.sum(lstm(*a)), argnums=(0, 1)),
-        *lstm_args))
-
-    _, x3, _ = _rnn_shapes(one_chip, b, t, h, 3)
-    gru_args = (x3, sds((h, 2 * h)), sds((h, h)), sds((3 * h,)), lens)
-
-    def gru(*a):
-        return pr.gru_fused(*a, False)
-
-    assert _has_kernel(_compile(gru, *gru_args))
-    assert _has_kernel(_compile(
-        jax.grad(lambda *a: jnp.sum(gru(*a)), argnums=(0, 1)),
-        *gru_args))
-    assert _fallbacks() == before
-
-
-def test_fused_lstm_that_falls_back_says_so(one_chip):
-    """b=128, t=32, h=512: the gradient kernel's batch block comes out
-    under 32 rows and the scan reference runs instead. That has to be
-    visible — the counter moves — and, fed bf16 activations against
-    f32 weights, the reference has to compile at all (its scan carry
-    used to break)."""
-    from paddle_tpu.ops import pallas_rnn as pr
-
-    b, t, h = 128, 32, 512
-    sds, x4, lens = _rnn_shapes(one_chip, b, t, h, 4, jnp.bfloat16)
-    f32 = jnp.float32
-    args = (x4, sds((h, 4 * h), f32), sds((4 * h,), f32), sds((h,), f32),
-            sds((h,), f32), sds((h,), f32), lens)
-    before = _fallbacks()
-    _compile(
-        jax.grad(lambda *a: jnp.sum(pr.lstm_fused(*a, False)
-                                    .astype(jnp.float32)),
-                 argnums=(0, 1)),
-        *args)
-    assert _fallbacks() > before
+    text = _compile(jax.value_and_grad(loss, argnums=(0, 1)), params, x, lens)
+    assert " while(" in text and not _has_kernel(text)
 
 
 # ---- the decoder block's kernels at the widths of the cell

@@ -788,15 +788,19 @@ class TestBreakerOpenOnRescuedDispatch:
         try:
             out = server.submit("jp", [1, 2]).result(timeout=30)
             assert out["path"] == "host"  # the rescue worked
+            # the dump fires after the request has completed, and its
+            # bundle is written as a `.tmp` then renamed: wait for the
+            # renamed bundle itself, not for the directory to fill
+            def bundles():
+                return [f for f in os.listdir(str(tmp_path))
+                        if f.endswith(".json")]
+
             deadline = time.monotonic() + 10
-            while time.monotonic() < deadline and not \
-                    os.listdir(str(tmp_path)):
+            while time.monotonic() < deadline and not bundles():
                 time.sleep(0.02)
-            bundles = [f for f in os.listdir(str(tmp_path))
-                       if f.endswith(".json")]
-            assert len(bundles) == 1, bundles
+            assert len(bundles()) == 1, os.listdir(str(tmp_path))
             doc = json.load(open(os.path.join(str(tmp_path),
-                                              bundles[0])))
+                                              bundles()[0])))
             assert doc["reason"] == "breaker_open"
             assert doc["context"] == {"model": "jp"}
         finally:
